@@ -92,7 +92,7 @@ def main():
 
 # -- measured CPU denominators for the remaining BASELINE configs ------------
 #
-# Round-5: every BENCHMARKS.md row gets a measured-vs-measured ratio
+# Round-5: every benchmark config gets a measured-vs-measured ratio
 # (r4 verdict item 2). Each function mirrors its TPU config's metric set
 # with the strongest plausible single-threaded vectorized-numpy kernels —
 # exact bincount instead of HLL where exact counting is FASTER on CPU, so

@@ -420,6 +420,7 @@ _COMPILE_RE = re.compile(
     r"[Ff]ailed to compile|Mosaic|XLA can't deduce|[Ll]owering",
     re.DOTALL,
 )
+_PALLAS_LOWERING_RE = re.compile(r"Pallas|Mosaic")
 _LOST_RE = re.compile(
     r"DATA_LOSS|UNAVAILABLE|ABORTED|INTERNAL|DEADLINE_EXCEEDED|"
     r"[Dd]evice.*(lost|halt|reset)|[Uu]nable to initialize backend|"
@@ -460,8 +461,9 @@ def _device_error_strength(exception: BaseException) -> Optional[str]:
     surfaces runtime failures as XlaRuntimeError, a RuntimeError from the
     jaxlib/jax modules — checked structurally so no jaxlib import is
     needed and test doubles with the same shape classify identically);
-    ``"weak"`` for plain RuntimeError/MemoryError, which only classify on
-    an unambiguous message pattern; None for everything else."""
+    ``"weak"`` for plain RuntimeError/MemoryError (and the builtin
+    errors the Pallas TPU lowering raises), which only classify on an
+    unambiguous message pattern; None for everything else."""
     for klass in type(exception).__mro__:
         if klass.__name__ in (
             "XlaRuntimeError", "JaxRuntimeError", "InternalError"
@@ -471,6 +473,13 @@ def _device_error_strength(exception: BaseException) -> Optional[str]:
         if module.startswith(("jaxlib", "jax.")) or module == "jax":
             return "strong"
     if isinstance(exception, (RuntimeError, MemoryError)):
+        return "weak"
+    # the Pallas TPU lowering refuses a kernel (block shape, unsupported
+    # op) with a BUILTIN ValueError/NotImplementedError raised while jit
+    # lowers the program — a compile refusal that must surface typed
+    if isinstance(
+        exception, (ValueError, NotImplementedError)
+    ) and _PALLAS_LOWERING_RE.search(str(exception)):
         return "weak"
     return None
 

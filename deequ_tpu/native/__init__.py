@@ -3,8 +3,10 @@
 The device compute path is JAX/XLA; this module accelerates the host-side
 hot loops that feed it: per-distinct-value hashing, type classification and
 utf-8 lengths over dictionary batches. The extension compiles on first use
-(g++, cached next to the source); if the toolchain is unavailable every
-entry point silently falls back to the Python implementation.
+(g++, from the tracked ``kernels.cpp``, cached next to it); if the build or
+the load fails, the failure is reported ONCE on stderr with the compiler's
+output and every entry point runs the bit-identical Python implementation
+(``available()`` says which path a process is on).
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import List, Optional, Sequence
 
@@ -33,7 +36,14 @@ def _build() -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        output = getattr(e, "stderr", None) or b""
+        print(
+            f"deequ_tpu.native: building {_SO} failed ({e}); host string "
+            "kernels run the Python path.\n"
+            + output.decode("utf-8", "replace"),
+            file=sys.stderr,
+        )
         return False
 
 
@@ -55,7 +65,12 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
+            print(
+                f"deequ_tpu.native: loading {_SO} failed ({e}); host "
+                "string kernels run the Python path.",
+                file=sys.stderr,
+            )
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -53,8 +53,8 @@ def encoded_ingest_enabled(param: Optional[bool] = None) -> bool:
     regression-triage escape hatch, mirroring DEEQU_TPU_SELECT_KERNEL;
     parsed via the deequ_tpu/envcfg registry), then on. When on, columns
     carrying a dictionary encoding ride the int16 ``enc`` plane (codes
-    only over the tunnel; decode is a dictionary gather fused into the
-    scan program); off routes every column through the decoded planes
+    only over the host->device link; decode is a dictionary gather fused
+    into the scan program); off routes every column through the decoded planes
     exactly as before round 8."""
     from deequ_tpu.envcfg import env_value
 

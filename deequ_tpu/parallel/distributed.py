@@ -5,7 +5,7 @@ The engine itself is topology-agnostic: it runs over whatever mesh
 ``parallel.mesh.current_mesh()`` resolves. On a multi-host TPU slice, call
 ``initialize_multi_host()`` once per process before building tables; the
 default mesh then spans every chip in the slice and the scan engine's
-collectives (psum/pmin/pmax/all_gather) ride ICI inside a slice and DCN
+collectives (psum/all_gather) ride ICI inside a slice and DCN
 across slices — XLA routes them, exactly as the design requires (no NCCL/
 MPI analogue needed).
 

@@ -5,7 +5,7 @@ monoid state merge + shuffle group-by + tree reduce. The TPU-native
 equivalent implemented here: rows are sharded over a 1-D ``jax.sharding.Mesh``
 axis (``"rows"``), per-device partial states are computed inside
 ``shard_map``, and state merges ride ICI as XLA collectives
-(psum/pmin/pmax — see ops/scan_engine.py for the tagged merge).
+(psum / all_gather — see ops/scan_engine.py for the tagged merge).
 
 Multi-host scaling: the same mesh spans hosts under ``jax.distributed``;
 nothing in the engine distinguishes ICI from DCN — XLA routes collectives.
@@ -20,20 +20,7 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh
 
-# jax.shard_map graduated from jax.experimental in newer releases (where the
-# replication-check kwarg is also renamed check_rep -> check_vma); older
-# runtimes (e.g. 0.4.x) only ship the experimental symbol. One resolution
-# point here so every kernel site works on both.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on older jax
-
-    def shard_map(f, *args, **kwargs):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _sm(f, *args, **kwargs)
+shard_map = jax.shard_map
 
 ROW_AXIS = "rows"
 

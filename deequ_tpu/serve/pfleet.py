@@ -101,6 +101,36 @@ from deequ_tpu.serve.transport import (
 )
 
 
+def _host_tpu_chips() -> int:
+    """TPU chips this host exposes, counted WITHOUT jax (the coordinator
+    never initialises a backend): one device node per chip."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def _worker_env(idx: int, n_workers: int) -> Dict[str, str]:
+    """The environment of worker process ``idx``: the coordinator's own
+    (workers inherit the compile-cache placement and every DEEQU_TPU_*
+    setting), plus — where the host has a TPU chip for EACH of several
+    workers — the libtpu settings that give this process chip ``idx`` and
+    nothing else. A chip serves one process (docs/serving.md); with fewer
+    chips than workers nothing is set and the surplus workers fail their
+    spawn typed."""
+    env = dict(os.environ)
+    if n_workers > 1 and _host_tpu_chips() >= n_workers:
+        env.update(
+            TPU_VISIBLE_CHIPS=str(idx),
+            TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+            TPU_PROCESS_BOUNDS="1,1,1",
+            TPU_MESH_CONTROLLER_ADDRESS=f"localhost:{8476 + idx}",
+            TPU_MESH_CONTROLLER_PORT=str(8476 + idx),
+        )
+    return env
+
+
 @dataclass
 class ProcessFleetConfig:
     """ProcessFleet knobs. ``transport`` / ``ledger_dir`` default from
@@ -372,12 +402,29 @@ class ProcessFleet:
             name=f"deequ-tpu-pfleet-rx-{idx}",
         )
         worker.receiver.start()
-        if not worker.ready.wait(self.config.spawn_timeout):
+        # wait for hello, but not past the worker's own death: a process
+        # that cannot start (on a TPU host, one that cannot get the chip —
+        # a chip serves ONE process, docs/serving.md) exits at once, and
+        # the spawn must fail typed then, not spawn_timeout later
+        deadline = time.monotonic() + self.config.spawn_timeout
+        while not worker.ready.wait(0.05):
+            if worker.proc is not None and worker.proc.poll() is not None:
+                why = (
+                    "exited before saying hello "
+                    f"(exit code {worker.proc.returncode})"
+                )
+            elif worker.stopped.is_set():
+                why = "closed its channel before saying hello"
+            elif time.monotonic() >= deadline:
+                why = (
+                    "did not say hello within "
+                    f"{self.config.spawn_timeout:g}s of spawn"
+                )
+            else:
+                continue
             self._retire_endpoint(worker)
             raise WorkerLostException(
-                f"worker {idx} did not say hello within "
-                f"{self.config.spawn_timeout:g}s of spawn",
-                worker_ids=(idx,),
+                f"worker {idx} {why}", worker_ids=(idx,),
             )
         return worker
 
@@ -392,7 +439,10 @@ class ProcessFleet:
         ]
         if self.config.worker_knobs:
             argv += ["--knobs", json.dumps(self.config.worker_knobs)]
-        proc = subprocess.Popen(argv, pass_fds=(child.fileno(),))
+        proc = subprocess.Popen(
+            argv, pass_fds=(child.fileno(),),
+            env=_worker_env(idx, self.n_workers),
+        )
         child.close()
         return _PWorker(idx, SocketTransport(parent), proc=proc)
 

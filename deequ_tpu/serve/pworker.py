@@ -419,6 +419,13 @@ def main(argv=None) -> int:
     from deequ_tpu.serve.transport import SocketTransport
 
     knobs = json.loads(args.knobs) if args.knobs else None
+    # hello means "I hold my device": initialise the backend BEFORE the
+    # loop says it, so a worker that cannot get the chip (a chip serves
+    # ONE process; its parent or a sibling holds it) fails the
+    # coordinator's spawn, typed, instead of failing every request later
+    import jax
+
+    jax.devices()
     sock = socket.socket(fileno=args.fd)
     WorkerLoop(SocketTransport(sock), idx=args.idx,
                worker_knobs=knobs).run()

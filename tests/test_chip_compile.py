@@ -1,0 +1,493 @@
+"""The main path's device programs, compiled for a DESCRIBED TPU v5e at the
+shapes chip_smoke.py runs (on-chip-measurement guide §2, rehearsal 3).
+
+The sandbox has no chip, but the TPU compiler is installed and compiles for
+a topology that is described, not attached — so what Mosaic or XLA:TPU
+would refuse on the machine with the chip is refused here, at no chip
+time. Nothing runs: these tests say nothing about results or speed.
+
+Code that asks ``jax.default_backend()`` / ``jax.devices()`` still sees the
+CPU here and would take its CPU branch (f32 one-hot planes, scatter HLL
+fold, no donation); the ``as_tpu`` fixture steers it inside the test.
+
+Every chip-related call lives in a fixture or a test body — never at
+import time, in conftest.py, in a ``skipif`` or in ``parametrize`` — because
+only one process may load the TPU library and every xdist worker imports
+this file. Keep these tests in ONE file (one worker owns the library).
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+TOPOLOGY = "v5e:2x2"
+SMOKE_ROWS = chip_smoke.RESIDENT_ROWS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described (not attached) 4-chip v5e host, with the persistent
+    compile cache off around every compile of this module: an entry
+    written for a described chip cannot be read back without one."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name=TOPOLOGY
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no {TOPOLOGY} topology can be described here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch, topo):
+    """Steer the code that asks which backend it runs on: inside the test
+    the default backend reads "tpu" and ``jax.devices()`` lists the
+    described chips."""
+    import jax
+
+    real_devices = jax.devices
+
+    def devices(backend=None):
+        return list(topo.devices) if backend is None else real_devices(backend)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", devices)
+
+
+def _aval(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _compile(fn, *avals):
+    import jax
+
+    return jax.jit(fn).lower(*avals).compile()
+
+
+def _chunk_avals(packer, chunk, plane, rows):
+    """Abstract (values, hi, lo, narrow_i, masks, codes, row_valid, enc)
+    for one packed chunk — the shapes ``_ChunkPacker.pack`` would emit."""
+    return (
+        _aval((len(packer.wide_names), chunk), np.float64, plane),
+        _aval((len(packer.pair_names) + len(packer.hi_only_names), chunk),
+              np.float32, plane),
+        _aval((len(packer.pair_names), chunk), np.float32, plane),
+        _aval((len(packer.narrow_i32), chunk), np.int32, plane),
+        _aval((len(packer.masked_names), chunk), np.bool_, plane),
+        _aval((len(packer.string_names), chunk), np.int32, plane),
+        _aval((chunk,), np.bool_, rows),
+        _aval((len(packer.enc_names), chunk), np.int16, plane),
+    )
+
+
+def _smoke_step(chunk, mesh, plane, rows, replicated, resident=True):
+    """The fused step of chip_smoke's resident suite, built the way
+    ``scan_engine._run_scan_once`` builds it, at ``chunk`` abstract rows.
+    Returns (jitted step, abstract args, resolved plan)."""
+    from deequ_tpu.analyzers.base import ScanShareableAnalyzer
+    from deequ_tpu.analyzers.runner import AnalysisRunner, _is_grouping_shared
+    from deequ_tpu.ops.lut_cache import dictionary_lut
+    from deequ_tpu.ops.scan_engine import _build_step_fns, _ChunkPacker
+    from deequ_tpu.ops.scan_plan import plan_scan_ops
+
+    table = chip_smoke.build_table(4096, seed=21)
+    scanning = [
+        a for a in chip_smoke.suite_analyzers()
+        if isinstance(a, ScanShareableAnalyzer) and not _is_grouping_shared(a)
+    ]
+    ops, scannable, failures = AnalysisRunner._build_scan_ops(table, scanning)
+    assert not failures and len(scannable) == len(scanning)
+    exec_ops, _ = AnalysisRunner._coalesce_scan_ops(ops)
+    packer = _ChunkPacker(
+        {n: table[n] for n in table.column_names}, chunk, encode_ingest=True
+    )
+    plan = plan_scan_ops(
+        exec_ops, packer, resident=resident, select_kernel=True, rows=chunk
+    )
+    luts = {}
+    for op in plan.ops:
+        for col, kind, builder in op.luts:
+            host = dictionary_lut(table[col].dictionary, kind, builder)
+            # the smoke's wide dictionary holds WIDE_CARD entries: the LUT
+            # is a runtime argument, so only its pow2-padded length matters
+            width = 1 << (chip_smoke.WIDE_CARD - 1).bit_length() \
+                if col == "ustr" else 1 << max(len(host) - 1, 0).bit_length()
+            luts[col + "\x00" + kind] = _aval((width,), host.dtype, replicated)
+    n_dev = int(np.prod(mesh.devices.shape)) if mesh is not None else 1
+    step_fn, _, _ = _build_step_fns(
+        plan.ops, packer.unpack_view(), mesh, chunk // n_dev,
+        tuple(sorted(luts)),
+    )
+    return step_fn, _chunk_avals(packer, chunk, plane, rows) + (luts,), plan
+
+
+def test_resident_fused_step_compiles_at_smoke_shape(as_tpu, one_chip):
+    step_fn, avals, plan = _smoke_step(
+        SMOKE_ROWS, None, one_chip, one_chip, one_chip
+    )
+    # the chip's branches: selection kernel routed over the one-hot tier
+    assert plan.variant == "select" and plan.hist_variant == "onehot"
+    lowered = step_fn.lower(*avals)
+    # traced as the chip traces it: bf16 one-hot planes (HLL MXU fold and
+    # the selection kernel's histogram passes)
+    assert "bf16" in lowered.as_text()
+    compiled = lowered.compile()
+    # ...which XLA:TPU turns into MXU convolutions with the one-hot
+    # compare fused into the operands
+    assert "convolution" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # one program must fit beside the resident table in 16 GB of HBM
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 << 30
+
+
+def test_row_sharded_step_compiles_with_all_reduce(as_tpu, topo):
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from deequ_tpu.parallel.mesh import ROW_AXIS
+
+    mesh = Mesh(np.array(topo.devices), (ROW_AXIS,))
+    step_fn, avals, _ = _smoke_step(
+        SMOKE_ROWS, mesh,
+        NamedSharding(mesh, P(None, ROW_AXIS)),
+        NamedSharding(mesh, P(ROW_AXIS)),
+        NamedSharding(mesh, P()),
+    )
+    compiled = step_fn.lower(*avals).compile()
+    assert "all-reduce" in compiled.as_text()
+    # each device holds a quarter of every packed plane
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    whole = sum(
+        int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize for a in avals[:8]
+    )
+    # (plus the (8, 128) tile padding of 20-row planes)
+    assert per_device < whole / 4 * 1.3
+
+
+def test_hll_mxu_fold_compiles(as_tpu, one_chip):
+    import jax.numpy as jnp
+
+    from deequ_tpu.ops import hll
+
+    def fold(idx, rank, valid):
+        return hll.registers_from_idx_rank(idx, rank, valid, 9, jnp)
+
+    import jax
+
+    lowered = jax.jit(fold).lower(
+        _aval((SMOKE_ROWS,), np.int32, one_chip),
+        _aval((SMOKE_ROWS,), np.int32, one_chip),
+        _aval((SMOKE_ROWS,), np.bool_, one_chip),
+    )
+    # the MXU branch (one-hot bf16 matmul), not the scatter segment_max
+    assert "bf16" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "convolution" in text and "scatter" not in text
+
+
+def test_vmapped_hll_fold_compiles_without_a_batched_convolution(
+    as_tpu, one_chip
+):
+    """The tenant-axis form of the fold (serving, 8 members): it must lower
+    to the UNBATCHED one-hot convolution inside a loop — XLA:TPU returned
+    zeros for half of a batch-8 convolution on the chip (PR 21)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deequ_tpu.ops import hll
+
+    K, n = 8, chip_smoke.SERVE_ROWS
+    compiled = _compile(
+        jax.vmap(lambda i, r: hll._registers_mxu_fold(i, r, 512, jnp)),
+        _aval((K, n), np.int32, one_chip),
+        _aval((K, n), np.int32, one_chip),
+    )
+    text = compiled.as_text()
+    assert "convolution" in text and "while" in text
+
+
+@pytest.mark.parametrize("segments", [1 << 16, 1 << 17])
+def test_bincount_onehot_bf16_planes_compile(as_tpu, one_chip, segments):
+    import jax.numpy as jnp
+
+    from deequ_tpu.ops.histogram_device import _plane_dtype, bincount_onehot
+
+    assert _plane_dtype(jnp) == jnp.bfloat16
+    compiled = _compile(
+        lambda seg: bincount_onehot(seg, segments, jnp),
+        _aval((1 << 22,), np.int32, one_chip),
+    )
+    text = compiled.as_text()
+    assert "convolution" in text and "scatter" not in text
+
+
+def test_selection_kernel_compiles_at_config3_width(as_tpu, one_chip):
+    """BASELINE config 3: 50 quantile columns (k=256) over one resident
+    chunk, every histogram pass on the one-hot tier as the chip routes."""
+    import jax.numpy as jnp
+
+    from deequ_tpu.ops.device_policy import resolve_hist_variant
+    from deequ_tpu.ops.histogram_device import active_hist_variant
+    from deequ_tpu.ops.select_device import chunk_summary_select_batched
+
+    K, n, k = 50, 1 << 22, 256
+    variant = resolve_hist_variant((1 << 16, (k + 2) * 256 + 1), rows=n)
+    assert variant == "onehot"
+
+    def summaries(x, valid, lo):
+        with active_hist_variant(variant):
+            return chunk_summary_select_batched(x, valid, k, n, jnp, lo=lo)
+
+    compiled = _compile(
+        summaries,
+        _aval((K, n), np.float32, one_chip),
+        _aval((K, n), np.bool_, one_chip),
+        _aval((K, n), np.float32, one_chip),
+    )
+    assert "sort" not in compiled.as_text().replace("sorted", "")
+    assert compiled.memory_analysis().temp_size_in_bytes < 14 << 30
+
+
+def test_coalesced_tenant_step_compiles(topo, one_chip, monkeypatch):
+    """The vmapped packed program of chip_smoke's serving phase: the plan
+    is captured from the real service on a CPU run of tiny tenants, then
+    its program is rebuilt and compiled at the smoke's (submits, rows)."""
+    import dataclasses
+
+    import jax
+
+    from deequ_tpu.parallel.mesh import use_mesh
+    from deequ_tpu.serve import VerificationService, executor
+
+    captured = []
+    real_build = executor._build_packed_program
+
+    def capture(plan, lut_keys, op_order=None):
+        captured.append((plan, lut_keys, op_order))
+        return real_build(plan, lut_keys, op_order=op_order)
+
+    rows, tenants = 4096, 4
+    with monkeypatch.context() as capture_run, use_mesh(None):
+        capture_run.setattr(executor, "_build_packed_program", capture)
+        service = VerificationService(max_batch=tenants)
+        try:
+            futures = [
+                service.submit(
+                    chip_smoke._tenant_table(rows, 100 + t),
+                    [chip_smoke._tenant_check(rows)], tenant=f"t{t}",
+                )
+                for t in range(tenants)
+            ]
+            for f in futures:
+                f.result(timeout=120)
+        finally:
+            service.stop()
+    assert captured, "the service never built a coalesced program"
+    plan, lut_keys, op_order = captured[-1]
+    assert not lut_keys  # numeric tenants: no dictionary LUT arguments
+    layout = plan.layout
+    # the satisfies predicate routed x over the exact wide-f64 plane
+    assert "x" in layout["wide"]
+
+    # the same plan at the smoke's row count, traced as the chip would
+    K, n = chip_smoke.SERVE_SUBMITS, chip_smoke.SERVE_ROWS
+    big = dataclasses.replace(
+        plan, key=dataclasses.replace(plan.key, chunk=n)
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: list(topo.devices))
+    _tree, _flat, vstep = real_build(big, lut_keys, op_order=op_order)
+    avals = (
+        _aval((K, len(layout["wide"]), n), np.float64, one_chip),
+        _aval((K, len(layout["pair"]) + len(layout["hi_only"]), n),
+              np.float32, one_chip),
+        _aval((K, len(layout["pair"]), n), np.float32, one_chip),
+        _aval((K, len(layout["narrow_i32"]), n), np.int32, one_chip),
+        _aval((K, len(layout["masked"]), n), np.bool_, one_chip),
+        _aval((K, 0, n), np.int32, one_chip),
+        _aval((K, n), np.bool_, one_chip),
+        _aval((K, len(layout.get("enc", ())), n), np.int16, one_chip),
+        {},
+    )
+    assert vstep.lower(*avals).compile() is not None
+
+
+@pytest.mark.parametrize(
+    "keyspaces", [(22, 41), (22, chip_smoke.WIDE_CARD + 1)],
+    ids=["narrow-onehot", "wide-scatter"],
+)
+def test_fused_grouping_pass_compiles(as_tpu, one_chip, keyspaces):
+    from deequ_tpu.ops.device_policy import resolve_hist_variant
+    from deequ_tpu.ops.segment import _bincount_fn
+
+    n = 2 * (SMOKE_ROWS // 2)  # two sub-passes' keys, concatenated
+    total = sum(keyspaces)
+    variant = resolve_hist_variant((total + 1,), rows=n)
+    assert variant == ("onehot" if total < (1 << 17) else "scatter")
+    fn = _bincount_fn(total, None, variant)
+    assert fn.lower(_aval((n,), np.int64, one_chip)).compile() is not None
+
+
+def test_grouping_sort_kernels_compile(one_chip):
+    """Uniqueness over the smoke's near-unique int64 key: the device
+    unique/inverse sort and the sparse run-length stats, 64-bit keys."""
+    from deequ_tpu.ops import segment
+
+    n = SMOKE_ROWS
+    values = _aval((n,), np.int64, one_chip)
+    valid = _aval((n,), np.bool_, one_chip)
+    assert segment._unique_inverse_kernel.lower(values, valid).compile()
+    codes = _aval((1, n), np.int64, one_chip)
+    assert segment._rle_stats_kernel.lower(codes, valid).compile()
+
+
+def test_sharded_grouping_kernels_compile(as_tpu, topo):
+    """The persisted string column's bincount under the 4-chip mesh (the
+    --chips 4 run's Histogram/Entropy path): per-shard counts + i64 psum."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from deequ_tpu.ops.device_policy import resolve_hist_variant
+    from deequ_tpu.ops.segment import _bincount_fn, _resident_bincount_fn
+    from deequ_tpu.parallel.mesh import ROW_AXIS
+
+    mesh = Mesh(np.array(topo.devices), (ROW_AXIS,))
+    n = SMOKE_ROWS
+    card = chip_smoke.N_CATS
+    variant = resolve_hist_variant((card + 2,), rows=n)
+    assert variant == "onehot"
+    fn = _resident_bincount_fn(card + 1, 1, 0, True, mesh, variant)
+    compiled = fn.lower(
+        _aval((2, n), np.int32, NamedSharding(mesh, P(None, ROW_AXIS))),
+        _aval((n,), np.bool_, NamedSharding(mesh, P(ROW_AXIS))),
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+    wide = _bincount_fn(chip_smoke.WIDE_CARD + 1, mesh, "scatter")
+    assert wide.lower(
+        _aval((n,), np.int64, NamedSharding(mesh, P(ROW_AXIS)))
+    ).compile()
+
+
+def test_pane_step_compiles_in_f64(one_chip):
+    from deequ_tpu.analyzers import (
+        Completeness, Maximum, Mean, Minimum, Size, Sum,
+    )
+    from deequ_tpu.windows.engine import (
+        _data_columns, _make_step, pane_signature,
+    )
+
+    sig = pane_signature([
+        Size(), Completeness("v"), Mean("v"), Minimum("v"), Maximum("v"),
+        Sum("v"),
+    ])
+    data_cols = _data_columns(sig)
+    n, panes = chip_smoke.WINDOW_BATCH_ROWS, 4
+    compiled = _compile(
+        _make_step(sig, 20.0, data_cols),
+        _aval((n,), np.float64, one_chip),       # event times
+        _aval((panes,), np.float64, one_chip),   # pane starts
+        _aval((), np.float64, one_chip),         # watermark fence
+        *[_aval((n,), np.float64, one_chip) for _ in data_cols],
+        *[_aval((n,), np.bool_, one_chip) for _ in data_cols],
+    )
+    assert compiled is not None
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["count", "weighted"])
+def test_bincount_pallas_compiles_on_mosaic(one_chip, weighted):
+    import jax.numpy as jnp
+
+    from deequ_tpu.ops.histogram_device import bincount_pallas
+
+    n, segments = 1 << 20, (1 << 18) + 3
+    avals = [_aval((n,), np.int32, one_chip)]
+    if weighted:
+        avals.append(_aval((n,), np.int32, one_chip))
+
+    def kernel(seg, w=None):
+        return bincount_pallas(seg, segments, jnp, weights=w, interpret=False)
+
+    compiled = _compile(kernel, *avals)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_refusal_surfaces_as_device_compile_exception(one_chip):
+    """A kernel Mosaic refuses (a (1, 1024) block over a (1024, 1024)
+    array — the shape bincount_pallas had before it met the compiler) must
+    classify as a typed compile failure, never reroute."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from deequ_tpu.exceptions import (
+        DeviceCompileException,
+        classify_device_error,
+    )
+
+    def misaligned(x):
+        def body(x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+
+        return pl.pallas_call(
+            body, grid=(1024,),
+            in_specs=[pl.BlockSpec((1, 1024), lambda i: (i, np.int32(0)))],
+            out_specs=pl.BlockSpec((1, 1024), lambda i: (i, np.int32(0))),
+            out_shape=jax.ShapeDtypeStruct((1024, 1024), jnp.int32),
+        )(x)
+
+    with pytest.raises(ValueError) as refused:
+        _compile(misaligned, _aval((1024, 1024), np.int32, one_chip))
+    assert "divisible by 8 and 128" in str(refused.value)
+    typed = classify_device_error(refused.value, "execute")
+    assert isinstance(typed, DeviceCompileException)
+
+
+def test_device_fold_merge_compiles_with_donation(as_tpu, one_chip):
+    """Buffer donation is on only off-CPU (scan_engine._fold_plan_for):
+    the donated multi-chunk merge must compile for the chip."""
+    import jax
+
+    from deequ_tpu.analyzers import Maximum, Mean, Minimum, Size
+    from deequ_tpu.ops.scan_engine import _fold_plan_for
+
+    table = chip_smoke.build_table(64, seed=1, n_numeric=2)
+    ops = [a.scan_op(table) for a in (Size(), Mean("c0"), Minimum("c0"),
+                                      Maximum("c1"))]
+    shapes = [
+        jax.tree.map(
+            lambda tag: jax.ShapeDtypeStruct((), np.float64), op.tags
+        )
+        for op in ops
+    ]
+    plan = _fold_plan_for(ops, shapes, capacity=4)
+    flat = sum(len(jax.tree.leaves(op.tags)) for op in ops)
+    lowered = plan._merge_jit.lower(
+        _aval((plan.acc_size,), np.float64, one_chip),
+        _aval((flat,), np.float64, one_chip),
+    )
+    assert "donat" in lowered.as_text() or "alias" in lowered.as_text()
+    assert lowered.compile() is not None

@@ -64,7 +64,7 @@ def test_registered_scheme_backs_metrics_repository():
 
 
 def test_host_row_range_balanced(monkeypatch):
-    """Edge cases from VERDICT r1 #10: 0 rows, n_proc > rows, balance."""
+    """Edge cases from the round-1 review (#10): 0 rows, n_proc > rows, balance."""
     import jax
 
     from deequ_tpu.parallel.distributed import host_row_range

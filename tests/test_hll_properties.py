@@ -82,7 +82,7 @@ def test_host_device_hash_consistency():
 def test_ertl_estimator_accuracy_across_range():
     """Relative error holds ~1.3/sqrt(m) across 100..1M cardinalities,
     including the classic 2.5m-5m band the raw+linear-counting estimator
-    gets wrong without bias tables (VERDICT r1 #6; reference
+    gets wrong without bias tables (round-1 review #6; reference
     StatefulHyperloglogPlus.scala:210-257)."""
     from deequ_tpu.ops import hll
 

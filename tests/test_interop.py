@@ -197,7 +197,7 @@ _GSON_FIXTURE = [
 
 def test_repository_json_import_and_anomaly_continuity():
     """The migrated metric history feeds anomaly detection on day one —
-    the VERDICT's 'existing deployment switches over' workflow."""
+    the round-5 review's 'existing deployment switches over' workflow."""
     from deequ_tpu.anomaly import AnomalyDetector, RelativeRateOfChangeStrategy
     from deequ_tpu.anomaly.history import DataPoint
     from deequ_tpu.metrics import Entity

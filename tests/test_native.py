@@ -57,3 +57,27 @@ def test_large_batch_consistency(built):
     expected = [xxhash64_bytes(v.encode("utf-8"), XXHASH_SEED) for v in values]
     assert out.tolist() == expected
     assert native.utf8_lengths(values).tolist() == [len(v) for v in values]
+
+
+def test_failed_build_is_reported_once_with_the_compilers_output(
+    monkeypatch, tmp_path, capfd
+):
+    """A g++ that refuses kernels.cpp must not vanish: the compiler's own
+    words reach stderr (once — the load is attempted once per process) and
+    the Python path takes over."""
+    import shutil
+
+    if shutil.which("g++") is None:
+        pytest.skip("no g++ to refuse anything")
+    broken = tmp_path / "kernels.cpp"
+    broken.write_text("this is not C++;\n")
+    monkeypatch.setattr(native, "_SRC", str(broken))
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "_kernels.so"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_attempted", False)
+    assert native.available() is False
+    assert native.hash_strings(["abc"], 1) is None  # callers fall back
+    assert native.available() is False
+    err = capfd.readouterr().err
+    assert err.count("deequ_tpu.native: building") == 1
+    assert "error" in err and "kernels.cpp" in err

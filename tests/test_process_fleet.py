@@ -632,3 +632,91 @@ def test_process_fleet_sigkill_failover_bit_identical():
                    for w in stats["workers"].values())
     finally:
         fleet.stop(drain=True)
+
+
+# -- one process per chip (PR 21) --------------------------------------------
+
+
+def test_spawn_fails_typed_and_promptly_when_a_worker_cannot_start(
+    monkeypatch,
+):
+    """A worker process that cannot initialise its backend (on a TPU host:
+    its parent, or another worker, holds the one chip) exits before hello;
+    the spawn must fail TYPED when the process dies, not spawn_timeout
+    (60 s) later."""
+    from deequ_tpu.exceptions import WorkerLostException
+
+    # inherited by the spawned worker only: this process's backend is up
+    monkeypatch.setenv("JAX_PLATFORMS", "no_such_backend")
+    t0 = time.monotonic()
+    with pytest.raises(WorkerLostException, match="exited before saying hello"):
+        ProcessFleet(transport="proc", n_workers=1, monitor=False)
+    assert time.monotonic() - t0 < 45.0
+
+
+def test_coordinator_never_initialises_a_jax_backend():
+    """The coordinator of a proc-transport fleet routes, frames and
+    ledgers on the host only: after serving a suite through a worker
+    process its own jax has NO backend — so it never takes the chip its
+    workers need."""
+    import subprocess
+    import sys
+
+    code = """
+import numpy as np
+from deequ_tpu.analyzers import Completeness, Mean, Size
+from deequ_tpu.data.table import Column, ColumnarTable, DType
+from deequ_tpu.serve.pfleet import ProcessFleet
+from jax._src import xla_bridge
+
+table = ColumnarTable([
+    Column("x", DType.FRACTIONAL, values=np.arange(64, dtype=np.float64)),
+])
+fleet = ProcessFleet(transport="proc", n_workers=1, monitor=False)
+try:
+    result = fleet.submit(
+        table, required_analyzers=[Size(), Completeness("x"), Mean("x")],
+        tenant="t",
+    ).result(timeout=300)
+finally:
+    fleet.stop(drain=True)
+assert all(m.value.is_success for m in result.metrics.values())
+assert result.metrics[Mean("x")].value.get() == 31.5
+print("BACKENDS_INITIALIZED", xla_bridge.backends_are_initialized())
+"""
+    done = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=600, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert "BACKENDS_INITIALIZED False" in done.stdout
+
+
+@pytest.mark.parametrize(
+    "chips,n_workers,pinned",
+    [(4, 4, True), (4, 2, True), (1, 2, False), (4, 1, False), (0, 4, False)],
+)
+def test_worker_env_gives_each_worker_its_own_chip(
+    monkeypatch, chips, n_workers, pinned
+):
+    """Where the host has a chip for each of several workers, worker i
+    sees chip i only; otherwise nothing is set (one worker owns the one
+    chip; surplus workers fail their spawn typed). The coordinator's
+    environment — compile-cache placement included — always passes on."""
+    from deequ_tpu.serve import pfleet
+
+    monkeypatch.setattr(pfleet, "_host_tpu_chips", lambda: chips)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/cache")
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    envs = [pfleet._worker_env(i, n_workers) for i in range(n_workers)]
+    for i, env in enumerate(envs):
+        assert env["JAX_COMPILATION_CACHE_DIR"] == "/somewhere/cache"
+        if pinned:
+            assert env["TPU_VISIBLE_CHIPS"] == str(i)
+            assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        else:
+            assert "TPU_VISIBLE_CHIPS" not in env
+    if pinned:
+        ports = {env["TPU_MESH_CONTROLLER_PORT"] for env in envs}
+        assert len(ports) == n_workers

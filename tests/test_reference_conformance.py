@@ -4,7 +4,7 @@ The reference pins exact sketch semantics: HLL++ as 52 x 6-bit registers
 with xxHash64 and Spark's empirical bias tables
 (analyzers/catalyst/StatefulHyperloglogPlus.scala:152-298), and KLL with
 the compactor hierarchy of QuantileNonSample.scala:25-305. This framework
-DELIBERATELY redesigned both (BENCHMARKS.md, ops/hll.py docstring): a
+DELIBERATELY redesigned both (ops/hll.py docstring): a
 table-free Ertl-style HLL estimator over the same register-max algebra,
 and a device-built KLL with deterministic strata compaction feeding the
 standard merge algebra. These tests pin the redesigned estimators to
