@@ -923,76 +923,6 @@ def measure_governance_overhead(n_rows: int):
     }
 
 
-def measure_obs_overhead(n_rows: int):
-    """Observability cost probe (deequ_tpu/obs): the config-1 shape
-    timed DISARMED (no recorder anywhere — the production default) vs
-    ARMED (an ambient FlightRecorder recording every seam span). Two
-    contracts, both hard-asserted:
-
-    - disarmed is FREE: a disarmed run must leave a canary recorder
-      empty and write nothing span-shaped anywhere — the disarmed seam
-      cost is one module-global integer check, which no wall-clock
-      probe on a noisy container can even resolve (that structural
-      zero IS the disarmed assert);
-    - armed costs <1% of healthy wall (median-of-5 trials + one
-      discard-and-retry, the governance probe's harness), while
-      actually recording (span count > 0 re-asserted per trial)."""
-    from deequ_tpu.obs import recorder as _rec_mod
-    from deequ_tpu.obs.recorder import (
-        FlightRecorder,
-        current_recorder,
-        maybe_arm_from_env,
-        recording_scope,
-    )
-
-    run_suites = _config1_suites(n_rows)
-
-    # disarmed-is-free (structural): nothing is armed anywhere — not
-    # here, and not as a side effect of running. Arm from the env
-    # FIRST: the global recorder is created lazily, so a bench
-    # environment leaking DEEQU_TPU_TRACE=1 would otherwise pass the
-    # disarmed assert and then arm itself during warmup, turning the
-    # A/B into armed-vs-armed.
-    maybe_arm_from_env()
-    assert current_recorder() is None, (
-        "obs probe must start disarmed (a leaked recording_scope or "
-        "DEEQU_TPU_TRACE in the bench environment?)"
-    )
-    run_suites()  # warmup: compile the fused program
-    # the disarmed run must leave the process structurally disarmed:
-    # the module armed-counter at zero (every seam's fast path is one
-    # read of it) and no global recorder installed as a side effect
-    assert _rec_mod._armed == 0 and _rec_mod.global_recorder() is None, (
-        "a disarmed run armed the flight recorder as a side effect"
-    )
-
-    def armed():
-        # a fresh bounded recorder per trial: steady-state armed cost,
-        # not the cost of an ever-growing ring
-        rec = FlightRecorder(capacity=1 << 14)
-        with recording_scope(rec):
-            wall = run_suites()
-        assert len(rec) > 0, "armed run recorded no spans"
-        return wall
-
-    frac, skip = _stable_overhead_frac(
-        run_suites, armed, gate=0.01, what="obs tracing"
-    )
-    assert _rec_mod._armed == 0 and _rec_mod.global_recorder() is None, (
-        "the armed trials leaked arming past their scopes"
-    )
-    if skip is not None:
-        return {
-            "obs_overhead_frac": None,
-            "obs_overhead_skipped": skip,
-            "obs_disarmed_armed_counter": _rec_mod._armed,
-        }
-    return {
-        "obs_overhead_frac": round(frac, 4),
-        "obs_disarmed_armed_counter": _rec_mod._armed,
-    }
-
-
 def measure_oom_bisection_overhead(n_rows: int):
     """Device-fault degradation cost probe: the same in-memory analysis
     timed clean vs with a seeded device OOM injected on its first attempt
@@ -3189,11 +3119,6 @@ def main():
         SMOKE_ROWS if smoke else 200_000
     )
     print(f"governance probe: {governance_probe}", file=sys.stderr)
-    # observability probe (round 11): armed-vs-disarmed flight-recorder
-    # A/B on the same config-1 shape — <1% armed, structurally zero
-    # disarmed (asserted inside)
-    obs_probe = measure_obs_overhead(SMOKE_ROWS if smoke else 200_000)
-    print(f"obs probe: {obs_probe}", file=sys.stderr)
     # serving-layer probe (round 10): the 1k-tenant open-loop load with
     # the bit-identity / zero-trace / one-fetch-per-batch / >=5x gates
     # asserted inside
@@ -3231,7 +3156,7 @@ def main():
     print(f"windowed-stream probe: {wstream_probe}", file=sys.stderr)
     ckpt_probe = {
         **ckpt_probe, **oom_probe, **reshard_probe, **select_probe,
-        **lint_probe, **ingest_probe, **governance_probe, **obs_probe,
+        **lint_probe, **ingest_probe, **governance_probe,
         **serving_probe, **fleet_probe, **fencing_probe,
         **repo_probe, **kernel_probe, **wstream_probe,
     }

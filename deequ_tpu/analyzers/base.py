@@ -28,6 +28,7 @@ from deequ_tpu.exceptions import (
     wrap_if_necessary,
 )
 from deequ_tpu.metrics import DoubleMetric, Entity, Metric
+from deequ_tpu.obs.recorder import seam
 from deequ_tpu.tryresult import Failure, Success
 
 S = TypeVar("S", bound="State")
@@ -200,15 +201,9 @@ class Analyzer(ABC):
     def calculate_metric(
         self, state: Optional[State], aggregate_with=None, save_states_with=None
     ) -> Metric:
-        try:
-            if aggregate_with is not None:
-                loaded = aggregate_with.load(self)
-                state = merge_states(state, loaded)
-            if save_states_with is not None and state is not None:
-                save_states_with.persist(self, state)
-            return self.compute_metric_from(state)
-        except Exception as e:  # noqa: BLE001
-            return self.to_failure_metric(wrap_if_necessary(e))
+        return metrics_from_states(
+            [(self, state)], aggregate_with, save_states_with
+        )[0]
 
     def aggregate_state_to(self, source_a, source_b, target) -> None:
         """Merge states from two loaders into a persister (reference L130-147)."""
@@ -233,6 +228,44 @@ class Analyzer(ABC):
     @property
     def name(self) -> str:
         return type(self).__name__
+
+
+def metrics_from_states(
+    pairs, aggregate_with=None, save_states_with=None
+) -> list:
+    """One metric per ``(analyzer, state)`` pair, in order, in two
+    phases so that each is ONE seam whatever the number of analyzers:
+    ``states`` (load what ``aggregate_with`` holds, merge, persist to
+    ``save_states_with``; not opened when there is neither) and
+    ``evaluate`` (the metric from the merged state). A failure anywhere
+    is that analyzer's failure metric, as data."""
+    pairs = list(pairs)
+    states = [state for _, state in pairs]
+    metrics: List[Optional[Metric]] = [None] * len(pairs)
+    if aggregate_with is not None or save_states_with is not None:
+        with seam("states", analyzers=len(pairs)):
+            for i, (analyzer, state) in enumerate(pairs):
+                try:
+                    if aggregate_with is not None:
+                        state = merge_states(
+                            state, aggregate_with.load(analyzer)
+                        )
+                    if save_states_with is not None and state is not None:
+                        save_states_with.persist(analyzer, state)
+                    states[i] = state
+                except Exception as e:  # noqa: BLE001
+                    metrics[i] = analyzer.to_failure_metric(
+                        wrap_if_necessary(e)
+                    )
+    with seam("evaluate", analyzers=len(pairs)):
+        for i, (analyzer, _) in enumerate(pairs):
+            if metrics[i] is not None:
+                continue
+            try:
+                metrics[i] = analyzer.compute_metric_from(states[i])
+            except Exception as e:  # noqa: BLE001
+                metrics[i] = analyzer.to_failure_metric(wrap_if_necessary(e))
+    return metrics
 
 
 def merge_states(a: Optional[State], b: Optional[State]) -> Optional[State]:

@@ -29,6 +29,7 @@ from deequ_tpu.analyzers.base import (
     State,
     find_first_failing,
     merge_states,
+    metrics_from_states,
 )
 from deequ_tpu.analyzers.grouping import (
     FrequenciesAndNumRows,
@@ -47,6 +48,7 @@ from deequ_tpu.exceptions import (
     wrap_if_necessary,
 )
 from deequ_tpu.metrics import DoubleMetric, Metric
+from deequ_tpu.obs.recorder import seam
 from deequ_tpu.ops.scan_engine import run_scan
 
 
@@ -151,6 +153,13 @@ def _release_spill(folder) -> None:
         store.release()
 
 
+def _put_metrics(ctx, pairs, aggregate_with, save_states_with):
+    """``ctx`` with the metric of every ``(analyzer, state)`` pair."""
+    metrics = metrics_from_states(pairs, aggregate_with, save_states_with)
+    ctx.metric_map.update(zip((a for a, _ in pairs), metrics))
+    return ctx
+
+
 def _save_or_append_result(metrics_repository, result_key, ctx) -> None:
     """Append ctx's metrics into the repository entry for result_key — the
     ONE copy of the load-combine-save sequence every runner path shares."""
@@ -158,11 +167,12 @@ def _save_or_append_result(metrics_repository, result_key, ctx) -> None:
         return
     from deequ_tpu.repository import AnalysisResult
 
-    existing = metrics_repository.load_by_key(result_key)
-    combined = (
-        (existing.analyzer_context + ctx) if existing is not None else ctx
-    )
-    metrics_repository.save(AnalysisResult(result_key, combined))
+    with seam("repository"):
+        existing = metrics_repository.load_by_key(result_key)
+        combined = (
+            (existing.analyzer_context + ctx) if existing is not None else ctx
+        )
+        metrics_repository.save(AnalysisResult(result_key, combined))
 
 
 class AnalysisRunner:
@@ -260,61 +270,62 @@ class AnalysisRunner:
                         shard_deadline=shard_deadline,
                     )
 
-        analyzers = list(analyzers)
+        with seam("plan", what="partition analyzers"):
+            analyzers = list(analyzers)
 
-        # an explicit retry policy must cover EVERY streaming path, not
-        # just the resilient branch: wrap the handle so the fused scan,
-        # grouping folds, and own-pass loops all read through it (the
-        # resilient loop's exhaustion handling recognizes the wrapper's
-        # RetryExhaustedException, so retries never multiply)
-        if retry_policy is not None and hasattr(data, "with_retry"):
-            data = data.with_retry(retry_policy)
+            # an explicit retry policy must cover EVERY streaming path, not
+            # just the resilient branch: wrap the handle so the fused scan,
+            # grouping folds, and own-pass loops all read through it (the
+            # resilient loop's exhaustion handling recognizes the wrapper's
+            # RetryExhaustedException, so retries never multiply)
+            if retry_policy is not None and hasattr(data, "with_retry"):
+                data = data.with_retry(retry_policy)
 
-        # (1) repository reuse (reference L116-134)
-        results_loaded = AnalyzerContext.empty()
-        if metrics_repository is not None and reuse_existing_results_for_key is not None:
-            existing = metrics_repository.load_by_key(reuse_existing_results_for_key)
-            if existing is not None:
-                loaded = {
-                    a: m
-                    for a, m in existing.analyzer_context.metric_map.items()
-                    if a in analyzers
-                }
-                results_loaded = AnalyzerContext(loaded)
-        remaining = [a for a in analyzers if a not in results_loaded.metric_map]
-        if fail_if_results_missing and remaining:
-            raise ReusingNotPossibleResultsMissingException(
-                "Could not find all necessary results in the MetricsRepository, "
-                f"the calculation of the metrics for these analyzers would be "
-                f"needed: {', '.join(str(a) for a in remaining)}"
-            )
+            # (1) repository reuse (reference L116-134)
+            results_loaded = AnalyzerContext.empty()
+            if metrics_repository is not None and reuse_existing_results_for_key is not None:
+                existing = metrics_repository.load_by_key(reuse_existing_results_for_key)
+                if existing is not None:
+                    loaded = {
+                        a: m
+                        for a, m in existing.analyzer_context.metric_map.items()
+                        if a in analyzers
+                    }
+                    results_loaded = AnalyzerContext(loaded)
+            remaining = [a for a in analyzers if a not in results_loaded.metric_map]
+            if fail_if_results_missing and remaining:
+                raise ReusingNotPossibleResultsMissingException(
+                    "Could not find all necessary results in the MetricsRepository, "
+                    f"the calculation of the metrics for these analyzers would be "
+                    f"needed: {', '.join(str(a) for a in remaining)}"
+                )
 
-        # (2) precondition partition (reference L137-145)
-        passed: List[Analyzer] = []
-        failure_ctx = AnalyzerContext.empty()
-        for analyzer in remaining:
-            exc = find_first_failing(data.schema, analyzer.preconditions())
-            if exc is None:
-                passed.append(analyzer)
-            else:
-                failure_ctx.metric_map[analyzer] = analyzer.to_failure_metric(exc)
+            # (2) precondition partition (reference L137-145)
+            passed: List[Analyzer] = []
+            failure_ctx = AnalyzerContext.empty()
+            for analyzer in remaining:
+                exc = find_first_failing(data.schema, analyzer.preconditions())
+                if exc is None:
+                    passed.append(analyzer)
+                else:
+                    failure_ctx.metric_map[analyzer] = analyzer.to_failure_metric(exc)
 
-        # (3) split (reference L148-153)
-        grouping = [a for a in passed if _is_grouping_shared(a)]
-        scanning = [
-            a
-            for a in passed
-            if isinstance(a, ScanShareableAnalyzer) and not _is_grouping_shared(a)
-        ]
-        own_pass = [a for a in passed if a not in grouping and a not in scanning]
+            # (3) split (reference L148-153)
+            grouping = [a for a in passed if _is_grouping_shared(a)]
+            scanning = [
+                a
+                for a in passed
+                if isinstance(a, ScanShareableAnalyzer) and not _is_grouping_shared(a)
+            ]
+            own_pass = [a for a in passed if a not in grouping and a not in scanning]
 
-        # grouping analyzers share one frequency fold per distinct sorted
-        # grouping-column set — ONE partition rule for both the resilient
-        # branch below and step (5)
-        by_grouping: Dict[Tuple[str, ...], List[FrequencyBasedAnalyzer]] = {}
-        for analyzer in grouping:
-            key = tuple(sorted(analyzer.group_columns))
-            by_grouping.setdefault(key, []).append(analyzer)
+            # grouping analyzers share one frequency fold per distinct sorted
+            # grouping-column set — ONE partition rule for both the resilient
+            # branch below and step (5)
+            by_grouping: Dict[Tuple[str, ...], List[FrequencyBasedAnalyzer]] = {}
+            for analyzer in grouping:
+                key = tuple(sorted(analyzer.group_columns))
+                by_grouping.setdefault(key, []).append(analyzer)
 
         # resilient streaming pass: checkpoint/resume and batch quarantine
         # need per-batch fold state on the host, so ALL analyzers share one
@@ -509,15 +520,17 @@ class AnalysisRunner:
         ctx = AnalyzerContext.empty()
         if not analyzers:
             return ctx, [], [], None
-        ops, scannable, op_failures = AnalysisRunner._build_scan_ops(
-            data, analyzers
-        )
+        with seam("plan", what="scan ops"):
+            ops, scannable, op_failures = AnalysisRunner._build_scan_ops(
+                data, analyzers
+            )
         for analyzer, err in op_failures.items():
             ctx.metric_map[analyzer] = analyzer.to_failure_metric(err)
         if not scannable:
             return ctx, [], [], None
         try:
-            exec_ops, plan = AnalysisRunner._coalesce_scan_ops(ops)
+            with seam("plan", what="coalesce scan ops"):
+                exec_ops, plan = AnalysisRunner._coalesce_scan_ops(ops)
             scan = run_scan(
                 data, exec_ops, defer=defer,
                 on_device_error=on_device_error,
@@ -554,20 +567,29 @@ class AnalysisRunner:
         aggregate_with=None,
         save_states_with=None,
     ) -> AnalyzerContext:
-        for analyzer, (exec_idx, extract) in zip(scannable, plan):
-            try:
-                result = results[exec_idx]
-                if extract is not None:
-                    result = extract(result)
-                state = analyzer.state_from_scan_result(result)
-            except Exception as e:  # noqa: BLE001
-                ctx.metric_map[analyzer] = analyzer.to_failure_metric(
-                    wrap_if_necessary(e)
-                )
-                continue
-            ctx.metric_map[analyzer] = analyzer.calculate_metric(
-                state, aggregate_with, save_states_with
-            )
+        metrics = [None] * len(scannable)
+        pairs, slots = [], []
+        with seam("evaluate", what="states from scan results"):
+            for i, (analyzer, (exec_idx, extract)) in enumerate(
+                zip(scannable, plan)
+            ):
+                try:
+                    result = results[exec_idx]
+                    if extract is not None:
+                        result = extract(result)
+                    pairs.append(
+                        (analyzer, analyzer.state_from_scan_result(result))
+                    )
+                    slots.append(i)
+                except Exception as e:  # noqa: BLE001
+                    metrics[i] = analyzer.to_failure_metric(
+                        wrap_if_necessary(e)
+                    )
+        for i, metric in zip(
+            slots, metrics_from_states(pairs, aggregate_with, save_states_with)
+        ):
+            metrics[i] = metric
+        ctx.metric_map.update(zip(scannable, metrics))
         return ctx
 
     @staticmethod
@@ -698,6 +720,7 @@ class AnalysisRunner:
             )
 
         ctx = AnalyzerContext.empty()
+        pairs = []
         for a in analyzers:
             if a in failed:
                 ctx.metric_map[a] = a.to_failure_metric(
@@ -706,9 +729,8 @@ class AnalysisRunner:
                 # a failed fold's result() never runs: free its spill dir
                 _release_spill(folders[a])
             else:
-                ctx.metric_map[a] = a.calculate_metric(
-                    folders[a].result(), aggregate_with, save_states_with
-                )
+                pairs.append((a, folders[a].result()))
+        _put_metrics(ctx, pairs, aggregate_with, save_states_with)
         return ctx
 
     @staticmethod
@@ -1126,6 +1148,7 @@ class AnalysisRunner:
             return ctx
 
         ctx = AnalyzerContext.empty()
+        pairs = []
         for a in per_analyzer:
             if a in failed:
                 ctx.metric_map[a] = failed[a]
@@ -1133,9 +1156,7 @@ class AnalysisRunner:
                 # directory now instead of waiting on GC finalizers
                 _release_spill(folders[keys[a]])
             else:
-                ctx.metric_map[a] = a.calculate_metric(
-                    folders[keys[a]].result(), aggregate_with, save_states_with
-                )
+                pairs.append((a, folders[keys[a]].result()))
         for g, group_analyzers in by_grouping.items():
             if g in failed_groups:
                 for a in group_analyzers:
@@ -1143,10 +1164,8 @@ class AnalysisRunner:
                 _release_spill(folders[group_keys[g]])
             else:
                 merged = folders[group_keys[g]].result()
-                for a in group_analyzers:
-                    ctx.metric_map[a] = a.calculate_metric(
-                        merged, aggregate_with, save_states_with
-                    )
+                pairs.extend((a, merged) for a in group_analyzers)
+        _put_metrics(ctx, pairs, aggregate_with, save_states_with)
         ctx.skipped_batches = list(skipped)
         if checkpoint is not None:
             # the run completed: a later run of this directory must start
@@ -1258,12 +1277,10 @@ class AnalysisRunner:
                 return AnalyzerContext(
                     {a: a.to_failure_metric(wrapped) for a in analyzers}
                 )
-            ctx = AnalyzerContext.empty()
-            for analyzer in analyzers:
-                ctx.metric_map[analyzer] = analyzer.calculate_metric(
-                    merged, aggregate_with, save_states_with
-                )
-            return ctx
+            return _put_metrics(
+                AnalyzerContext.empty(), [(a, merged) for a in analyzers],
+                aggregate_with, save_states_with,
+            )
 
         # count-stats fast path: when nobody needs the materialized
         # frequency table (no state persistence/merge, and every analyzer
@@ -1322,12 +1339,10 @@ class AnalysisRunner:
             return AnalyzerContext(
                 {a: a.to_failure_metric(wrapped) for a in analyzers}
             )
-        ctx = AnalyzerContext.empty()
-        for analyzer in analyzers:
-            ctx.metric_map[analyzer] = analyzer.calculate_metric(
-                state, aggregate_with, save_states_with
-            )
-        return ctx
+        return _put_metrics(
+            AnalyzerContext.empty(), [(a, state) for a in analyzers],
+            aggregate_with, save_states_with,
+        )
 
     @staticmethod
     def run_on_aggregated_states(

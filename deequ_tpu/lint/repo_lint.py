@@ -43,15 +43,17 @@ Rules (stable ids; all severity "error" — the repo pass is a CI gate):
   ``deequ_tpu.exceptions`` taxonomy (or a precise builtin like
   ``ValueError`` for argument validation), never the generic classes the
   fault ladder cannot dispatch on.
-- ``span-in-jit`` — flight-recorder emission (``<recorder>.span(...)``
-  / ``.event(...)`` / ``.record_span(...)``, ``current_recorder()``,
-  ``recording_scope(...)``) inside a function that is jitted or traced
+- ``span-in-jit`` — seam or flight-recorder emission (``seam(...)``,
+  ``<recorder>.span(...)`` / ``.event(...)`` / ``.record_span(...)``,
+  ``current_recorder()``, ``recording_scope(...)``) inside a function
+  that is jitted or traced
   (the same traced-function set ``jit-impure`` computes): a span
   emitted from traced code is a host callback by another name — it
   bakes one trace-time record into the cached program and re-fires (or
   worse, doesn't) on every replay, exactly the ``jit-impure`` failure
-  class. Spans belong at the HOST seams around the program
-  (``device_call``, the packing loops), never inside it.
+  class. Seams belong at the HOST boundaries around the program
+  (``device_call``, the packing loops), never inside it; what names the
+  device side is ``jax.named_scope`` (metadata only, never flagged).
 - ``durable-write`` — raw durable-write shapes in ``serve/``,
   ``repository/``, ``control/``, ``resilience/``: ``open(..., "w"/"wb")``
   (any write-mode open, builtin or ``fs.open``), ``os.fsync(...)``, and
@@ -218,15 +220,17 @@ def _is_tracing_ref(parts: List[str]) -> bool:
 
 _GENERIC_RAISES = frozenset(("Exception", "RuntimeError", "BaseException"))
 
-#: flight-recorder emission shapes for the span-in-jit rule: attribute
-#: calls any recorder object exposes (``rec.span`` / ``.event`` /
-#: ``.record_span``) and the ambient-arming module functions. Like
+#: emission shapes for the span-in-jit rule: ``seam(...)`` (the one
+#: duration primitive), attribute calls any recorder object exposes
+#: (``rec.span`` / ``.event`` / ``.record_span``) and the
+#: ambient-arming module functions. Like
 #: host-fetch, a convention checker over names — an unrelated
 #: ``.event()`` method on another object inside traced code would
 #: false-positive and takes a per-line annotated ignore.
 _SPAN_EMIT_ATTRS = frozenset(("span", "event", "record_span"))
 _SPAN_EMIT_FNS = frozenset(
-    ("current_recorder", "recording_scope", "maybe_arm_from_env")
+    ("seam", "worker_seams", "current_recorder", "recording_scope",
+     "maybe_arm_from_env")
 )
 
 

@@ -2,10 +2,13 @@
 
 Three pieces (docs/observability.md):
 
-- :mod:`~deequ_tpu.obs.recorder` — typed, monotonic-clock span/event
-  records at every engine seam; ring-buffer bounded; OFF by default and
-  armed via ``run_scan(trace=...)`` /
-  ``VerificationRunBuilder.with_tracing()`` / ``DEEQU_TPU_TRACE=1``;
+- :mod:`~deequ_tpu.obs.recorder` — ``seam``, the one way the engine
+  takes a duration (exclusive seconds into ``SCAN_STATS.seam_*``, a
+  ``deequ.<name>`` annotation on the profiler's clock, a recorder span
+  when armed), and the flight recorder those spans land on: typed
+  span/event records, ring-buffer bounded, OFF by default and armed via
+  ``run_scan(trace=...)`` / ``VerificationRunBuilder.with_tracing()`` /
+  ``DEEQU_TPU_TRACE=1``;
 - :mod:`~deequ_tpu.obs.export` — Chrome-trace/Perfetto JSON export of a
   recording (one track per thread, nested spans, instant events for
   fault rungs and budget charges);
@@ -19,6 +22,7 @@ Three pieces (docs/observability.md):
 from deequ_tpu.obs.export import to_chrome_trace, write_chrome_trace
 from deequ_tpu.obs.recorder import (
     DEFAULT_CAPACITY,
+    SEAM_NAMES,
     FlightRecorder,
     SpanRecord,
     current_recorder,
@@ -27,6 +31,8 @@ from deequ_tpu.obs.recorder import (
     maybe_arm_from_env,
     recording_scope,
     resolve_recorder,
+    seam,
+    seam_fields,
 )
 from deequ_tpu.obs.registry import (
     REGISTRY,
@@ -39,6 +45,7 @@ from deequ_tpu.obs.registry import (
 
 __all__ = [
     "DEFAULT_CAPACITY",
+    "SEAM_NAMES",
     "FlightRecorder",
     "SpanRecord",
     "current_recorder",
@@ -47,6 +54,8 @@ __all__ = [
     "maybe_arm_from_env",
     "recording_scope",
     "resolve_recorder",
+    "seam",
+    "seam_fields",
     "to_chrome_trace",
     "write_chrome_trace",
     "REGISTRY",

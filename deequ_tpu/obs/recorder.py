@@ -1,23 +1,45 @@
-"""Run flight recorder — typed, monotonic-clock span/event records.
+"""The seam primitive and the run flight recorder.
 
-The engine's counters (``ScanStats``, ``RETRY_TELEMETRY``, ``RunBudget``
-ledgers) say *how much* happened; nothing says *when*. The flight
-recorder is the timeline half of the observability layer: every seam the
-ladder already owns — program trace, plan lint, double-buffer staging,
-dispatch, drain/fetch, each fault-ladder rung, budget charges,
-coalesced-batch assembly, per-tenant serve submit→resolve — emits a
+:class:`seam` is the ONE way the engine takes a duration. ``with
+seam("pack", bytes=n):`` at a layer boundary does three things:
+
+1. **Counter, always on.** The seam's EXCLUSIVE seconds (a per-thread
+   stack: while a child seam is open the parent's clock stands still)
+   add to ``SCAN_STATS.seam_<name>_seconds`` and 1 to
+   ``seam_<name>_count`` on ``time.perf_counter()`` — a dot in a seam's
+   name is an underscore in its field. The four device-wait seams also
+   feed the two older fields with what those always held:
+   ``dispatch_seconds`` = ``stage`` + ``dispatch``,
+   ``drain_wait_seconds`` = ``drain`` + ``fetch``. The enclosing seams
+   (``run``, ``scan_attempt``) have no exclusive counter;
+   ``scan_attempt`` adds its whole duration to ``scan_seconds``. Only
+   caller threads count: the engine's worker threads (prefetch reader,
+   watchdog pool) mark themselves with :func:`worker_seams` and emit
+   spans only — the caller's wait for them is a seam of its own.
+2. **Span on the profiler's clock.** It enters
+   ``jax.profiler.TraceAnnotation("deequ.<name>", **args)``: inside any
+   profiler session the seam lands in the ``.xplane.pb`` beside the
+   device's events; outside one it costs a flag check.
+3. **Record, when armed.** If :func:`current_recorder` is not None the
+   seam opens a :class:`FlightRecorder` span (parent, track, args).
+
+Spans of one verification run share ``run_id`` and spans of one scan
+``scan_id``: an enclosing seam given either hands it down to every seam
+opened under it (args of 2 and 3). docs/observability.md lists every
+seam, its layer, its counter and the metric that reads it.
+
+The flight recorder is the timeline the seams (and the instant events:
+each fault-ladder rung, budget charges, serve submit→resolve) land on: a
 typed :class:`SpanRecord` into a ring-buffer-bounded recorder when one
-is armed, and does nothing (one module-global integer check) when none
-is.
+is armed, nothing (one module-global integer check) when none is.
 
 Design constraints, in order:
 
-1. **Disarmed is free.** Tracing is OFF by default; the disarmed fast
-   path is ``current_recorder()`` returning ``None`` after reading one
-   module-global counter — no allocation, no lock, no thread-local
-   lookup. bench.py's ``measure_obs_overhead`` hard-asserts that a
-   disarmed run records nothing and an armed healthy run costs <1% of
-   wall.
+1. **Disarmed is cheap.** Recording is OFF by default; the disarmed
+   path of a seam is two clock reads, the profiler's flag and
+   ``current_recorder()`` returning ``None`` after reading one
+   module-global counter — no lock, no recorder allocation. What that
+   costs on the chip is measured, not asserted: PERF.md §6 (PR 24).
 2. **Bounded.** Records land in a ring buffer (``capacity`` spans); a
    saturated recorder drops the OLDEST records and counts the drops —
    a long-lived traced service degrades to a rolling window, never to
@@ -58,6 +80,8 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: default ring capacity — ~64k records is minutes of traced serving
 #: traffic at a few hundred spans/suite, a few MB of host memory
@@ -268,8 +292,8 @@ class FlightRecorder:
         ``VerificationResult.run_trace`` payload. Spans aggregate by
         name (count + total wall seconds); instant events aggregate by
         name (count). The dispatch/fetch phase sums reconcile with
-        ``ScanStats.dispatch_seconds`` / ``drain_wait_seconds`` — both
-        instrument the same device boundaries.
+        ``ScanStats.dispatch_seconds`` / ``drain_wait_seconds`` — one
+        :class:`seam` writes both.
 
         ``since`` (a ``time.monotonic()`` stamp) restricts the summary
         to records STARTED at or after it — a shared or env-armed
@@ -445,3 +469,176 @@ def resolve_recorder(trace=None) -> Optional[FlightRecorder]:
         f"trace must be a FlightRecorder, True, False or None, "
         f"got {trace!r}"
     )
+
+
+# -- the seam primitive ------------------------------------------------------
+
+#: every counted seam, in pipeline order (docs/observability.md has the
+#: table: layer, where it is emitted, the metric that reads it)
+SEAM_NAMES = (
+    "plan", "build", "pack", "stage", "dispatch", "drain", "fetch",
+    "states", "evaluate", "repository",
+    "persist.pack", "persist.stage", "grouping.host",
+)
+#: seams whose exclusive seconds ALSO feed an older ScanStats field,
+#: with exactly what that field always held
+_LEGACY_FIELD = {
+    "stage": "dispatch_seconds",
+    "dispatch": "dispatch_seconds",
+    "drain": "drain_wait_seconds",
+    "fetch": "drain_wait_seconds",
+}
+#: enclosing seams: no exclusive counter (their own time is what no
+#: named seam accounts for); the value is the field their WHOLE
+#: duration adds to, if any
+_ENCLOSING = {"run": None, "scan_attempt": "scan_seconds"}
+#: the ids an enclosing seam hands down to the seams under it
+_ID_ARGS = ("run_id", "scan_id")
+_UNSET = object()
+
+RUN_IDS = itertools.count(1)
+
+
+def seam_fields(name: str):
+    """``(seconds_field, count_field)`` of a counted seam."""
+    stem = "seam_" + name.replace(".", "_")
+    return stem + "_seconds", stem + "_count"
+
+
+#: name -> (seconds field, count field, legacy field or None)
+_COUNTED = {
+    name: seam_fields(name) + (_LEGACY_FIELD.get(name),)
+    for name in SEAM_NAMES
+}
+
+# the counters' home (``SCAN_STATS``): bound by ops/scan_engine.py when it
+# creates the singleton — this module is imported by it, not the reverse
+_STATS = None
+_SEAM_TLS = threading.local()
+
+
+class _ThreadSeams:
+    """One thread's seam state: the innermost open counted seam, whether
+    the thread is an engine worker (spans only), and the ids handed
+    down by the enclosing seams."""
+
+    __slots__ = ("open", "worker", "ids")
+
+    def __init__(self):
+        self.open = None
+        self.worker = False
+        self.ids = None
+
+
+def _thread_seams() -> _ThreadSeams:
+    try:
+        return _SEAM_TLS.state
+    except AttributeError:
+        state = _SEAM_TLS.state = _ThreadSeams()
+        return state
+
+
+def bind_seam_counters(stats) -> None:
+    """Name the object whose ``seam_*`` fields the seams add to."""
+    global _STATS
+    _STATS = stats
+
+
+def seam_ids() -> Dict[str, Any]:
+    """The ``run_id`` / ``scan_id`` in force on this thread (what a
+    worker-thread scope should be seeded with)."""
+    return dict(_thread_seams().ids or {})
+
+
+@contextmanager
+def worker_seams(ids: Optional[Dict[str, Any]] = None) -> Iterator[None]:
+    """Mark this thread as an engine WORKER for the block: its seams
+    are spans only (the caller thread's wait for the worker is already a
+    counted seam, so counting here would count the time twice). ``ids``
+    seeds the caller's ``run_id`` / ``scan_id``."""
+    state = _thread_seams()
+    prev = (state.worker, state.ids)
+    state.worker = True
+    if ids:
+        state.ids = ids
+    try:
+        yield
+    finally:
+        state.worker, state.ids = prev
+
+
+class seam:
+    """One layer-boundary duration (see the module doc): exclusive
+    seconds into ``SCAN_STATS``, a ``deequ.<name>`` annotation on the
+    profiler's clock, a recorder span when armed. Host-side only — a
+    seam inside traced code is a host callback (``span-in-jit``)."""
+
+    __slots__ = ("name", "args", "_state", "_t0", "_resumed", "_own",
+                 "_outer", "_annotation", "_span", "_ids_before")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "seam":
+        state = self._state = _thread_seams()
+        self._annotation = None
+        if _armed or TraceAnnotation.is_enabled():
+            self._enter_spans(state)
+        if not state.worker:
+            now = time.perf_counter()
+            self._outer = outer = state.open
+            if outer is not None:
+                outer._own += now - outer._resumed
+            state.open = self
+            self._own = 0.0
+            self._t0 = self._resumed = now
+        return self
+
+    def _enter_spans(self, state: _ThreadSeams) -> None:
+        """The profiler annotation and the recorder span, with the ids
+        of the enclosing seams among their args."""
+        name, args = self.name, self.args
+        ids = state.ids
+        self._ids_before = _UNSET
+        if name in _ENCLOSING:
+            mine = {k: args[k] for k in _ID_ARGS if k in args}
+            if mine:
+                self._ids_before = ids
+                state.ids = {**(ids or {}), **mine}
+        if ids:
+            args = {**ids, **args}
+        rec = current_recorder()
+        self._span = rec.span(name, **args) if rec is not None else None
+        self._annotation = TraceAnnotation("deequ." + name, **args)
+        self._annotation.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        state = self._state
+        if not state.worker:
+            now = time.perf_counter()
+            outer = self._outer
+            state.open = outer
+            if outer is not None:
+                outer._resumed = now
+            stats = _STATS
+            if stats is not None:
+                counted = _COUNTED.get(self.name)
+                if counted is not None:
+                    own = self._own + (now - self._resumed)
+                    seconds, count, legacy = counted
+                    setattr(stats, seconds, getattr(stats, seconds) + own)
+                    setattr(stats, count, getattr(stats, count) + 1)
+                    if legacy is not None:
+                        setattr(stats, legacy, getattr(stats, legacy) + own)
+                else:
+                    total = _ENCLOSING[self.name]  # KeyError: no such seam
+                    if total is not None:
+                        setattr(stats, total,
+                                getattr(stats, total) + (now - self._t0))
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            if self._span is not None:
+                self._span.__exit__(exc_type, exc, tb)
+            if self._ids_before is not _UNSET:
+                state.ids = self._ids_before

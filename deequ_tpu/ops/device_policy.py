@@ -47,7 +47,7 @@ from deequ_tpu.exceptions import (
     DeviceHangException,
     classify_device_error,
 )
-from deequ_tpu.obs.recorder import current_recorder
+from deequ_tpu.obs.recorder import seam, worker_seams
 
 # -- fault-injection seam ----------------------------------------------------
 
@@ -227,6 +227,12 @@ class _WatchdogPool:
         inbox: "queue.SimpleQueue" = queue.SimpleQueue()
 
         def loop():
+            # a watchdog worker's seams are spans only: the caller's wait
+            # for it is the counted seam (device_call opens it)
+            with worker_seams():
+                serve()
+
+        def serve():
             while True:
                 fn, box, done, state = inbox.get()
                 # publish the call state to this thread before running:
@@ -300,23 +306,39 @@ def _call_with_deadline(fn: Callable, deadline: float, what: str,
     return _WATCHDOG_POOL.call(fn, deadline, what, boundary)
 
 
+#: the seam each device boundary is (obs/recorder.py:seam); an
+#: ``execute`` call that only waits (a throttle) or that is a program's
+#: first, compiling call names ``drain`` / ``build`` itself
+_BOUNDARY_SEAM = {
+    "transfer": "stage",
+    "trace": "build",
+    "execute": "dispatch",
+    "fetch": "fetch",
+}
+
+
 def device_call(
     fn: Callable,
     boundary: str,
     what: str = "device call",
     deadline: Optional[float] = None,
     hook_ctx: Optional[Dict[str, Any]] = None,
+    seam_name: Optional[str] = None,
+    **span_args,
 ):
     """Run one device-boundary call under classification (+ optional
-    watchdog + optional fault injection).
+    watchdog + optional fault injection), inside the boundary's seam.
 
     Raw jaxlib/XLA failures re-raise as their typed DeviceException (with
     ``__cause__`` preserved); non-device errors propagate untouched.
     ``hook_ctx`` is passed only at the execute seam — the one place the
-    deterministic fault hook fires.
+    deterministic fault hook fires. The seam (``seam_name``, else the
+    boundary's: ``_BOUNDARY_SEAM``) opens on the CALLER thread (its
+    track), wrapping the watchdog wait too, so a hang shows as a long
+    span ending in a typed error; ``span_args`` go to the span.
 
-    Cost note: an armed deadline spawns one short-lived watchdog thread
-    per call (~0.1ms) — noise next to a device round trip, but reason
+    Cost note: an armed deadline hands the call to a pooled watchdog
+    thread (~0.1ms) — noise next to a device round trip, but reason
     enough that the watchdog is opt-in and off by default."""
     hook = _SCAN_FAULT_HOOK if hook_ctx is not None else None
 
@@ -325,7 +347,10 @@ def device_call(
             hook(boundary, hook_ctx)
         return fn()
 
-    def classified():
+    with seam(
+        seam_name or _BOUNDARY_SEAM[boundary],
+        boundary=boundary, what=what, **span_args,
+    ):
         try:
             if deadline is not None:
                 return _call_with_deadline(body, deadline, what, boundary)
@@ -339,17 +364,6 @@ def device_call(
             if typed is not None:
                 raise typed from e
             raise
-
-    # flight-recorder seam (deequ_tpu/obs): every device boundary is a
-    # span when a recorder is armed — the span opens on the CALLER
-    # thread (its track), wrapping the watchdog wait too, so a hang
-    # shows as a long span ending in a typed error. Disarmed cost: one
-    # module-global integer check.
-    rec = current_recorder()
-    if rec is not None:
-        with rec.span(boundary, what=what):
-            return classified()
-    return classified()
 
 
 # -- backend health ----------------------------------------------------------

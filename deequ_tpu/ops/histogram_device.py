@@ -368,10 +368,24 @@ def bincount_scatter(seg, num_segments: int, xp, weights=None, dtype=None):
     return zeros.at[safe].add(weights.astype(dtype), mode="drop")
 
 
+def _scoped(variant: str, kernel):
+    """``kernel`` under ``jax.named_scope("deequ.bincount.<variant>")``:
+    the name a device trace gives the XLA ops of that variant (metadata
+    only)."""
+    import jax
+
+    def scoped(seg, num_segments, xp, weights=None, dtype=None):
+        with jax.named_scope("deequ.bincount." + variant):
+            return kernel(seg, num_segments, xp, weights=weights,
+                          dtype=dtype)
+
+    return scoped
+
+
 _KERNELS = {
-    "scatter": bincount_scatter,
-    "onehot": bincount_onehot,
-    "pallas": bincount_pallas,
+    "scatter": _scoped("scatter", bincount_scatter),
+    "onehot": _scoped("onehot", bincount_onehot),
+    "pallas": _scoped("pallas", bincount_pallas),
 }
 
 

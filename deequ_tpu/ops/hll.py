@@ -371,19 +371,20 @@ def registers_from_idx_rank(idx, rank, valid, p: int, xp):
     rank = xp.where(valid, rank, 0)
     idx = xp.where(valid, idx, 0)
 
-    if xp is not np:
-        # TPU only: on CPU backends the one-hot matmul is a large
-        # memory/FLOP regression over scatter (no MXU to ride).
-        if (
-            idx.shape[0] >= _MXU_FOLD_MIN_ROWS
-            and jax.devices()[0].platform != "cpu"
-        ):
-            return _registers_mxu_fold(idx, rank, m, xp)
+    with jax.named_scope("deequ.hll.fold"):
+        if xp is not np:
+            # TPU only: on CPU backends the one-hot matmul is a large
+            # memory/FLOP regression over scatter (no MXU to ride).
+            if (
+                idx.shape[0] >= _MXU_FOLD_MIN_ROWS
+                and jax.devices()[0].platform != "cpu"
+            ):
+                return _registers_mxu_fold(idx, rank, m, xp)
 
-    regs = jax.ops.segment_max(
-        rank, idx, num_segments=m, indices_are_sorted=False
-    ).astype(xp.int32)
-    return xp.maximum(regs, 0)  # untouched segments fill with INT_MIN
+        regs = jax.ops.segment_max(
+            rank, idx, num_segments=m, indices_are_sorted=False
+        ).astype(xp.int32)
+        return xp.maximum(regs, 0)  # untouched segments fill with INT_MIN
 
 
 def registers_from_hashes(hashes, valid, p: int, xp):

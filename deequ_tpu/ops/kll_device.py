@@ -30,6 +30,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import numpy as np
 
 from deequ_tpu.ops.kll import KLLSketchState
@@ -81,7 +82,8 @@ def chunk_summary(x, valid, sketch_size: int, local_n: int, xp, lo=None):
         from deequ_tpu.ops.df32 import masked_extremum
 
         xf32 = xp.where(valid, x, xp.asarray(np.float32(np.inf)))
-        order = xp.argsort(xf32)
+        with jax.named_scope("deequ.sort.kll_summary"):
+            order = xp.argsort(xf32)
         sx_hi = xf32[order]
         sx_lo = xp.where(valid, lo, xp.asarray(np.float32(0.0)))[order]
 
@@ -92,7 +94,8 @@ def chunk_summary(x, valid, sketch_size: int, local_n: int, xp, lo=None):
         mx = masked_extremum(x, lo, valid, xp, "max")
     else:
         xf = xp.where(valid, x.astype(xp.float64), xp.inf)
-        sx = xp.sort(xf)
+        with jax.named_scope("deequ.sort.kll_summary"):
+            sx = xp.sort(xf)
 
         def gather_items(idx):
             return sx[idx]

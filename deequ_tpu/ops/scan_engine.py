@@ -48,14 +48,19 @@ from deequ_tpu.exceptions import (
     DeviceException,
     DeviceHangException,
     DeviceOOMException,
-    classify_device_error,
 )
 from deequ_tpu.expr.eval import Val
 from deequ_tpu.obs.recorder import (
+    SEAM_NAMES,
+    bind_seam_counters,
     current_recorder,
     maybe_arm_from_env,
     recording_scope,
     resolve_recorder,
+    seam,
+    seam_fields,
+    seam_ids,
+    worker_seams,
 )
 from deequ_tpu.ops.device_policy import (
     DEVICE_HEALTH,
@@ -292,13 +297,22 @@ class ScanStats:
         # array): the observable for the one-fetch-per-scan contract — a
         # multi-chunk device-folded scan must show exactly 1
         self.device_fetches = 0
-        # time spent issuing step dispatches (host-side enqueue; near zero
-        # unless the runtime backpressures) vs time blocked waiting for
-        # device results in drain. drain_wait ~= device compute + any
-        # in-flight transfer not hidden by the pipeline window; the gap
-        # between scan_seconds and (dispatch + drain_wait) is host packing.
+        # time spent issuing host->device transfers and step dispatches
+        # (host-side enqueue; near zero unless the runtime backpressures)
+        # vs time blocked waiting for device results. drain_wait ~=
+        # device compute + any in-flight transfer not hidden by the
+        # pipeline window. Both are written by the seams below:
+        # dispatch_seconds = stage + dispatch, drain_wait_seconds =
+        # drain + fetch; scan_seconds is the scan_attempt seams' wall.
         self.dispatch_seconds = 0.0
         self.drain_wait_seconds = 0.0
+        # every duration the engine takes (obs/recorder.py:seam): the
+        # EXCLUSIVE seconds of each seam and how often it opened, as
+        # flat numbers so a counter snapshot carries them
+        for name in SEAM_NAMES:
+            seconds, count = seam_fields(name)
+            setattr(self, seconds, 0.0)
+            setattr(self, count, 0)
         # out-of-core spill engine (deequ_tpu/spill): sorted runs written,
         # bytes moved to/from disk, merge cascade passes, and the largest
         # in-RAM grouping tail observed (the number the group memory
@@ -523,6 +537,7 @@ class ScanStats:
 
 
 SCAN_STATS = ScanStats()
+bind_seam_counters(SCAN_STATS)
 
 
 def _tag_reduce_np(tag: str, a, b):
@@ -1107,7 +1122,9 @@ def persist_table(
     )
     chunk = max(n_dev, ((chunk + n_dev - 1) // n_dev) * n_dev)
 
-    packer = _ChunkPacker(cols, chunk, encode_ingest=encode)
+    with seam("persist.pack", what="layout"):
+        # the layout check reads every column once (range, nulls)
+        packer = _ChunkPacker(cols, chunk, encode_ingest=encode)
     put = _make_put(mesh)
 
     n_chunks = max(1, (n_rows + chunk - 1) // chunk)
@@ -1116,15 +1133,19 @@ def persist_table(
     for ci in range(n_chunks):
         start = ci * chunk
         stop = min(start + chunk, n_rows)
-        args = packer.pack(start, stop)
-        nbytes += sum(a.nbytes for a in args)
+        with seam("persist.pack", chunk=ci):
+            args = packer.pack(start, stop)
+        chunk_bytes = sum(a.nbytes for a in args)
+        nbytes += chunk_bytes
         if nbytes + total_resident_bytes() > max_bytes:
             raise MemoryError(
                 f"persist_table: combined resident size would exceed "
                 f"{max_bytes} bytes; stream instead or raise max_bytes"
             )
-        device_chunks.append(put(args))
-    jax.block_until_ready(device_chunks)
+        with seam("persist.stage", chunk=ci, bytes=chunk_bytes):
+            device_chunks.append(put(args))
+    with seam("persist.stage", wait=True):
+        jax.block_until_ready(device_chunks)
     cache = DeviceTableCache(packer, chunk, device_chunks, mesh, nbytes, n_dev)
     table._device_cache = cache
     return cache
@@ -1161,6 +1182,28 @@ def _split_lut_key(key: str) -> Tuple[str, str]:
     return col, kind
 
 
+def op_scope(op: "ScanOp") -> str:
+    """The ``jax.named_scope`` of one op's reductions in the fused step,
+    ``deequ.<Analyzer>.<column>[_<column>]``: what a device trace calls
+    the XLA ops it lowers to (metadata only: no operation changes).
+    ``cache_key`` is the analyzer that built the op (or a tuple whose
+    head names a coalesced kernel)."""
+    key = op.cache_key
+    if key is None:
+        label = "op"
+    elif isinstance(key, tuple):
+        label = str(key[0])
+    else:
+        label = type(key).__name__
+    parts = ["deequ", label] + (["_".join(op.columns)] if op.columns else [])
+    return ".".join(parts).replace("/", "_")
+
+
+def _scoped_update(op: "ScanOp", vals, row_valid, local_n):
+    with jax.named_scope(op_scope(op)):
+        return op.update(vals, row_valid, jnp, local_n)
+
+
 def _build_step_fns(ops, unpacker, mesh, local_n, lut_keys: Tuple[str, ...] = ()):
     """Build (jitted flat step fn, shape fn, raw flat fn) for one packer
     layout — the raw (unjitted) flat fn is what the fused resident
@@ -1180,11 +1223,14 @@ def _build_step_fns(ops, unpacker, mesh, local_n, lut_keys: Tuple[str, ...] = ()
         for key, arr in luts.items():
             col, kind = _split_lut_key(key)
             col_luts.setdefault(col, {})[kind] = arr
-        vals = unpacker.unpack_vals(
-            values, hi, lo, narrow_i, masks, codes, jnp, row_valid,
-            col_luts=col_luts, enc=enc,
+        with jax.named_scope("deequ.unpack"):
+            vals = unpacker.unpack_vals(
+                values, hi, lo, narrow_i, masks, codes, jnp, row_valid,
+                col_luts=col_luts, enc=enc,
+            )
+        partials = tuple(
+            _scoped_update(op, vals, row_valid, local_n) for op in ops
         )
-        partials = tuple(op.update(vals, row_valid, jnp, local_n) for op in ops)
         if mesh is not None:
             partials = tuple(
                 jax.tree.map(
@@ -1203,9 +1249,10 @@ def _build_step_fns(ops, unpacker, mesh, local_n, lut_keys: Tuple[str, ...] = ()
 
     def _flatten(partials):
         leaves = jax.tree.leaves(partials)
-        return jnp.concatenate(
-            [jnp.ravel(leaf).astype(jnp.float64) for leaf in leaves]
-        )
+        with jax.named_scope("deequ.flatten"):
+            return jnp.concatenate(
+                [jnp.ravel(leaf).astype(jnp.float64) for leaf in leaves]
+            )
 
     if mesh is not None:
         inner = shard_map(
@@ -1473,6 +1520,10 @@ class _DeviceFoldPlan:
     def merge_body(self, acc, new):
         """Pure traced merge: fold one chunk's flat vector into the
         accumulator (left-to-right order = call order)."""
+        with jax.named_scope("deequ.fold"):
+            return self._merge(acc, new)
+
+    def _merge(self, acc, new):
         if self.elem_size:
             from deequ_tpu.ops.df32 import merge_tags_f64
 
@@ -1572,33 +1623,34 @@ class _PartialFolder:
     unflattened by the plan and merged — a scan that stays within one
     accumulator drains exactly once)."""
 
-    def __init__(self, ops):
+    def __init__(self, ops, deadline: Optional[float] = None):
         self.ops = ops
         self.merged = None
         self.shapes = None
         self.fold_plan: Optional[_DeviceFoldPlan] = None
         self.fold_filled = 0
+        # the run's watchdog deadline: the fetch below is the blocking
+        # device round trip, the watchdog's prime target
+        self.deadline = deadline
 
     def drain(self, device_result) -> None:
-        import time as _time
-
         # host-side slices (fetch_deferred hands those out) are already
-        # materialized: only a true device array counts as a fetch
-        fetched = not isinstance(device_result, np.ndarray)
-        t0 = _time.time()
-        try:
-            flat = np.asarray(device_result)
-        except Exception as e:  # noqa: BLE001 — async device failures
-            # (OOM, device loss) surface HERE, at the fetch: classify once
-            # so every drain path (inline, deferred, grouped) raises typed
-            typed = classify_device_error(e, "fetch")
-            if typed is not None:
-                raise typed from e
-            raise
-        finally:
-            SCAN_STATS.drain_wait_seconds += _time.time() - t0
-        if fetched:
+        # materialized: only a true device array is a fetch. Async device
+        # failures (OOM, device loss) surface HERE: device_call classifies
+        # them once, so every drain path (inline, deferred, grouped)
+        # raises typed, and a hung device becomes DeviceHangException
+        if isinstance(device_result, np.ndarray):
+            flat = device_result
+        else:
+            flat = device_call(
+                lambda: np.asarray(device_result), "fetch",
+                what="scan drain", deadline=self.deadline,
+            )
             SCAN_STATS.record_fetch(flat.nbytes)
+        with seam("evaluate", what="fold"):
+            self._fold(flat)
+
+    def _fold(self, flat: np.ndarray) -> None:
         if self.fold_plan is not None:
             # the vector IS an accumulator already covering fold_filled
             # chunks: unflatten and merge (a second drain only happens
@@ -1642,45 +1694,43 @@ class DeferredScan:
         self,
         folder: _PartialFolder,
         in_flight,
-        t_start: float,
-        bill_from_start: bool = False,
-        deadline: Optional[float] = None,
+        inline: bool = False,
+        scan_id: Optional[int] = None,
     ):
         self._folder = folder
         self._in_flight = in_flight
-        self._t_start = t_start
-        # the run's watchdog deadline, carried so a batched fetch
-        # (fetch_deferred) stays guarded like the per-scan drain
-        self._deadline = deadline
-        # resolved-inline scans (run_scan defer=False) bill the whole
-        # pack+dispatch+drain wall as before; genuinely deferred scans
-        # bill only the BLOCKING drain segment — wall between dispatch
-        # and drain belongs to the caller, and with several scans in
-        # flight it would double-count
-        self._bill_from_start = bill_from_start
+        # resolved-inline scans (run_scan defer=False) drain inside the
+        # attempt's own scan_attempt seam; a genuinely deferred scan
+        # opens one around its BLOCKING drain segment — the wall between
+        # dispatch and drain belongs to the caller, and with several
+        # scans in flight it would double-count in scan_seconds
+        self._inline = inline
+        self._scan_id = scan_id
         self._done = False
         self._error: Optional[BaseException] = None
 
     def result(self) -> List[Any]:
         if not self._done:
-            import time as _time
-
-            t0 = self._t_start if self._bill_from_start else _time.time()
             pending = self._in_flight
             self._in_flight = []
             self._done = True
-            try:
-                for device_result in pending:
-                    self._folder.drain(device_result)
-            except BaseException as e:  # noqa: BLE001 — a retry must not
-                # re-fold already-drained chunks into the accumulator, and
-                # even a KeyboardInterrupt mid-drain must leave the scan
-                # FAILED, never silently half-folded. Non-Exception
-                # control-flow signals (Ctrl-C) propagate immediately.
-                self._error = e
-                if not isinstance(e, Exception):
-                    raise
-            SCAN_STATS.scan_seconds += _time.time() - t0
+            with (
+                nullcontext() if self._inline
+                else seam("scan_attempt", scan_id=self._scan_id,
+                          deferred=True)
+            ):
+                try:
+                    for device_result in pending:
+                        self._folder.drain(device_result)
+                except BaseException as e:  # noqa: BLE001 — a retry must
+                    # not re-fold already-drained chunks into the
+                    # accumulator, and even a KeyboardInterrupt mid-drain
+                    # must leave the scan FAILED, never silently
+                    # half-folded. Non-Exception control-flow signals
+                    # (Ctrl-C) propagate immediately.
+                    self._error = e
+                    if not isinstance(e, Exception):
+                        raise
         if self._error is not None:
             raise self._error
         return self._folder.merged
@@ -1696,12 +1746,14 @@ def fetch_deferred(scans: Sequence["DeferredScan"]) -> None:
     every pending vector concatenates ON DEVICE (one async dispatch) and
     comes back in a single fetch; the slices then feed each scan's folder
     in order. After this, ``result()`` on every scan is free."""
-    import time as _time
-
     pending = [s for s in scans if not s._done and s._in_flight]
     if not pending:
         return
-    t0 = _time.time()
+    with seam("scan_attempt", deferred=True, scans=len(pending)):
+        _fetch_deferred(pending)
+
+
+def _fetch_deferred(pending: Sequence["DeferredScan"]) -> None:
     arrays = [a for s in pending for a in s._in_flight]
     # a CPU-fallback scan's accumulator is committed to the CPU backend
     # while its siblings sit on the accelerator — cross-device arrays
@@ -1719,7 +1771,8 @@ def fetch_deferred(scans: Sequence["DeferredScan"]) -> None:
     # device_deadline), falling back to the process-wide env default —
     # this blocking fetch is where async faults and hangs surface now
     deadline = next(
-        (s._deadline for s in pending if s._deadline is not None),
+        (s._folder.deadline for s in pending
+         if s._folder.deadline is not None),
         default_device_deadline(),
     )
 
@@ -1743,11 +1796,10 @@ def fetch_deferred(scans: Sequence["DeferredScan"]) -> None:
     parts = device_call(
         materialize, "fetch", what="deferred scan fetch", deadline=deadline,
     )
-    # the batched round trip is a drain wait and a device->host fetch like
-    # any other — attribute it so the one-fetch contract stays observable
-    # (the per-scan folder.drain calls below see numpy slices and count
+    # the batched round trip is a device->host fetch like any other —
+    # attribute it so the one-fetch contract stays observable (the
+    # per-scan folder.drain calls below see numpy slices and count
     # nothing)
-    SCAN_STATS.drain_wait_seconds += _time.time() - t0
     with SCAN_STATS._fetch_lock:
         SCAN_STATS.device_fetches += (
             len(arrays) if (len(arrays) > 1 and not same_device) else 1
@@ -1770,7 +1822,6 @@ def fetch_deferred(scans: Sequence["DeferredScan"]) -> None:
             if not isinstance(e, Exception):
                 raise
         i += n_parts
-    SCAN_STATS.scan_seconds += _time.time() - t0
 
 
 # smallest chunk the OOM bisection will try before giving up: below this
@@ -1840,12 +1891,7 @@ def _maybe_plan_lint(
         return
     from deequ_tpu.lint.plan_lint import enforce_plan_lint, lint_plan_cached
 
-    rec = current_recorder()
-    with (
-        rec.span("plan_lint", variant=plan_ir.variant, mode=mode)
-        if rec is not None
-        else nullcontext()
-    ):
+    with seam("plan", what="plan_lint", variant=plan_ir.variant, mode=mode):
         avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
         memo_key = None
         baked = any(op.dictionary_baked for op in plan_ir.ops)
@@ -1874,18 +1920,15 @@ def _maybe_plan_lint(
         enforce_plan_lint(findings, mode)
 
 
-def _block_throttle(arr) -> None:
+def _block_throttle(arr, what: str, deadline: Optional[float]) -> None:
     """Wait for a device result WITHOUT fetching it (pipeline
-    backpressure for the device-fold loops). The wait is a drain in the
-    accounting sense — time blocked on the device — but moves no bytes
-    and counts no fetch."""
-    import time as _time
-
-    t0 = _time.time()
-    try:
-        jax.block_until_ready(arr)
-    finally:
-        SCAN_STATS.drain_wait_seconds += _time.time() - t0
+    backpressure for the device-fold loops). The wait is a ``drain`` —
+    time blocked on the device — but moves no bytes and counts no
+    fetch."""
+    device_call(
+        lambda: jax.block_until_ready(arr), "execute", what=what,
+        deadline=deadline, seam_name="drain",
+    )
 
 
 def _cpu_fallback_device():
@@ -1953,18 +1996,22 @@ def _governed_attempt(budget, fn: Callable, what: str):
     # the caller's current span)
     rec = current_recorder()
     rec_parent = rec.current_span_id() if rec is not None else None
+    ids = seam_ids()
 
     def governed_fn():
-        with run_budget_scope(budget):
+        with run_budget_scope(budget), worker_seams(ids):
             if rec is not None:
                 with recording_scope(rec, rec_parent):
                     return fn()
             return fn()
 
-    return _call_with_deadline(
-        governed_fn, max(wall_left, MIN_BUDGET_WATCHDOG_SECONDS), what,
-        "execute",
-    )
+    # the attempt's own seams run on the watchdog worker as spans only;
+    # what this thread spends is the wait for it
+    with seam("drain", what=what, governed=True):
+        return _call_with_deadline(
+            governed_fn, max(wall_left, MIN_BUDGET_WATCHDOG_SECONDS), what,
+            "execute",
+        )
 
 
 def run_scan(
@@ -2159,7 +2206,6 @@ def run_scan(
         shard_deadline = default_shard_deadline()
     window = _resolve_scan_window(window)
     scan_id = next(_SCAN_IDS)
-    rec = current_recorder()
     from deequ_tpu.ops import scan_executors
 
     kind = scan_executors.classify(table, mesh)
@@ -2171,7 +2217,7 @@ def run_scan(
             shard_deadline=shard_deadline, window=window,
             select_kernel=select_kernel, plan_lint=plan_lint,
             encoded_ingest=encoded_ingest, budget=budget,
-            scan_id=scan_id, rec=rec,
+            scan_id=scan_id,
         )
 
     # fallback needs a CPU backend to land on; a process pinned to the
@@ -2224,7 +2270,7 @@ def run_scan(
         device_deadline=device_deadline, shard_deadline=shard_deadline,
         window=window, select_kernel=select_kernel, plan_lint=plan_lint,
         encoded_ingest=encoded_ingest, budget=budget, scan_id=scan_id,
-        rec=rec, fallback=fallback,
+        fallback=fallback,
     )
 
 
@@ -2247,109 +2293,118 @@ def _run_scan_once(
     ``report`` returns the chunk size actually used (and whether the
     attempt ran the encoded ingest variant) so the bisection/demotion
     driver can react."""
-    from deequ_tpu.ops.scan_plan import plan_scan_ops
-    n_rows = table.num_rows
-    needed = sorted({c for op in ops for c in op.columns})
-    cols = {name: table[name] for name in needed}
+    with seam("plan"):
+        from deequ_tpu.ops.scan_plan import plan_scan_ops
+        n_rows = table.num_rows
+        needed = sorted({c for op in ops for c in op.columns})
+        cols = {name: table[name] for name in needed}
 
-    n_dev = math.prod(mesh.devices.shape) if mesh is not None else 1
+        n_dev = math.prod(mesh.devices.shape) if mesh is not None else 1
 
-    # device-resident fast path: table was persist()ed with a compatible
-    # mesh — stream chunks straight from HBM, no packing, no transfer
-    cache = getattr(table, "_device_cache", None)
-    if cache is not None and cache.packer.enc_names and not encoded:
-        # encoded residency cannot serve a decoded-path attempt (the
-        # A/B switch, or a fault-ladder demotion whose eviction raced a
-        # concurrent re-persist): bypass it, scan from host decoded
-        cache = None
-    if cache is not None and not cache.mesh_matches(mesh):
-        # a mesh change (degraded-mesh reshard, explicit use_mesh) strands
-        # the per-device shards on devices that may no longer be in the
-        # active mesh — stale residency must be FREED (and uncharged from
-        # the HBM budget), not just skipped, or a dead chip keeps its
-        # buffers and the budget gate overcommits the survivors
-        freed = _evict_device_cache(table)
-        SCAN_STATS.record_degradation(
-            "stale_residency_evicted",
-            scan_id=scan_ctx.get("scan_id"),
-            evicted_bytes=freed,
+        # device-resident fast path: table was persist()ed with a compatible
+        # mesh — stream chunks straight from HBM, no packing, no transfer
+        cache = getattr(table, "_device_cache", None)
+        if cache is not None and cache.packer.enc_names and not encoded:
+            # encoded residency cannot serve a decoded-path attempt (the
+            # A/B switch, or a fault-ladder demotion whose eviction raced a
+            # concurrent re-persist): bypass it, scan from host decoded
+            cache = None
+        if cache is not None and not cache.mesh_matches(mesh):
+            # a mesh change (degraded-mesh reshard, explicit use_mesh) strands
+            # the per-device shards on devices that may no longer be in the
+            # active mesh — stale residency must be FREED (and uncharged from
+            # the HBM budget), not just skipped, or a dead chip keeps its
+            # buffers and the budget gate overcommits the survivors
+            freed = _evict_device_cache(table)
+            SCAN_STATS.record_degradation(
+                "stale_residency_evicted",
+                scan_id=scan_ctx.get("scan_id"),
+                evicted_bytes=freed,
+            )
+            cache = None
+        if cache is not None and not cache.matches(mesh, needed):
+            cache = None
+        if cache is not None and chunk_rows is not None and chunk_rows != cache.chunk:
+            cache = None
+
+        if cache is not None:
+            chunk = cache.chunk
+            packer = cache.packer
+            for name in packer.pair_names:
+                if getattr(cols.get(name), "_exact_compare", False):
+                    _warn_pair_compare_once(name, cols.get(name))
+        else:
+            chunk = chunk_rows or min(_auto_chunk_rows(cols), max(n_rows, 1))
+            # static shapes: round the chunk up so it splits evenly across devices
+            chunk = max(n_dev, ((chunk + n_dev - 1) // n_dev) * n_dev)
+            packer = _ChunkPacker(cols, chunk, encode_ingest=encoded)
+        report["chunk"] = chunk
+        local_n = chunk // n_dev if mesh is not None else chunk
+
+        # kernel-variant resolution for THIS attempt (ops/scan_plan.py):
+        # resident tables route KLL/quantile summaries through the histogram
+        # selection kernel; re-planned per attempt, so an OOM retry that
+        # evicted residency falls back to the sort path by construction
+        plan_ir = plan_scan_ops(
+            ops, packer, resident=cache is not None,
+            select_kernel=select_kernel, rows=chunk,
         )
-        cache = None
-    if cache is not None and not cache.matches(mesh, needed):
-        cache = None
-    if cache is not None and chunk_rows is not None and chunk_rows != cache.chunk:
-        cache = None
+        ops = plan_ir.ops
+        report["encoded"] = plan_ir.ingest_variant == "encoded"
+        if report["encoded"]:
+            SCAN_STATS.encoded_scan_passes += 1
 
-    if cache is not None:
-        chunk = cache.chunk
-        packer = cache.packer
-        for name in packer.pair_names:
-            if getattr(cols.get(name), "_exact_compare", False):
-                _warn_pair_compare_once(name, cols.get(name))
-    else:
-        chunk = chunk_rows or min(_auto_chunk_rows(cols), max(n_rows, 1))
-        # static shapes: round the chunk up so it splits evenly across devices
-        chunk = max(n_dev, ((chunk + n_dev - 1) // n_dev) * n_dev)
-        packer = _ChunkPacker(cols, chunk, encode_ingest=encoded)
-    report["chunk"] = chunk
-    local_n = chunk // n_dev if mesh is not None else chunk
-
-    # kernel-variant resolution for THIS attempt (ops/scan_plan.py):
-    # resident tables route KLL/quantile summaries through the histogram
-    # selection kernel; re-planned per attempt, so an OOM retry that
-    # evicted residency falls back to the sort path by construction
-    plan_ir = plan_scan_ops(
-        ops, packer, resident=cache is not None,
-        select_kernel=select_kernel, rows=chunk,
-    )
-    ops = plan_ir.ops
-    report["encoded"] = plan_ir.ingest_variant == "encoded"
-    if report["encoded"]:
-        SCAN_STATS.encoded_scan_passes += 1
-
-    # dictionary LUTs ship once (memoized device arrays) and enter the
-    # jitted step as arguments; encoded columns add their dictionary's
-    # decode planes the same way
-    lut_arrays = _collect_luts(
-        ops, {n: packer.col_dict.get(n) for n in packer.string_names}, mesh
-    )
-    lut_arrays.update(_collect_enc_luts(packer, mesh))
-    lut_sig = _lut_sig(lut_arrays)
-    baked = any(op.dictionary_baked for op in ops)
-
-    # reuse the traced program across repeated runs: per-table cache for
-    # persisted tables, plus the global cache for any program without
-    # trace-baked dictionary constants (resident and streamed runs over
-    # same-schema tables share one traced program)
-    prog_key = _ops_prog_key(ops, chunk, lut_sig)
-    dtypes = {n: c.dtype for n, c in cols.items()}
-    global_key = (
-        _global_prog_key(prog_key, packer, mesh) if not baked else None
-    )
-    cached_prog = None
-    if cache is not None and prog_key is not None:
-        cached_prog = cache.get_program(prog_key)
-    if cached_prog is None and global_key is not None:
-        cached_prog = _GLOBAL_PROGRAMS.get(global_key)
-
-    if cached_prog is not None:
-        step_fn, shapes0, raw_flat = cached_prog
-        shape_fn = None
-        SCAN_STATS.programs_reused += 1
-    else:
-        shapes0 = None
-        SCAN_STATS.programs_built += 1
-        # the trace closure captures a metadata-only view, never the column
-        # arrays — cached programs must not pin batches in host memory
-        step_fn, shape_fn, raw_flat = _build_step_fns(
-            ops, packer.unpack_view(), mesh, local_n,
-            tuple(sorted(lut_arrays)),
+        # dictionary LUTs ship once (memoized device arrays) and enter the
+        # jitted step as arguments; encoded columns add their dictionary's
+        # decode planes the same way
+        lut_arrays = _collect_luts(
+            ops, {n: packer.col_dict.get(n) for n in packer.string_names}, mesh
         )
+        lut_arrays.update(_collect_enc_luts(packer, mesh))
+        lut_sig = _lut_sig(lut_arrays)
+        baked = any(op.dictionary_baked for op in ops)
+
+        # reuse the traced program across repeated runs: per-table cache for
+        # persisted tables, plus the global cache for any program without
+        # trace-baked dictionary constants (resident and streamed runs over
+        # same-schema tables share one traced program)
+        prog_key = _ops_prog_key(ops, chunk, lut_sig)
+        dtypes = {n: c.dtype for n, c in cols.items()}
+        global_key = (
+            _global_prog_key(prog_key, packer, mesh) if not baked else None
+        )
+        cached_prog = None
+        if cache is not None and prog_key is not None:
+            cached_prog = cache.get_program(prog_key)
+        if cached_prog is None and global_key is not None:
+            cached_prog = _GLOBAL_PROGRAMS.get(global_key)
+
+        if cached_prog is not None:
+            step_fn, shapes0, raw_flat = cached_prog
+            shape_fn = None
+            SCAN_STATS.programs_reused += 1
+        else:
+            shapes0 = None
+            SCAN_STATS.programs_built += 1
+            # the trace closure captures a metadata-only view, never the column
+            # arrays — cached programs must not pin batches in host memory
+            step_fn, shape_fn, raw_flat = _build_step_fns(
+                ops, packer.unpack_view(), mesh, local_n,
+                tuple(sorted(lut_arrays)),
+            )
+    # the first call of a program built in this attempt traces and
+    # compiles: that dispatch is a `build`, every later one a `dispatch`
+    building = shapes0 is None
+
+    def dispatch_seam() -> Optional[str]:
+        nonlocal building
+        name, building = ("build" if building else None), False
+        return name
 
     SCAN_STATS.scan_passes += 1
     SCAN_STATS.rows_scanned += n_rows
 
-    folder = _PartialFolder(ops)
+    folder = _PartialFolder(ops, deadline=device_deadline)
     folder.shapes = shapes0
     n_chunks = (
         len(cache.device_chunks)
@@ -2363,9 +2418,6 @@ def _run_scan_once(
     # packing, host->device transfer, and device compute overlap.
     put = _make_put(mesh)
 
-    import time as _time
-
-    t_start = _time.time()
     in_flight = []
     # on-device partial fold: the per-chunk state vectors merge into ONE
     # device-resident accumulator (exact left-to-right chunk order), so
@@ -2399,6 +2451,23 @@ def _run_scan_once(
         )
         folded += 1
 
+    def after_dispatch(flat, ci) -> None:
+        """Fold or queue one chunk's result, window-bounded."""
+        _record_kernel_passes(plan_ir, 1)
+        in_flight.append(flat)
+        if use_fold:
+            fold_chunk(flat, ci)
+            # throttle, don't drain: block on (not fetch) the oldest
+            # chunk's result so pinned host buffers / queued device
+            # work stay window-bounded while zero fetches happen
+            if len(in_flight) >= window:
+                _block_throttle(
+                    in_flight.pop(0), f"chunk throttle (window at {ci})",
+                    device_deadline,
+                )
+        elif len(in_flight) >= window:
+            folder.drain(in_flight.pop(0))
+
     if cache is not None:
         SCAN_STATS.resident_passes += 1
         SCAN_STATS.bytes_resident += cache.nbytes
@@ -2430,6 +2499,7 @@ def _run_scan_once(
         # resident chunks — per-chunk partials never exist as separate
         # dispatches, the whole pass is ONE dispatch + ONE fetch
         fused = None
+        fused_built = False
         stacked = None
         if use_fold and mesh is None and n_chunks > 1 and _fused_resident_enabled():
             # the stack is the largest new HBM allocation of the scan (a
@@ -2453,6 +2523,7 @@ def _run_scan_once(
                 )
                 if fused is None:
                     SCAN_STATS.programs_built += 1
+                    fused_built = True
                     fplan, rflat = plan, raw_flat
 
                     def _fused(stacked_bufs, luts):
@@ -2471,49 +2542,26 @@ def _run_scan_once(
                 else:
                     SCAN_STATS.programs_reused += 1
         if fused is not None:
-            t_d = _time.time()
             acc = device_call(
                 lambda: fused(stacked, lut_arrays),
                 "execute", what="fused resident scan dispatch",
                 deadline=device_deadline,
                 hook_ctx={**scan_ctx, "chunk_index": 0},
+                seam_name="build" if fused_built else None,
             )
-            SCAN_STATS.dispatch_seconds += _time.time() - t_d
             _record_kernel_passes(plan_ir, n_chunks)
             folded = n_chunks
         else:
             for ci, args in enumerate(cache.device_chunks):
                 ensure_shapes(args)
-                t_d = _time.time()
                 flat = device_call(
                     lambda: step_fn(*args, lut_arrays),
                     "execute", what=f"chunk {ci} dispatch",
                     deadline=device_deadline,
                     hook_ctx={**scan_ctx, "chunk_index": ci},
+                    seam_name=dispatch_seam(),
                 )
-                SCAN_STATS.dispatch_seconds += _time.time() - t_d
-                _record_kernel_passes(plan_ir, 1)
-                if use_fold:
-                    fold_chunk(flat, ci)
-                    # same backpressure as the packing loop: queued
-                    # device work stays window-bounded, no fetch
-                    in_flight.append(flat)
-                    if len(in_flight) >= window:
-                        oldest = in_flight.pop(0)
-                        device_call(
-                            lambda: _block_throttle(oldest),
-                            "execute",
-                            what=f"chunk throttle (window at {ci})",
-                            deadline=device_deadline,
-                        )
-                else:
-                    in_flight.append(flat)
-                    if len(in_flight) >= window:
-                        device_call(
-                            lambda: folder.drain(in_flight.pop(0)),
-                            "execute", what=f"chunk drain (window at {ci})",
-                            deadline=device_deadline,
-                        )
+                after_dispatch(flat, ci)
     else:
         # double-buffered host->device staging (round 8, the Eiger
         # discipline): chunk k+1's async device_put is ISSUED before
@@ -2526,42 +2574,22 @@ def _run_scan_once(
 
         def dispatch_staged(entry) -> None:
             device_args, ci = entry
-            t_d = _time.time()
             flat = device_call(
                 lambda: step_fn(*device_args, lut_arrays),
                 "execute", what=f"chunk {ci} dispatch",
                 deadline=device_deadline,
                 hook_ctx={**scan_ctx, "chunk_index": ci},
+                seam_name=dispatch_seam(),
             )
-            SCAN_STATS.dispatch_seconds += _time.time() - t_d
-            _record_kernel_passes(plan_ir, 1)
-            if use_fold:
-                fold_chunk(flat, ci)
-                # throttle, don't drain: block on (not fetch) the oldest
-                # chunk's result so pinned host buffers / queued device
-                # work stay window-bounded while zero fetches happen
-                in_flight.append(flat)
-                if len(in_flight) >= window:
-                    oldest = in_flight.pop(0)
-                    device_call(
-                        lambda: _block_throttle(oldest),
-                        "execute", what=f"chunk throttle (window at {ci})",
-                        deadline=device_deadline,
-                    )
-            else:
-                in_flight.append(flat)
-                if len(in_flight) >= window:
-                    device_call(
-                        lambda: folder.drain(in_flight.pop(0)),
-                        "execute", what=f"chunk drain (window at {ci})",
-                        deadline=device_deadline,
-                    )
+            after_dispatch(flat, ci)
 
         for ci in range(n_chunks):
             start = ci * chunk
             stop = min(start + chunk, n_rows)
-            args = packer.pack(start, stop)
-            SCAN_STATS.bytes_packed += sum(a.nbytes for a in args)
+            with seam("pack", chunk=ci):
+                args = packer.pack(start, stop)
+            chunk_bytes = sum(a.nbytes for a in args)
+            SCAN_STATS.bytes_packed += chunk_bytes
             if ci == 0:
                 # static plan lint on the first chunk's shapes, before
                 # its transfer/dispatch (memoized per program identity)
@@ -2585,15 +2613,12 @@ def _run_scan_once(
             # always sees an empty stage here and reports 0, so the
             # observable genuinely detects a dead double buffer
             overlapped = bool(pending_stage)
-            t_d = _time.time()
             device_args = device_call(
                 lambda: put(args), "transfer",
                 what=f"chunk {ci} transfer", deadline=device_deadline,
+                bytes=chunk_bytes, overlapped=overlapped,
             )
-            SCAN_STATS.dispatch_seconds += _time.time() - t_d
-            SCAN_STATS.record_staged(
-                sum(a.nbytes for a in args), overlapped
-            )
+            SCAN_STATS.record_staged(chunk_bytes, overlapped)
             pending_stage.append((device_args, ci))
             if len(pending_stage) > 1:
                 dispatch_staged(pending_stage.pop(0))
@@ -2604,18 +2629,15 @@ def _run_scan_once(
         folder.fold_filled = folded
         in_flight = [acc]
     deferred = DeferredScan(
-        folder, in_flight, t_start, bill_from_start=not defer,
-        deadline=device_deadline,
+        folder, in_flight, inline=not defer,
+        scan_id=scan_ctx.get("scan_id"),
     )
     if defer:
         return deferred
     # the drain is the blocking device round trip — the watchdog's prime
-    # target (folder.drain classifies fetch errors; device_call adds the
-    # hang deadline on top)
-    return device_call(
-        deferred.result, "fetch", what="scan drain",
-        deadline=device_deadline,
-    )
+    # target (folder.drain runs its fetch through device_call: typed
+    # errors, and the hang deadline on top)
+    return deferred.result()
 
 
 # -- micro-batched group scan (incremental pipelines) -----------------------
@@ -2636,28 +2658,26 @@ class DeferredGroupScan:
 
     def results(self) -> list:
         if not self._done:
-            import time as _time
-
             # same half-folded-accumulator invariant as DeferredScan /
             # fetch_deferred: mark done BEFORE draining so a mid-drain
             # failure (or Ctrl-C) can never be retried into double-folds
             self._done = True
-            t0 = _time.time()
-            try:
-                host = np.asarray(self._device_out)  # the one round trip
-                SCAN_STATS.drain_wait_seconds += _time.time() - t0
-                SCAN_STATS.record_fetch(host.nbytes)
-                out = []
-                for k, folder in enumerate(self._folders):
-                    folder.drain(host[k])
-                    out.append(folder.merged)
-                self._results = out
-            except BaseException as e:  # noqa: BLE001
-                self._error = e
-                if not isinstance(e, Exception):
-                    raise
-            finally:
-                SCAN_STATS.scan_seconds += _time.time() - t0
+            with seam("scan_attempt", deferred=True,
+                      scans=len(self._folders)):
+                try:
+                    with seam("fetch"):
+                        # the one round trip
+                        host = np.asarray(self._device_out)
+                    SCAN_STATS.record_fetch(host.nbytes)
+                    out = []
+                    for k, folder in enumerate(self._folders):
+                        folder.drain(host[k])
+                        out.append(folder.merged)
+                    self._results = out
+                except BaseException as e:  # noqa: BLE001
+                    self._error = e
+                    if not isinstance(e, Exception):
+                        raise
         if self._error is not None:
             raise self._error
         return self._results
@@ -2746,116 +2766,119 @@ def run_scan_group(
     # SAME layout at the same chunk size (no union/promotion: that would
     # change the compute path vs the per-batch serial scans and break
     # bit-exactness); callers pass that validated layout through
-    first_cols = {name: tables[0][name] for name in needed}
-    if layout is None:
-        layout = _ChunkPacker(first_cols, chunk).layout()
-    packer = _ChunkPacker(first_cols, chunk, layout=layout)
+    with seam("plan"):
+        first_cols = {name: tables[0][name] for name in needed}
+        if layout is None:
+            layout = _ChunkPacker(first_cols, chunk).layout()
+        packer = _ChunkPacker(first_cols, chunk, layout=layout)
 
     # stack per-table packed buffers along a leading K axis
     stacked = None
-    for t in tables:
-        cols = {name: t[name] for name in needed}
-        p = _ChunkPacker(cols, chunk, layout=packer.layout())
-        args = p.pack(0, t.num_rows)
-        SCAN_STATS.bytes_packed += sum(a.nbytes for a in args)
-        if stacked is None:
-            stacked = [[a] for a in args]
+    with seam("pack", tables=K):
+        for t in tables:
+            cols = {name: t[name] for name in needed}
+            p = _ChunkPacker(cols, chunk, layout=packer.layout())
+            args = p.pack(0, t.num_rows)
+            SCAN_STATS.bytes_packed += sum(a.nbytes for a in args)
+            if stacked is None:
+                stacked = [[a] for a in args]
+            else:
+                for lst, a in zip(stacked, args):
+                    lst.append(a)
+        bufs = tuple(np.stack(lst) for lst in stacked)
+
+    with seam("plan", what="luts and program lookup"):
+        # per-table dictionary LUTs stacked to (K, L_groupmax): each table's
+        # LUT pads to the GROUP's max pow2 size — padding slots are never
+        # gathered (codes < that table's cardinality), so per-batch results
+        # stay bit-identical to the serial path's individually-padded LUTs
+        lut_stacked: Dict[str, Any] = {}
+        lut_specs = {}
+        for op in ops:
+            for col, kind, builder in op.luts:
+                lut_specs.setdefault(col + "\x00" + kind, (col, kind, builder))
+        if lut_specs:
+            from deequ_tpu.ops.lut_cache import dictionary_lut
+
+            for key, (col, kind, builder) in lut_specs.items():
+                per_table = [
+                    dictionary_lut(t[col].dictionary, kind, builder)
+                    for t in tables
+                ]
+                target = 1
+                while target < max(len(a) for a in per_table):
+                    target <<= 1
+                padded = []
+                for a in per_table:
+                    if len(a) < target:
+                        out = np.zeros(target, dtype=a.dtype)
+                        out[: len(a)] = a
+                        a = out
+                    padded.append(a)
+                lut_stacked[key] = jax.device_put(np.stack(padded))
+        lut_sig = tuple(
+            sorted(
+                (key, tuple(int(d) for d in arr.shape), str(arr.dtype))
+                for key, arr in lut_stacked.items()
+            )
+        )
+
+        prog_key = _ops_prog_key(ops, chunk, lut_sig)
+        global_key = None
+        if prog_key is not None:
+            gk = _global_prog_key(prog_key, packer, None)
+            if gk is not None:
+                global_key = ("group", K, gk)
+        cached = _GLOBAL_PROGRAMS.get(global_key) if global_key else None
+
+        if cached is not None:
+            vstep, shapes = cached
+            SCAN_STATS.programs_reused += 1
         else:
-            for lst, a in zip(stacked, args):
-                lst.append(a)
-    bufs = tuple(np.stack(lst) for lst in stacked)
+            SCAN_STATS.programs_built += 1
+            view = packer.unpack_view()
 
-    # per-table dictionary LUTs stacked to (K, L_groupmax): each table's
-    # LUT pads to the GROUP's max pow2 size — padding slots are never
-    # gathered (codes < that table's cardinality), so per-batch results
-    # stay bit-identical to the serial path's individually-padded LUTs
-    lut_stacked: Dict[str, Any] = {}
-    lut_specs = {}
-    for op in ops:
-        for col, kind, builder in op.luts:
-            lut_specs.setdefault(col + "\x00" + kind, (col, kind, builder))
-    if lut_specs:
-        from deequ_tpu.ops.lut_cache import dictionary_lut
-
-        for key, (col, kind, builder) in lut_specs.items():
-            per_table = [
-                dictionary_lut(t[col].dictionary, kind, builder)
-                for t in tables
-            ]
-            target = 1
-            while target < max(len(a) for a in per_table):
-                target <<= 1
-            padded = []
-            for a in per_table:
-                if len(a) < target:
-                    out = np.zeros(target, dtype=a.dtype)
-                    out[: len(a)] = a
-                    a = out
-                padded.append(a)
-            lut_stacked[key] = jax.device_put(np.stack(padded))
-    lut_sig = tuple(
-        sorted(
-            (key, tuple(int(d) for d in arr.shape), str(arr.dtype))
-            for key, arr in lut_stacked.items()
-        )
-    )
-
-    prog_key = _ops_prog_key(ops, chunk, lut_sig)
-    global_key = None
-    if prog_key is not None:
-        gk = _global_prog_key(prog_key, packer, None)
-        if gk is not None:
-            global_key = ("group", K, gk)
-    cached = _GLOBAL_PROGRAMS.get(global_key) if global_key else None
-
-    if cached is not None:
-        vstep, shapes = cached
-        SCAN_STATS.programs_reused += 1
-    else:
-        SCAN_STATS.programs_built += 1
-        view = packer.unpack_view()
-
-        def single_tree(values, hi, lo, narrow_i, masks, codes, row_valid, enc, luts):
-            col_luts: Dict[str, Dict[str, Any]] = {}
-            for key, arr in luts.items():
-                lcol, lkind = _split_lut_key(key)
-                col_luts.setdefault(lcol, {})[lkind] = arr
-            vals = view.unpack_vals(
-                values, hi, lo, narrow_i, masks, codes, jnp, row_valid,
-                col_luts=col_luts, enc=enc,
-            )
-            return tuple(
-                jax.tree.map(
-                    _tag_identity_wrap,
-                    op.tags,
-                    op.update(vals, row_valid, jnp, chunk),
+            def single_tree(values, hi, lo, narrow_i, masks, codes, row_valid, enc, luts):
+                col_luts: Dict[str, Dict[str, Any]] = {}
+                for key, arr in luts.items():
+                    lcol, lkind = _split_lut_key(key)
+                    col_luts.setdefault(lcol, {})[lkind] = arr
+                vals = view.unpack_vals(
+                    values, hi, lo, narrow_i, masks, codes, jnp, row_valid,
+                    col_luts=col_luts, enc=enc,
                 )
-                for op in ops
-            )
+                return tuple(
+                    jax.tree.map(
+                        _tag_identity_wrap,
+                        op.tags,
+                        _scoped_update(op, vals, row_valid, chunk),
+                    )
+                    for op in ops
+                )
 
-        def single_flat(*args):
-            leaves = jax.tree.leaves(single_tree(*args))
-            return jnp.concatenate(
-                [jnp.ravel(leaf).astype(jnp.float64) for leaf in leaves]
-            )
+            def single_flat(*args):
+                leaves = jax.tree.leaves(single_tree(*args))
+                return jnp.concatenate(
+                    [jnp.ravel(leaf).astype(jnp.float64) for leaf in leaves]
+                )
 
-        vstep = jax.jit(jax.vmap(single_flat))
-        shapes = jax.eval_shape(
-            single_tree,
-            *(b[0] for b in bufs),
-            {k: v[0] for k, v in lut_stacked.items()},
-        )
-        if global_key is not None:
-            _GLOBAL_PROGRAMS.put(global_key, (vstep, shapes))
+            vstep = jax.jit(jax.vmap(single_flat))
+            with seam("build", what="group-scan trace"):
+                shapes = jax.eval_shape(
+                    single_tree,
+                    *(b[0] for b in bufs),
+                    {k: v[0] for k, v in lut_stacked.items()},
+                )
+            if global_key is not None:
+                _GLOBAL_PROGRAMS.put(global_key, (vstep, shapes))
 
     SCAN_STATS.scan_passes += 1
     SCAN_STATS.rows_scanned += sum(t.num_rows for t in tables)
 
-    import time as _time
-
-    t_d = _time.time()
-    device_out = vstep(*bufs, lut_stacked)
-    SCAN_STATS.dispatch_seconds += _time.time() - t_d
+    # the numpy buffers transfer inside the call: stage and dispatch are
+    # one enqueue here (a program's first call also traces and compiles)
+    with seam("build" if cached is None else "dispatch", tables=K):
+        device_out = vstep(*bufs, lut_stacked)
     # grouped micro-batches are packed fresh per call (never resident):
     # the kernel census is the sort path's, once per table in the stack
     from deequ_tpu.ops.scan_plan import plan_scan_ops
@@ -2915,9 +2938,11 @@ def _prefetch(iterator, depth: int = 2):
         else nullcontext()
     )
 
+    ids = seam_ids()
+
     def run():
         try:
-            with run_budget_scope(budget), rec_scope:
+            with run_budget_scope(budget), rec_scope, worker_seams(ids):
                 for item in iterator:
                     while not stop.is_set():
                         try:
@@ -3108,7 +3133,7 @@ def _run_scan_stream(
 
     SCAN_STATS.scan_passes += 1
 
-    folder = _PartialFolder(ops)
+    folder = _PartialFolder(ops, deadline=device_deadline)
     in_flight = []
     chunk_counter = [0]
     encoded_counted = [False]
@@ -3126,9 +3151,14 @@ def _run_scan_stream(
     # dispatch the staged chunk under its own (old-layout) program
     pending_stage: List[Tuple] = []
 
+    # programs built in this scan whose first call (trace + compile) is
+    # still to come: that dispatch is a `build`
+    unbuilt: set = set()
+
     def dispatch_staged(entry) -> None:
         fn, device_args, luts, idx = entry
-        t_d = _time.time()
+        building = id(fn) in unbuilt
+        unbuilt.discard(id(fn))
         flat = device_call(
             lambda: fn(*device_args, luts),
             "execute",
@@ -3139,8 +3169,8 @@ def _run_scan_stream(
                 "chunk_index": idx,
                 "device_ids": mesh_device_ids(mesh),
             },
+            seam_name="build" if building else None,
         )
-        SCAN_STATS.dispatch_seconds += _time.time() - t_d
         _record_kernel_passes(plan_ir, 1)
         if use_fold:
             if fold_state["plan"] is None:
@@ -3160,11 +3190,9 @@ def _run_scan_stream(
             fold_state["filled"] += 1
             in_flight.append(flat)
             if len(in_flight) >= window:
-                oldest = in_flight.pop(0)
-                device_call(
-                    lambda: _block_throttle(oldest),
-                    "execute", what="stream chunk throttle",
-                    deadline=device_deadline,
+                _block_throttle(
+                    in_flight.pop(0), "stream chunk throttle",
+                    device_deadline,
                 )
             # only gather leaves grow with the chunk count: a
             # gather-free accumulator never overflows, so it folds
@@ -3178,21 +3206,14 @@ def _run_scan_stream(
         else:
             in_flight.append(flat)
             if len(in_flight) >= window:
-                device_call(
-                    lambda: folder.drain(in_flight.pop(0)),
-                    "execute", what="stream chunk drain",
-                    deadline=device_deadline,
-                )
+                folder.drain(in_flight.pop(0))
 
     def drain_fold() -> None:
         if fold_state["acc"] is None:
             return
         folder.fold_plan = fold_state["plan"]
         folder.fold_filled = fold_state["filled"]
-        device_call(
-            lambda: folder.drain(fold_state["acc"]),
-            "fetch", what="stream fold drain", deadline=device_deadline,
-        )
+        folder.drain(fold_state["acc"])
         fold_state["acc"] = None
         fold_state["filled"] = 0
     layout: Optional[dict] = None
@@ -3207,10 +3228,6 @@ def _run_scan_stream(
     # the traced contract surface does not)
     linted_sigs: set = set()
 
-    import time as _time
-
-    t_start = _time.time()
-
     # predicate-compiled boundary columns recorded on the stream (its
     # schema views can't carry the per-Column mark): apply to every
     # materialized batch BEFORE the layout is derived/pinned so they
@@ -3219,7 +3236,9 @@ def _run_scan_stream(
         getattr(stream, "_exact_compare_names", ()) or ()
     ) & set(needed)
 
-    def process_cols(cols: Dict[str, Column], n: int) -> None:
+    def plan_cols(cols: Dict[str, Column]):
+        """One batch's host planning: layout (pinned, upgraded when the
+        batch outgrows it), LUTs and the program lookup."""
         nonlocal layout, current_prog
         for name in exact_names:
             if name in cols:
@@ -3265,11 +3284,24 @@ def _run_scan_stream(
                 tuple(sorted(lut_arrays)),
             )
             shapes = None
+            unbuilt.add(id(step_fn))
+        return packer, lut_arrays, prog_key, sig, global_key, (
+            step_fn, shape_fn, raw_flat, shapes
+        )
 
+    def process_cols(cols: Dict[str, Column], n: int) -> None:
+        nonlocal current_prog
+        with seam("plan"):
+            packer, lut_arrays, prog_key, sig, global_key, prog = (
+                plan_cols(cols)
+            )
+        step_fn, shape_fn, raw_flat, shapes = prog
         for start in range(0, max(n, 1), chunk):
             stop = min(start + chunk, n)
-            args = packer.pack(start, stop)
-            SCAN_STATS.bytes_packed += sum(a.nbytes for a in args)
+            with seam("pack", chunk=chunk_counter[0]):
+                args = packer.pack(start, stop)
+            chunk_bytes = sum(a.nbytes for a in args)
+            SCAN_STATS.bytes_packed += chunk_bytes
             if sig not in linted_sigs:
                 # static plan lint before this program's first
                 # transfer/dispatch — runs again after a mid-stream
@@ -3305,16 +3337,13 @@ def _run_scan_stream(
             # staged-undispatched — a serial loop reports 0 (see the
             # in-memory loop's rationale comment)
             overlapped = bool(pending_stage)
-            t_d = _time.time()
             device_args = device_call(
                 lambda: put(args), "transfer",
                 what=f"stream chunk {chunk_counter[0]} transfer",
                 deadline=device_deadline,
+                bytes=chunk_bytes, overlapped=overlapped,
             )
-            SCAN_STATS.dispatch_seconds += _time.time() - t_d
-            SCAN_STATS.record_staged(
-                sum(a.nbytes for a in args), overlapped
-            )
+            SCAN_STATS.record_staged(chunk_bytes, overlapped)
             pending_stage.append(
                 (step_fn, device_args, lut_arrays, chunk_counter[0])
             )
@@ -3343,10 +3372,5 @@ def _run_scan_stream(
         drain_fold()  # the (usually only) fetch of the whole stream scan
     else:
         for device_result in in_flight:
-            device_call(
-                lambda: folder.drain(device_result),
-                "execute", what="stream tail drain",
-                deadline=device_deadline,
-            )
-    SCAN_STATS.scan_seconds += _time.time() - t_start
+            folder.drain(device_result)
     return folder.merged

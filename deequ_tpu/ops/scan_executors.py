@@ -30,7 +30,6 @@ patched values.
 from __future__ import annotations
 
 import math
-from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -40,6 +39,7 @@ from deequ_tpu.exceptions import (
     DeviceHangException,
     DeviceOOMException,
 )
+from deequ_tpu.obs.recorder import seam
 
 
 def _engine():
@@ -81,7 +81,6 @@ def run_streaming_scan(
     encoded_ingest: bool,
     budget,
     scan_id: int,
-    rec,
 ) -> List[Any]:
     """One governed pass over a streaming table. Streams never retry in
     here (no rewind), so the whole scan is ONE attempt span; a run budget
@@ -105,11 +104,7 @@ def run_streaming_scan(
             if device_deadline is None
             else min(device_deadline, shard_deadline)
         )
-    with (
-        rec.span("scan_attempt", scan_id=scan_id, attempt=0, stream=True)
-        if rec is not None
-        else nullcontext()
-    ):
+    with seam("scan_attempt", scan_id=scan_id, attempt=0, stream=True):
         return eng._governed_attempt(
             budget,
             lambda: eng._run_scan_stream(
@@ -138,7 +133,6 @@ def run_laddered_scan(
     encoded_ingest: bool,
     budget,
     scan_id: int,
-    rec,
     fallback: bool,
 ) -> List[Any]:
     """The in-memory fault ladder — resident and sharded scans alike
@@ -155,17 +149,14 @@ def run_laddered_scan(
     attempt = 0
     depth = 0
     while True:
-        # one span per ladder attempt: the seam spans (transfer/
-        # trace/execute/fetch via device_call) nest under it, and a
-        # rung firing in the except blocks below records its instant
-        # event INSIDE the attempt span it degraded
-        with (
-            rec.span(
-                "scan_attempt", scan_id=scan_id, attempt=attempt,
-                fallback=fallback,
-            )
-            if rec is not None
-            else nullcontext()
+        # one enclosing seam per ladder attempt (its wall is
+        # scan_seconds): the plan/pack/stage/dispatch/fetch seams nest
+        # under it and inherit its scan_id, and a rung firing in the
+        # except blocks below records its instant event INSIDE the
+        # attempt span it degraded
+        with seam(
+            "scan_attempt", scan_id=scan_id, attempt=attempt,
+            fallback=fallback,
         ):
             n_dev = _mesh_size(mesh)
             floor = max(

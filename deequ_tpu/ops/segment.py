@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from deequ_tpu.data.table import Column, ColumnarTable, DType
+from deequ_tpu.obs.recorder import seam
 from deequ_tpu.ops.scan_engine import SCAN_STATS
 from deequ_tpu.parallel.mesh import ROW_AXIS, current_mesh, shard_map
 
@@ -88,14 +89,15 @@ def _unique_inverse_kernel(v, m):
     # primary key: validity (valid rows first), then NaN-ness (all NaNs
     # group together), then the value; lax.sort is stable, so ties keep
     # row order exactly as the lexsort formulation did
-    if jnp.issubdtype(v.dtype, jnp.floating):
-        nm, snan, sv, perm = jax.lax.sort(
-            (~m, v != v, v, iota), num_keys=3
-        )
-        neq = (sv[1:] != sv[:-1]) & ~(snan[1:] & snan[:-1])
-    else:
-        nm, sv, perm = jax.lax.sort((~m, v, iota), num_keys=2)
-        neq = sv[1:] != sv[:-1]
+    with jax.named_scope("deequ.sort.unique_inverse"):
+        if jnp.issubdtype(v.dtype, jnp.floating):
+            nm, snan, sv, perm = jax.lax.sort(
+                (~m, v != v, v, iota), num_keys=3
+            )
+            neq = (sv[1:] != sv[:-1]) & ~(snan[1:] & snan[:-1])
+        else:
+            nm, sv, perm = jax.lax.sort((~m, v, iota), num_keys=2)
+            neq = sv[1:] != sv[:-1]
     sm = ~nm
     neq = jnp.concatenate([jnp.array([True]), neq])
     starts = neq & sm  # a new distinct value, among valid rows only
@@ -194,10 +196,11 @@ def _sorted_starts(mat, va):
     XLA:TPU's compile time; see _unique_inverse_kernel)."""
     k = mat.shape[0]
     mat = mat.astype(jnp.int32)
-    out = jax.lax.sort(
-        (~va,) + tuple(mat[i] for i in range(k - 1, -1, -1)),
-        num_keys=k + 1,
-    )
+    with jax.named_scope("deequ.sort.group_codes"):
+        out = jax.lax.sort(
+            (~va,) + tuple(mat[i] for i in range(k - 1, -1, -1)),
+            num_keys=k + 1,
+        )
     sva = ~out[0]
     smat = jnp.stack(out[:0:-1])
     neq = jnp.any(smat[:, 1:] != smat[:, :-1], axis=0)
@@ -523,7 +526,8 @@ def _rle_stats_kernel(mat, va):
     n = mat.shape[1]
     m = jnp.sum(sva)  # valid rows occupy the sorted prefix
     # positions are row indices (< 2^31): an i32 sort, not an emulated i64 one
-    pos = jnp.sort(jnp.where(starts, jnp.arange(n, dtype=jnp.int32), n))
+    with jax.named_scope("deequ.sort.run_positions"):
+        pos = jnp.sort(jnp.where(starts, jnp.arange(n, dtype=jnp.int32), n))
     counts = _run_lengths(pos, n, m.astype(jnp.int32))
     num_groups = jnp.sum(starts)
     singletons = jnp.sum(counts == 1)
@@ -619,6 +623,15 @@ def _prepare_grouping(
     """Derive one grouping set's codes/radices/packed keys.
     ``with_values=False`` skips the typed distinct-value arrays (the
     count-stats path never decodes group values)."""
+    with seam("grouping.host", columns=",".join(columns)):
+        return _pack_group_keys(
+            table, columns, require_any_non_null, with_values
+        )
+
+
+def _pack_group_keys(
+    table, columns, require_any_non_null, with_values
+) -> _GroupPrep:
     code_arrays = []
     value_arrays: Optional[List[np.ndarray]] = [] if with_values else None
     radices = []

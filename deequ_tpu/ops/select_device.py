@@ -64,6 +64,7 @@ mirror here — the host reference for tests is the sort path itself.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from deequ_tpu.ops.kll_device import strata_capacity, strata_weight
@@ -168,7 +169,8 @@ def _select_u32_multirank(u, ranks, xp):
 
     # -- pass 1: 16-bit leading digit, one shared full-range interval ----
     d1 = (u >> xp.uint32(_PASS1_BITS)).astype(xp.int32)
-    hist1 = _segment_count(d1, 1 << _PASS1_BITS, xp)
+    with jax.named_scope("deequ.select.pass1"):
+        hist1 = _segment_count(d1, 1 << _PASS1_BITS, xp)
     cum1 = xp.cumsum(hist1)
     pfx = xp.searchsorted(cum1, rank_rem, side="right").astype(xp.int32)
     below = xp.where(pfx > 0, cum1[xp.maximum(pfx - 1, 0)], 0)
@@ -187,7 +189,8 @@ def _select_u32_multirank(u, ranks, xp):
     row2 = lut2[d1]
     d2 = ((u >> xp.uint32(_PASS_BITS)) & xp.uint32(_B - 1)).astype(xp.int32)
     seg2 = xp.where(row2 < R, row2 * _B + d2, R * _B)
-    hist2 = _segment_count(seg2, R * _B + 1, xp)[: R * _B].reshape(R, _B)
+    with jax.named_scope("deequ.select.pass2"):
+        hist2 = _segment_count(seg2, R * _B + 1, xp)[: R * _B].reshape(R, _B)
     tcum2 = xp.cumsum(hist2, axis=1)[lut2[pfx]]
     bucket2, below2 = _bucket_of_rank(tcum2, rank_rem, xp)
     rank_rem = rank_rem - below2
@@ -203,7 +206,8 @@ def _select_u32_multirank(u, ranks, xp):
     row3 = lut3[xp.minimum(seg2, R * _B)]
     d3 = (u & xp.uint32(_B - 1)).astype(xp.int32)
     seg3 = xp.where(row3 < R, row3 * _B + d3, R * _B)
-    hist3 = _segment_count(seg3, R * _B + 1, xp)[: R * _B].reshape(R, _B)
+    with jax.named_scope("deequ.select.pass3"):
+        hist3 = _segment_count(seg3, R * _B + 1, xp)[: R * _B].reshape(R, _B)
     tcum3 = xp.cumsum(hist3, axis=1)[lut3[id3_t]]
     bucket3, below3 = _bucket_of_rank(tcum3, rank_rem, xp)
     rank_rem = rank_rem - below3

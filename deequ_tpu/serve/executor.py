@@ -34,16 +34,17 @@ device->host materialization of the (K, S) state matrix regardless of K.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deequ_tpu.obs.recorder import seam
 from deequ_tpu.ops.scan_engine import (
     SCAN_STATS,
     _ChunkPacker,
+    _scoped_update,
     _split_lut_key,
 )
 from deequ_tpu.ops.device_policy import device_call
@@ -211,7 +212,7 @@ def _build_packed_program(plan, lut_keys: Tuple[str, ...], op_order=None):
             jax.tree.map(
                 _tag_identity_wrap,
                 op.tags,
-                op.update(vals, row_valid, jnp, chunk),
+                _scoped_update(op, vals, row_valid, chunk),
             )
             for op in ops
         )
@@ -302,9 +303,16 @@ def run_coalesced(
     the batch runs with zero op builds, zero traces, zero compiles, and
     zero plan-lint traces (lint verdicts memoize under the packed key);
     a ``plan_cache_miss`` paid the one-time trace."""
-    from deequ_tpu.lint.plan_lint import enforce_plan_lint, lint_plan_cached
-    from deequ_tpu.ops.scan_plan import PackedMember, plan_packed_scan
+    with seam("scan_attempt", coalesced=len(tables), attempt=attempt):
+        return _run_coalesced(
+            plan, tables, labels, plan_lint, device_deadline, attempt,
+            packers,
+        )
 
+
+def _run_coalesced(
+    plan, tables, labels, plan_lint, device_deadline, attempt, packers,
+) -> List[List[Any]]:
     K = len(tables)
     assert K == len(labels) and K > 0
     if device_deadline is None:
@@ -315,20 +323,13 @@ def run_coalesced(
     while k_bucket < K:
         k_bucket <<= 1
 
-    t_start = time.time()
-    # coalesced-batch assembly is host work worth its own span: K tables
+    from deequ_tpu.lint.plan_lint import enforce_plan_lint, lint_plan_cached
+    from deequ_tpu.ops.scan_plan import PackedMember, plan_packed_scan
+
+    # coalesced-batch assembly is host work worth its own seam: K tables
     # pack + stack + LUTs pad to the group max — the serving path's one
     # per-batch host cost that scales with K
-    from contextlib import nullcontext
-
-    from deequ_tpu.obs.recorder import current_recorder
-
-    rec = current_recorder()
-    with (
-        rec.span("coalesce_assembly", tenants=K, bucket=k_bucket)
-        if rec is not None
-        else nullcontext()
-    ):
+    with seam("pack", what="coalesce_assembly", tenants=K, bucket=k_bucket):
         bufs = _stack_member_buffers(plan, tables, k_bucket, packers)
         lut_host, lut_sig = stack_luts(plan, tables, k_bucket)
 
@@ -492,19 +493,16 @@ def run_coalesced(
         "scan_id": scan_id, "attempt": attempt, "fallback": False,
         "chunk_index": 0, "device_ids": (), "coalesced": K,
     }
-    lut_dev = {k: jax.device_put(v) for k, v in lut_host.items()}
-    t_d = time.time()
+    with seam("stage", what="coalesced luts"):
+        lut_dev = {k: jax.device_put(v) for k, v in lut_host.items()}
     device_out = device_call(
         lambda: vstep(*bufs, lut_dev),
         "execute", what=f"coalesced dispatch (K={K}/{k_bucket})",
         deadline=device_deadline, hook_ctx=hook_ctx,
     )
-    SCAN_STATS.dispatch_seconds += time.time() - t_d
 
     def fetch() -> np.ndarray:
-        t0 = time.time()
         host = np.asarray(device_out)  # the batch's ONE round trip
-        SCAN_STATS.drain_wait_seconds += time.time() - t0
         SCAN_STATS.record_fetch(host.nbytes)
         return host
 
@@ -518,5 +516,4 @@ def run_coalesced(
         # callers consume exec-op order — permute back
         out.append([canonical[perm[i]] for i in range(len(canonical))])
     SCAN_STATS.chunks_processed += K
-    SCAN_STATS.scan_seconds += time.time() - t_start
     return out

@@ -21,6 +21,7 @@ from deequ_tpu.checks import Check, CheckLevel, CheckResult, CheckStatus
 from deequ_tpu.constraints import ConstraintStatus
 from deequ_tpu.data.table import ColumnarTable, Schema
 from deequ_tpu.metrics import Metric
+from deequ_tpu.obs.recorder import RUN_IDS, seam
 
 
 @dataclass
@@ -299,11 +300,6 @@ class VerificationSuite:
         )
         from deequ_tpu.resilience.retry import RETRY_TELEMETRY
 
-        analyzers = list(required_analyzers)
-        for check in checks:
-            analyzers.extend(check.required_analyzers())
-        unique_analyzers = _dedup_analyzers(analyzers)
-
         retry_before = RETRY_TELEMETRY.snapshot()
         events_before = len(SCAN_STATS.degradation_events)
         fallback_before = SCAN_STATS.fallback_scans
@@ -337,8 +333,8 @@ class VerificationSuite:
 
         # flight recorder: explicit ``trace`` argument > the caller's
         # ambient scope > the DEEQU_TPU_TRACE-armed global recorder. A
-        # traced run wraps everything (peer check + analysis) in one
-        # root span; the summary lands on result.run_trace below.
+        # traced run records everything under the ``run`` root seam;
+        # the summary lands on result.run_trace below.
         maybe_arm_from_env()
         recorder = (
             resolve_recorder(trace) if trace is not None
@@ -356,10 +352,17 @@ class VerificationSuite:
         with ExitStack() as _scopes:
             if trace is not None:
                 _scopes.enter_context(recording_scope(recorder))
-            if recorder is not None:
-                _scopes.enter_context(recorder.span("verification_run"))
+            # the root seam: every seam of this run opens under it and
+            # carries its run_id (peer check + analysis + evaluation +
+            # the repository append)
+            _scopes.enter_context(seam("run", run_id=next(RUN_IDS)))
             if armed_here is not None:
                 _scopes.enter_context(run_budget_scope(budget))
+            with seam("plan"):
+                analyzers = list(required_analyzers)
+                for check in checks:
+                    analyzers.extend(check.required_analyzers())
+                unique_analyzers = _dedup_analyzers(analyzers)
             # the peer check runs INSIDE the run (after the telemetry
             # baseline capture) so a degraded outcome lands on THIS
             # result's unverified_row_ranges/mesh_events delta
@@ -403,11 +406,24 @@ class VerificationSuite:
                 shard_deadline=shard_deadline,
             )
 
-        # evaluate BEFORE appending the new result: anomaly constraints query
-        # the repository history, which must not yet contain this run
-        # (reference VerificationSuite.scala evaluates at L263-281, then saves
-        # at L174-193)
-        result = VerificationSuite._evaluate(checks, analysis_context)
+            # evaluate BEFORE appending the new result: anomaly
+            # constraints query the repository history, which must not
+            # yet contain this run (reference VerificationSuite.scala
+            # evaluates at L263-281, then saves at L174-193)
+            with seam("evaluate"):
+                result = VerificationSuite._evaluate(
+                    checks, analysis_context
+                )
+            if (
+                metrics_repository is not None
+                and save_or_append_results_with_key is not None
+            ):
+                with seam("repository"):
+                    _save_or_append(
+                        metrics_repository,
+                        save_or_append_results_with_key,
+                        analysis_context,
+                    )
         # degradation + retry telemetry taken DURING this run (deltas
         # against the process-wide counters)
         result.device_events = [
@@ -446,12 +462,6 @@ class VerificationSuite:
             else getattr(SCAN_STATS, k) - v
             for k, v in scan_before.items()
         }
-
-        if metrics_repository is not None and save_or_append_results_with_key is not None:
-            _save_or_append(
-                metrics_repository, save_or_append_results_with_key,
-                analysis_context,
-            )
 
         VerificationSuite._save_json_outputs(
             result,
@@ -598,9 +608,11 @@ class IncrementalVerificationStream:
         for result_key, ctx in drained:
             # evaluate BEFORE appending (anomaly constraints must not see
             # their own run in the history — reference ordering)
-            result = VerificationSuite._evaluate(self.checks, ctx)
+            with seam("evaluate"):
+                result = VerificationSuite._evaluate(self.checks, ctx)
             if self.metrics_repository is not None and result_key is not None:
-                _save_or_append(self.metrics_repository, result_key, ctx)
+                with seam("repository"):
+                    _save_or_append(self.metrics_repository, result_key, ctx)
             out.append((result_key, result))
         return out
 

@@ -17,6 +17,7 @@ this file. Keep these tests in ONE file (one worker owns the library).
 """
 
 import os
+import re
 import sys
 
 import numpy as np
@@ -268,7 +269,10 @@ def test_selection_kernel_compiles_at_config3_width(as_tpu, one_chip):
         _aval((K, n), np.bool_, one_chip),
         _aval((K, n), np.float32, one_chip),
     )
-    assert "sort" not in compiled.as_text().replace("sorted", "")
+    # a sort INSTRUCTION, not the word: the module's text also lists the
+    # call stack of whoever first traced the cached bincount program (a
+    # test named "..._sort_reference_..." when it shares this worker)
+    assert not re.search(r"\bsort\(", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 14 << 30
 
 

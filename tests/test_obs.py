@@ -15,6 +15,7 @@ from deequ_tpu.analyzers import Completeness, Maximum, Mean, Minimum, Size
 from deequ_tpu.analyzers.runner import AnalysisRunner
 from deequ_tpu.data.table import Column, ColumnarTable, DType
 from deequ_tpu.obs import (
+    SEAM_NAMES,
     FlightRecorder,
     current_recorder,
     install_global_recorder,
@@ -111,9 +112,15 @@ def test_scan_spans_nest_under_attempt():
     attempt = attempts[0]
     seam_spans = [
         r for r in _spans(rec)
-        if r.name in ("transfer", "trace", "execute", "fetch")
+        if r.name in ("pack", "stage", "build", "dispatch", "fetch")
     ]
-    assert seam_spans, "no device-boundary spans recorded"
+    assert {"stage", "build", "fetch"} <= {r.name for r in seam_spans}, (
+        "no device-boundary spans recorded"
+    )
+    # spans of one scan share its scan_id
+    assert {r.args["scan_id"] for r in seam_spans} == {
+        attempt.args["scan_id"]
+    }
     # every seam span of this scan parents (transitively) to the attempt
     by_id = {r.span_id: r for r in rec.records()}
     for r in seam_spans:
@@ -258,6 +265,254 @@ def test_env_var_trace_garbage_raises_typed(monkeypatch):
         env_value("DEEQU_TPU_TRACE_CAPACITY")
 
 
+# -- the seam primitive ------------------------------------------------------
+
+
+def _seam_counters():
+    from deequ_tpu.obs import seam_fields
+
+    fields = [f for name in SEAM_NAMES for f in seam_fields(name)]
+    fields += ["dispatch_seconds", "drain_wait_seconds", "scan_seconds"]
+    return {f: getattr(SCAN_STATS, f) for f in fields}
+
+
+def _seam_deltas(before):
+    return {k: v - before[k] for k, v in _seam_counters().items()}
+
+
+@pytest.mark.parametrize("name", SEAM_NAMES)
+def test_seam_fields_are_numbers_straight_after_reset(name):
+    """The benchmark takes numbers only from ``snapshot()`` and leaves a
+    metric out when a name is missing: every seam's two fields must be
+    there, as numbers, before the seam ever opened."""
+    from deequ_tpu.obs import seam_fields
+    from deequ_tpu.ops.scan_engine import ScanStats
+
+    stats = ScanStats()
+    stats.reset()
+    snap = stats.snapshot()
+    seconds, count = seam_fields(name)
+    assert "." not in seconds and seconds.startswith("seam_")
+    assert type(snap[seconds]) is float and snap[seconds] == 0.0
+    assert type(snap[count]) is int and snap[count] == 0
+
+
+def test_seam_exclusive_accounting_sums_to_the_root():
+    """While a child seam is open the parent's clock stands still: the
+    exclusive seconds of nested seams on one thread sum to the outermost
+    seam's duration, and an enclosing seam bills its whole wall."""
+    import time
+
+    from deequ_tpu.obs import seam
+
+    before = _seam_counters()
+    t0 = time.perf_counter()
+    with seam("scan_attempt", scan_id=-1):
+        with seam("plan"):
+            time.sleep(0.010)
+            with seam("pack", chunk=0):
+                time.sleep(0.010)
+                with seam("stage"):
+                    time.sleep(0.005)
+            with seam("evaluate"):
+                time.sleep(0.005)
+    wall = time.perf_counter() - t0
+    d = _seam_deltas(before)
+    counted = sum(
+        v for k, v in d.items()
+        if k.startswith("seam_") and k.endswith("_seconds")
+    )
+    assert abs(counted - wall) < 1e-3, (counted, wall)
+    assert abs(d["scan_seconds"] - wall) < 1e-3
+    # each seam kept its OWN time only
+    assert 0.010 <= d["seam_plan_seconds"] < 0.010 + 4e-3
+    assert 0.010 <= d["seam_pack_seconds"] < 0.010 + 4e-3
+    assert 0.005 <= d["seam_stage_seconds"] < 0.005 + 4e-3
+    assert d["seam_plan_count"] == d["seam_pack_count"] == 1
+    # the older fields are the sums they are defined as
+    assert d["dispatch_seconds"] == pytest.approx(d["seam_stage_seconds"])
+    assert d["drain_wait_seconds"] == 0.0
+
+
+def test_unknown_seam_name_is_an_error():
+    from deequ_tpu.obs import seam
+
+    with pytest.raises(KeyError):
+        with seam("no_such_seam"):
+            pass
+
+
+def test_worker_thread_seam_is_a_span_only():
+    """Seams on an engine worker thread add to no counter (the caller's
+    wait for the worker is the counted seam) but still record, with the
+    ids the caller handed over."""
+    import threading
+
+    from deequ_tpu.obs import seam
+    from deequ_tpu.obs.recorder import worker_seams
+
+    rec = FlightRecorder()
+    before = _seam_counters()
+
+    def work():
+        with recording_scope(rec), worker_seams({"run_id": 41}):
+            with seam("pack", chunk=3):
+                pass
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert all(v == 0 for v in _seam_deltas(before).values())
+    (span,) = _spans(rec, "pack")
+    assert span.args == {"run_id": 41, "chunk": 3}
+
+
+def test_watchdog_call_counts_once_on_the_caller():
+    """device_call under a deadline runs its body on a watchdog worker:
+    the caller's seam counts the wait, a seam inside the body does not
+    count again."""
+    from deequ_tpu.obs import seam
+    from deequ_tpu.ops.device_policy import device_call
+
+    def body():
+        with seam("fetch", bytes=8):
+            return 7
+
+    before = _seam_counters()
+    assert device_call(body, "fetch", what="probe", deadline=30.0) == 7
+    d = _seam_deltas(before)
+    assert d["seam_fetch_count"] == 1
+    assert d["drain_wait_seconds"] == pytest.approx(d["seam_fetch_seconds"])
+
+
+def _append(table, states, repository, key):
+    from deequ_tpu import Check, CheckLevel, VerificationSuite
+    from deequ_tpu.repository import ResultKey
+
+    return (
+        VerificationSuite.on_data(table)
+        .add_check(Check(CheckLevel.ERROR, "a").has_size(lambda n: n > 0))
+        .add_required_analyzers(_analyzers())
+        .aggregate_with(states)
+        .save_states_with(states)
+        .use_repository(repository)
+        .save_or_append_result(ResultKey(key, {"stream": "append"}))
+        .run()
+    )
+
+
+def _suite_run():
+    from deequ_tpu import Check, CheckLevel, VerificationSuite
+
+    result = (
+        VerificationSuite.on_data(_table())
+        .add_check(Check(CheckLevel.ERROR, "t").has_size(lambda n: n == 4096))
+        .add_required_analyzers(_analyzers())
+        .run()
+    )
+    assert str(result.status).endswith("SUCCESS")
+    return 1
+
+
+def _two_appends():
+    from deequ_tpu.repository.memory import InMemoryMetricsRepository
+    from deequ_tpu.states import InMemoryStateProvider
+
+    states, repository = InMemoryStateProvider(), InMemoryMetricsRepository()
+    for key in (0, 1):
+        result = _append(_table(seed=key), states, repository, key)
+        assert str(result.status).endswith("SUCCESS")
+    return 2
+
+
+def test_legacy_wait_fields_equal_their_seam_sums():
+    before = _seam_counters()
+    _suite_run()
+    _two_appends()
+    d = _seam_deltas(before)
+    assert d["seam_dispatch_count"] + d["seam_build_count"] >= 3
+    assert d["dispatch_seconds"] == pytest.approx(
+        d["seam_stage_seconds"] + d["seam_dispatch_seconds"], abs=1e-9
+    )
+    assert d["drain_wait_seconds"] == pytest.approx(
+        d["seam_drain_seconds"] + d["seam_fetch_seconds"], abs=1e-9
+    )
+    assert d["seam_states_count"] == 2 and d["seam_repository_count"] == 2
+
+
+@pytest.mark.parametrize(
+    "workload,leaves",
+    [
+        (_suite_run, ("pack", "stage", "dispatch", "fetch", "evaluate")),
+        (_two_appends, ("pack", "stage", "dispatch", "fetch", "states",
+                        "evaluate", "repository")),
+    ],
+    ids=["suite", "two_appends"],
+)
+def test_seams_land_on_the_profilers_clock(workload, leaves, tmp_path):
+    """Inside a profiler session every seam is a ``deequ.<name>``
+    annotation in the ``.xplane.pb``: the leaves lie inside a
+    ``deequ.run`` and never overlap each other on the caller's thread."""
+    import jax
+
+    from chipbench import trace_reduce
+
+    workload()  # builds the program: the traced calls dispatch, not build
+    with jax.profiler.trace(str(tmp_path)):
+        runs = workload()
+    names = ["deequ." + n for n in SEAM_NAMES + ("run", "scan_attempt")]
+    host = trace_reduce.read_xplane(str(tmp_path), names)["host"]
+    by_name = {}
+    for name, start, end in host:
+        by_name.setdefault(name, []).append((start, end))
+    assert len(by_name["deequ.run"]) == runs
+    assert len(by_name["deequ.scan_attempt"]) == runs
+    spans = sorted(
+        (start, end, name) for name, start, end in host
+        if name[len("deequ."):] in leaves
+    )
+    assert {name for _, _, name in spans} == {"deequ." + n for n in leaves}
+    for start, end, name in spans:
+        assert any(
+            lo <= start and end <= hi for lo, hi in by_name["deequ.run"]
+        ), name
+    for (_, end, a), (start, _, b) in zip(spans, spans[1:]):
+        assert start >= end, f"{a} overlaps {b}"
+
+
+def test_fused_step_names_every_op_of_the_scan_suite():
+    """The device side of the boundaries: each op's reductions lower
+    under ``deequ.<Analyzer>.<column>`` (metadata only), so a device
+    trace names what its XLA ops belong to."""
+    import json
+    import os
+
+    from chipbench import cells, suite_build
+    from chipbench.generators import profile_table
+    from deequ_tpu.ops import scan_engine as eng
+
+    with open(os.path.join(cells.ROOT, "chipbench", "suites", "scan.json")) as f:
+        suite = json.load(f)
+    cell = cells.load_cell("profile10m.scan")
+    rows = 2048
+    table = suite_build.table_of(
+        profile_table.generate(rows, 7, cell["config"]["generator_params"])
+    )
+    analyzers = suite_build.analyzers_of(suite)
+    ops, scannable, failures = AnalysisRunner._build_scan_ops(table, analyzers)
+    assert len(ops) == len(suite["analyzers"]) and not failures
+    needed = sorted({c for op in ops for c in op.columns})
+    packer = eng._ChunkPacker({n: table[n] for n in needed}, rows)
+    step_fn, _, _ = eng._build_step_fns(ops, packer.unpack_view(), None, rows)
+    text = step_fn.lower(*packer.pack(0, rows), {}).as_text(debug_info=True)
+    for op, entry in zip(ops, suite["analyzers"]):
+        scope = f"deequ.{entry['analyzer']}.{'_'.join(entry['args'])}"
+        assert eng.op_scope(op) == scope
+        assert scope + "/" in text, scope
+    assert "deequ.unpack/" in text and "deequ.flatten/" in text
+
+
 # -- verification surface ----------------------------------------------------
 
 
@@ -275,7 +530,7 @@ def test_with_tracing_summary_on_result():
     assert str(result.status).endswith("SUCCESS")
     assert result.trace_recorder is not None
     assert result.run_trace["spans"] > 0
-    assert "verification_run" in result.run_trace["phases"]
+    assert "run" in result.run_trace["phases"]
     assert "scan_attempt" in result.run_trace["phases"]
     # untraced runs carry an empty summary
     plain = VerificationSuite.run(_table(), [])
@@ -285,10 +540,10 @@ def test_with_tracing_summary_on_result():
 def test_run_trace_reconciles_with_scan_stats():
     """The per-phase wall breakdown must reconcile with the ScanStats
     wall counters: the attempt span contains the dispatch window and
-    the drain wait, and the boundary spans (transfer+execute+fetch)
-    cover the same device time dispatch_seconds/drain_wait_seconds
-    account (generous absolute slack — both clocks bracket slightly
-    different host lines)."""
+    the drain wait, and the boundary spans (stage, build, dispatch,
+    drain, fetch) cover the same device time
+    dispatch_seconds/drain_wait_seconds account (generous absolute
+    slack — the recorder and the counters read different clocks)."""
     from deequ_tpu.verification import VerificationSuite
 
     before = {
@@ -310,11 +565,11 @@ def test_run_trace_reconciles_with_scan_stats():
     # ScanStats wall counters do
     boundary_wall = sum(
         phases.get(name, {"wall_seconds": 0.0})["wall_seconds"]
-        for name in ("transfer", "trace", "execute", "fetch")
+        for name in ("stage", "build", "dispatch", "drain", "fetch")
     )
     assert boundary_wall >= (dispatch + drain) - SLACK
     assert boundary_wall <= attempt_wall + SLACK
-    assert phases["verification_run"]["wall_seconds"] + SLACK >= attempt_wall
+    assert phases["run"]["wall_seconds"] + SLACK >= attempt_wall
 
 
 # -- export ------------------------------------------------------------------
@@ -536,10 +791,12 @@ def test_traced_coalesced_serve_exports_tenant_spans(no_mesh, tmp_path):
     assert {r.track for r in tenant_spans} == {
         f"tenant/t{i}" for i in range(K)
     }
-    # exactly one coalesced execute+fetch pair served all K tenants
+    # exactly one coalesced dispatch+fetch pair served all K tenants (the
+    # first call of the packed program is its `build`)
     exec_spans = [
-        r for r in _spans(rec, "execute")
-        if "coalesced" in r.args.get("what", "")
+        r for r in _spans(rec)
+        if r.name in ("dispatch", "build")
+        and "coalesced dispatch" in r.args.get("what", "")
     ]
     fetch_spans = [
         r for r in _spans(rec, "fetch")
@@ -551,7 +808,10 @@ def test_traced_coalesced_serve_exports_tenant_spans(no_mesh, tmp_path):
     for r in tenant_spans:
         assert r.t_start <= exec_spans[0].t_start
         assert r.t_end >= fetch_spans[0].t_end - 1e-6
-    assert _spans(rec, "coalesce_assembly")
+    assert [
+        r for r in _spans(rec, "pack")
+        if r.args.get("what") == "coalesce_assembly"
+    ]
     assert _events(rec, "serve_submit")
     path = write_chrome_trace(rec, str(tmp_path / "serve.json"))
     trace = json.load(open(path))
