@@ -48,35 +48,72 @@ PAIR_SAFE_MAX = float(2 ** 59)
 TREE_LEVELS = 5
 
 
-def split_pair_np(x: np.ndarray):
-    """Host-side packer split: f64 -> (hi, lo) f32 planes.
+# Elements per block of the two host-side walks below. Their scratch (two
+# f64 blocks and a bool block, ~0.5 MB) stays inside a core's L2 and is
+# local to the call: nothing column-sized is allocated, because every such
+# temporary is page-faulted in and handed back. Flat from 2^14 to 2^17 in
+# a sweep on the v5e's host (PERF.md, PR 25). A constant, not a knob.
+_HOST_BLOCK = 1 << 15
+
+
+def split_pair_np(x: np.ndarray, hi: np.ndarray = None, lo: np.ndarray = None):
+    """Host-side packer split: 1-D f64 -> (hi, lo) f32 planes.
 
     Mirrors ops/hll.py:_f64_key_u64 exactly (canonical +0.0 fold first) so
     device HLL hashing over the shipped pair is bit-identical to hashing
     the f64 values. Non-finite residuals (x = +/-inf => x - hi = nan)
     are zeroed so sums over columns containing infinities still produce
     the IEEE result (inf/nan) through the hi plane alone.
+
+    ``hi`` and ``lo`` are the destinations, each as long as ``x`` (the
+    packer passes rows of its staging planes); without them the call
+    allocates and returns its own.
     """
-    canonical = x + 0.0
+    n = len(x)
+    if hi is None:
+        hi = np.empty(n, dtype=np.float32)
+        lo = np.empty(n, dtype=np.float32)
+    block = min(_HOST_BLOCK, n)
+    canonical = np.empty(block, dtype=np.float64)
+    diff = np.empty(block, dtype=np.float64)
+    finite = np.empty(block, dtype=np.bool_)
     with np.errstate(over="ignore", invalid="ignore"):
-        hi = canonical.astype(np.float32)
-        diff = canonical - hi.astype(np.float64)
-        lo = np.where(np.isfinite(diff), diff, 0.0).astype(np.float32)
+        for start in range(0, n, _HOST_BLOCK):
+            stop = min(start + _HOST_BLOCK, n)
+            if stop - start < block:  # only the last block can be short
+                block = stop - start
+                canonical, diff, finite = (
+                    canonical[:block], diff[:block], finite[:block]
+                )
+            h = hi[start:stop]
+            np.add(x[start:stop], 0.0, out=canonical)
+            h[...] = canonical
+            np.subtract(canonical, h, out=diff)
+            np.isfinite(diff, out=finite)
+            # (count_nonzero: a third of .all()'s cost on a 256-row tenant)
+            if np.count_nonzero(finite) < block:
+                diff[np.logical_not(finite, out=finite)] = 0.0
+            lo[start:stop] = diff
     return hi, lo
 
 
 def pair_safe_np(values: np.ndarray) -> bool:
     """True when every finite value is safe for the f32-pair COMPUTE path
     (|x| <= PAIR_SAFE_MAX, leaving headroom for squares and partial-sum
-    growth); columns with larger magnitudes ship as wide f64."""
-    if len(values) == 0:
-        return True
-    with np.errstate(invalid="ignore"):
-        finite = values[np.isfinite(values)]
-    if len(finite) == 0:
-        return True
-    m = float(np.max(np.abs(finite)))
-    return m <= PAIR_SAFE_MAX
+    growth); columns with larger magnitudes ship as wide f64. False as
+    soon as a block holds an unsafe value."""
+    n = len(values)
+    scratch = np.empty(min(_HOST_BLOCK, n), dtype=np.float64)
+    for start in range(0, n, _HOST_BLOCK):
+        stop = min(start + _HOST_BLOCK, n)
+        mag = np.abs(values[start:stop], out=scratch[: stop - start])
+        # a NaN or an inf fails the first comparison too: only then is the
+        # block read again, for the largest of its finite values
+        if not mag.max() <= PAIR_SAFE_MAX and (
+            mag.max(where=np.isfinite(mag), initial=0.0) > PAIR_SAFE_MAX
+        ):
+            return False
+    return True
 
 
 def two_sum(a, b):

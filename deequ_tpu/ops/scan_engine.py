@@ -830,14 +830,14 @@ class _ChunkPacker:
         for i, name in enumerate(self.wide_names):
             values[i, :n] = self.cols[name].values[start:stop]
         for i, name in enumerate(self.pair_names):
-            h, l = split_pair_np(self.cols[name].values[start:stop])
-            hi[self._hi_row[name], :n] = h
-            lo[i, :n] = l
-        for name in self.hi_only_names:
-            with np.errstate(over="ignore", invalid="ignore"):
-                hi[self._hi_row[name], :n] = self.cols[name].values[
-                    start:stop
-                ].astype(np.float32)
+            split_pair_np(
+                self.cols[name].values[start:stop],
+                hi[self._hi_row[name], :n],
+                lo[i, :n],
+            )
+        with np.errstate(over="ignore", invalid="ignore"):
+            for name in self.hi_only_names:
+                hi[self._hi_row[name], :n] = self.cols[name].values[start:stop]
         for i, name in enumerate(self.narrow_i32):
             narrow_i[i, :n] = self.cols[name].values[start:stop]
         for name, i in self._mask_row.items():

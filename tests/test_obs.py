@@ -322,12 +322,20 @@ def test_seam_exclusive_accounting_sums_to_the_root():
         v for k, v in d.items()
         if k.startswith("seam_") and k.endswith("_seconds")
     )
-    assert abs(counted - wall) < 1e-3, (counted, wall)
-    assert abs(d["scan_seconds"] - wall) < 1e-3
-    # each seam kept its OWN time only
-    assert 0.010 <= d["seam_plan_seconds"] < 0.010 + 4e-3
-    assert 0.010 <= d["seam_pack_seconds"] < 0.010 + 4e-3
-    assert 0.005 <= d["seam_stage_seconds"] < 0.005 + 4e-3
+    # the seams share their timestamps: counting a second twice would put
+    # the sum over the root's wall, whatever the machine's load. What the
+    # sum lacks is the enclosing seam's own few microseconds
+    root = d["scan_seconds"]
+    assert counted <= root + 1e-9, (counted, root)
+    assert root - counted < 2.5e-3, (counted, root)
+    assert 0.030 <= root <= wall
+    # each seam kept its OWN time only: at least its sleep, and no more
+    # than the root's wall less the sleeps that ran under the other seams
+    # (no ceiling on the sleeps themselves: a loaded machine oversleeps)
+    assert 0.010 <= d["seam_plan_seconds"] <= root - 0.020 + 1e-9
+    assert 0.010 <= d["seam_pack_seconds"] <= root - 0.020 + 1e-9
+    assert 0.005 <= d["seam_stage_seconds"] <= root - 0.025 + 1e-9
+    assert 0.005 <= d["seam_evaluate_seconds"] <= root - 0.025 + 1e-9
     assert d["seam_plan_count"] == d["seam_pack_count"] == 1
     # the older fields are the sums they are defined as
     assert d["dispatch_seconds"] == pytest.approx(d["seam_stage_seconds"])
