@@ -388,10 +388,17 @@ def _retry_section() -> dict:
 
 
 def _hbm_section() -> dict:
-    from deequ_tpu.ops.scan_engine import _ACTIVE_CACHES, total_resident_bytes
+    """``resident_bytes``: over all devices; ``resident_bytes_per_device``:
+    on the fullest one, which is what the residency budget bounds."""
+    from deequ_tpu.ops.scan_engine import (
+        _ACTIVE_CACHES,
+        resident_bytes_per_device,
+        total_resident_bytes,
+    )
 
     return {
         "resident_bytes": total_resident_bytes(),
+        "resident_bytes_per_device": resident_bytes_per_device(),
         "resident_tables": len(_ACTIVE_CACHES),
     }
 

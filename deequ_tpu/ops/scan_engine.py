@@ -86,6 +86,10 @@ DEFAULT_CHUNK_ROWS = 1 << 20
 # double-buffer comfortably in HBM
 DEFAULT_CHUNK_BYTES = 512 << 20
 MAX_CHUNK_ROWS = 1 << 23
+# persist()'s resident chunks: what ONE device holds of a chunk, and the
+# most rows a chunk has whatever the mesh
+RESIDENT_CHUNK_BYTES = 2 << 30
+MAX_RESIDENT_CHUNK_ROWS = 1 << 25
 # streaming chunks are smaller: several live copies per chunk exist at once
 # (decoded batch in the prefetch queue, packed buffers, in-flight transfers),
 # so the host-RSS bound is ~6x the chunk size
@@ -346,6 +350,11 @@ class ScanStats:
         self.mesh_stragglers = 0
         self.peer_losses = 0
         self.unverified_row_ranges = []
+        # collective leaves dispatched on a mesh: each dispatch of a
+        # sharded step adds the number of state leaves its program merges
+        # across the mesh (one psum or all_gather each, _tag_collective);
+        # counted on the host at the dispatch, 0 without a mesh
+        self.mesh_collectives = 0
         # static plan lint (deequ_tpu/lint/plan_lint.py, armed via
         # run_scan(plan_lint=...) / DEEQU_TPU_PLAN_LINT): finding rows
         # the jaxpr pass produced for this process's scans, and how many
@@ -555,21 +564,30 @@ def _tag_reduce_np(tag: str, a, b):
 
 
 def _tag_collective(tag: str, leaf, axis_name: str):
-    if tag == "sum":
-        return jax.lax.psum(leaf, axis_name)
-    if tag in ("min", "max"):
-        # all_gather + local reduce, not pmin/pmax: XLA:TPU lowers a 64-bit
-        # all-reduce for Sum only ("Supported lowering only of Sum all
-        # reduce"), and min/max leaves are f64. Both reductions are
-        # exactly associative, so the result is bit-identical; the leaves
-        # are a few scalars (or one 512-register HLL file) per op.
+    """One state leaf merged across the mesh, under the scope
+    ``deequ.collective.<tag>`` (what a device trace calls its time)."""
+    if tag not in ("sum", "min", "max", "gather"):
+        raise ValueError(f"unknown reduce tag {tag}")
+    with jax.named_scope(f"deequ.collective.{tag}"):
+        if tag == "sum":
+            return jax.lax.psum(leaf, axis_name)
+        if tag == "gather":
+            return jax.lax.all_gather(
+                jnp.atleast_1d(leaf), axis_name
+            ).reshape((-1,) + jnp.shape(jnp.atleast_1d(leaf))[1:])
+        # min / max: all_gather + local reduce, not pmin/pmax: XLA:TPU
+        # lowers a 64-bit all-reduce for Sum only ("Supported lowering only
+        # of Sum all reduce"), and min/max leaves are f64. Both reductions
+        # are exactly associative, so the result is bit-identical; the
+        # leaves are a few scalars (or one 512-register HLL file) per op.
         gathered = jax.lax.all_gather(leaf, axis_name)
         return gathered.min(axis=0) if tag == "min" else gathered.max(axis=0)
-    if tag == "gather":
-        return jax.lax.all_gather(jnp.atleast_1d(leaf), axis_name).reshape(
-            (-1,) + jnp.shape(jnp.atleast_1d(leaf))[1:]
-        )
-    raise ValueError(f"unknown reduce tag {tag}")
+
+
+def _state_tags(ops) -> List[str]:
+    """The reduce tag of every state leaf of every op: on a mesh each is
+    one collective a dispatch of the sharded step (``_tag_collective``)."""
+    return [tag for op in ops for tag in jax.tree.leaves(op.tags)]
 
 
 def _tag_identity_wrap(tag: str, leaf):
@@ -1006,7 +1024,10 @@ class DeviceTableCache:
     straight from HBM.
     """
 
-    MAX_RESIDENT_BYTES = 12 << 30  # leave headroom in 16GB v5e HBM
+    # what ONE device may hold resident: leaves headroom in a v5e chip's
+    # 16 GB of HBM. Row-sharded tables are held to it by their per-device
+    # share (per_device_bytes), never by their total
+    MAX_RESIDENT_BYTES = 12 << 30
     MAX_CACHED_PROGRAMS = 32  # LRU cap on traced programs per table
 
     def __init__(self, packer, chunk, device_chunks, mesh, nbytes, device_count):
@@ -1026,14 +1047,30 @@ class DeviceTableCache:
         self.programs = _BoundedLRU(self.MAX_CACHED_PROGRAMS)
         _ACTIVE_CACHES.add(self)
 
+    @property
+    def resident_bytes(self) -> int:
+        """The table's HBM footprint over all its devices: a built
+        stacked fused-loop copy doubles it."""
+        return self.nbytes * (2 if self._stacked is not None else 1)
+
+    @property
+    def per_device_bytes(self) -> int:
+        """What each device of the mesh holds of it: every buffer is
+        row-sharded over a chunk that the mesh divides, so the shares are
+        equal."""
+        return self.resident_bytes // self.device_count
+
     def stacked_chunks(self):
         """The resident chunks stacked along a leading chunk axis (for the
         one-dispatch fused loop), or None when a second copy of the table
-        would blow the combined HBM budget. Built once per cache."""
+        would blow a device's HBM budget. Built once per cache."""
         if len(self.device_chunks) < 2:
             return None
         if self._stacked is None:
-            if total_resident_bytes() + self.nbytes > self.MAX_RESIDENT_BYTES:
+            if (
+                resident_bytes_per_device() + self.per_device_bytes
+                > self.MAX_RESIDENT_BYTES
+            ):
                 return None
             self._stacked = tuple(
                 jnp.stack([c[j] for c in self.device_chunks])
@@ -1063,7 +1100,7 @@ class DeviceTableCache:
 
 # Live caches (weakly held): persist() checks the COMBINED resident
 # footprint — e.g. the profiler holding both the raw and the numeric-cast
-# table — against the HBM budget, not just the newest table's size.
+# table — against a device's HBM budget, not just the newest table's share.
 _ACTIVE_CACHES: "weakref.WeakSet[DeviceTableCache]" = weakref.WeakSet()
 
 # Global traced-program cache for STREAMING runs over tables with identical
@@ -1080,25 +1117,34 @@ _GLOBAL_PROGRAMS = _BoundedLRU(64)
 
 
 def total_resident_bytes() -> int:
-    # a built stacked fused-loop copy doubles that cache's true HBM
-    # footprint — count it, or the budget gate overcommits the device
-    return sum(
-        c.nbytes * (2 if c._stacked is not None else 1)
-        for c in _ACTIVE_CACHES
-    )
+    """Bytes resident over ALL devices: the ledger that returns to zero
+    when every table is unpersisted."""
+    return sum(c.resident_bytes for c in _ACTIVE_CACHES)
+
+
+def resident_bytes_per_device() -> int:
+    """Bytes resident on the FULLEST device, which is what
+    ``MAX_RESIDENT_BYTES`` bounds: every live cache's per-device share,
+    summed (tables on different meshes are taken to meet on one device).
+    On one device it equals ``total_resident_bytes()``."""
+    return sum(c.per_device_bytes for c in _ACTIVE_CACHES)
 
 
 def persist_table(
     table: ColumnarTable,
     mesh=None,
     chunk_rows: Optional[int] = None,
-    max_bytes: int = DeviceTableCache.MAX_RESIDENT_BYTES,
+    max_bytes: Optional[int] = None,
     encode: Optional[bool] = None,
 ) -> DeviceTableCache:
     """Pack ALL columns of the table and transfer them to device HBM once.
 
     Returns the cache and attaches it to ``table._device_cache`` so every
     subsequent ``run_scan`` over this table skips host packing + transfer.
+
+    ``max_bytes`` (default ``DeviceTableCache.MAX_RESIDENT_BYTES``) bounds
+    what ONE device holds: under a mesh the table's per-device share plus
+    what is resident there already; past it the typed ``MemoryError``.
 
     Columns carrying a dictionary encoding stay ENCODED in HBM (int16
     code plane + dictionary LUTs, 2-8x smaller than the decoded planes —
@@ -1108,16 +1154,24 @@ def persist_table(
     from deequ_tpu.ops.scan_plan import encoded_ingest_enabled
 
     encode = encoded_ingest_enabled(encode)
+    if max_bytes is None:
+        max_bytes = DeviceTableCache.MAX_RESIDENT_BYTES
     if mesh is None:
         mesh = current_mesh()
     cols = {name: table[name] for name in table.column_names}
     n_rows = table.num_rows
     n_dev = math.prod(mesh.devices.shape) if mesh is not None else 1
     # resident chunks can be much larger than streaming ones: every extra
-    # chunk costs a device dispatch + result fetch, and HBM holds the
-    # whole table anyway
+    # chunk costs a device dispatch (on a mesh a round of collectives and
+    # a fold merge too), and each device's HBM holds its share of the
+    # whole table anyway. The byte target is per device, so the chunk in
+    # rows grows with the mesh
     chunk = chunk_rows or min(
-        _auto_chunk_rows(cols, target_bytes=2 << 30, max_rows=1 << 25),
+        _auto_chunk_rows(
+            cols,
+            target_bytes=RESIDENT_CHUNK_BYTES * n_dev,
+            max_rows=MAX_RESIDENT_CHUNK_ROWS,
+        ),
         max(n_rows, 1),
     )
     chunk = max(n_dev, ((chunk + n_dev - 1) // n_dev) * n_dev)
@@ -1137,12 +1191,13 @@ def persist_table(
             args = packer.pack(start, stop)
         chunk_bytes = sum(a.nbytes for a in args)
         nbytes += chunk_bytes
-        if nbytes + total_resident_bytes() > max_bytes:
+        if nbytes // n_dev + resident_bytes_per_device() > max_bytes:
             raise MemoryError(
                 f"persist_table: combined resident size would exceed "
-                f"{max_bytes} bytes; stream instead or raise max_bytes"
+                f"{max_bytes} bytes on each of {n_dev} device(s); stream "
+                f"instead or raise max_bytes"
             )
-        with seam("persist.stage", chunk=ci, bytes=chunk_bytes):
+        with seam("persist.stage", chunk=ci, bytes=chunk_bytes, devices=n_dev):
             device_chunks.append(put(args))
     with seam("persist.stage", wait=True):
         jax.block_until_ready(device_chunks)
@@ -2427,9 +2482,9 @@ def _run_scan_once(
     # a merge dispatch (a round trip on serialized links), so skip it.
     # Gather-leaf ops cap at MAX_FOLD_CAPACITY chunks (the gather region
     # scales with the chunk count — see the constant's rationale).
-    has_gather = any(
-        tag == "gather" for op in ops for tag in jax.tree.leaves(op.tags)
-    )
+    tags = _state_tags(ops)
+    has_gather = "gather" in tags
+    collectives = len(tags) if mesh is not None else 0
     use_fold = (
         n_chunks > 1
         and (not has_gather or n_chunks <= MAX_FOLD_CAPACITY)
@@ -2454,6 +2509,7 @@ def _run_scan_once(
     def after_dispatch(flat, ci) -> None:
         """Fold or queue one chunk's result, window-bounded."""
         _record_kernel_passes(plan_ir, 1)
+        SCAN_STATS.mesh_collectives += collectives
         in_flight.append(flat)
         if use_fold:
             fold_chunk(flat, ci)
@@ -3130,6 +3186,7 @@ def _run_scan_stream(
     local_n = chunk // n_dev if mesh is not None else chunk
     put = _make_put(mesh)
     baked = any(op.dictionary_baked for op in ops)
+    collectives = len(_state_tags(ops)) if mesh is not None else 0
 
     SCAN_STATS.scan_passes += 1
 
@@ -3172,6 +3229,7 @@ def _run_scan_stream(
             seam_name="build" if building else None,
         )
         _record_kernel_passes(plan_ir, 1)
+        SCAN_STATS.mesh_collectives += collectives
         if use_fold:
             if fold_state["plan"] is None:
                 fold_state["plan"] = _fold_plan_for(

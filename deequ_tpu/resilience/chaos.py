@@ -78,7 +78,8 @@ system's OWN cross-cutting invariants as oracles:
    disjoint from quarantined batches;
 5. fetch contract — device fetches never exceed scan passes (the PR-4
    one-fetch discipline under the fault ladder);
-6. HBM ledger — ``total_resident_bytes()`` returns to zero;
+6. HBM ledger — ``total_resident_bytes()`` (the sum over all devices)
+   returns to zero;
 7. ledger consistency — quarantined batches all trace to injected
    faults; the run budget's total equals the sum of its per-rung
    charges; its ``io_retry`` charges equal the run's retry-telemetry

@@ -40,6 +40,7 @@ from deequ_tpu.ops.scan_engine import (
     install_scan_fault_hook,
     persist_table,
     run_scan,
+    resident_bytes_per_device,
     total_resident_bytes,
 )
 from deequ_tpu.parallel.mesh import (
@@ -742,6 +743,8 @@ def test_reshard_evicts_residency_pinned_to_old_mesh(mesh8):
     persist_table(table, mesh=mesh8)
     assert table._device_cache is not None
     assert total_resident_bytes() > 0
+    # the budget's reading is per device: an eighth of the total
+    assert resident_bytes_per_device() == total_resident_bytes() // 8
     lost_id = mesh_device_ids(mesh8)[0]
     SCAN_STATS.reset()
     with scan_faults(
@@ -752,7 +755,7 @@ def test_reshard_evicts_residency_pinned_to_old_mesh(mesh8):
         run_scan(table, scan_ops(table))
     assert SCAN_STATS.mesh_reshards == 1
     assert table._device_cache is None
-    assert total_resident_bytes() == 0
+    assert total_resident_bytes() == 0 and resident_bytes_per_device() == 0
     (event,) = [
         e for e in SCAN_STATS.degradation_events if e["kind"] == "mesh_reshard"
     ]
@@ -794,8 +797,8 @@ def test_evicted_cache_stops_charging_budget():
     freed = _evict_device_cache(table)
     assert freed > 0
     # `cache` is still referenced HERE, yet charges nothing
-    assert cache.nbytes == 0
-    assert total_resident_bytes() == 0
+    assert cache.nbytes == 0 and cache.per_device_bytes == 0
+    assert total_resident_bytes() == 0 and resident_bytes_per_device() == 0
 
 
 # -- multi-host peer loss ----------------------------------------------------
