@@ -434,12 +434,19 @@ class AnalysisRunner:
         op (currently: N same-parameter where-free KLL sorts -> one vmapped
         batched sort, the dominant cost of wide quantile profiles).
 
-        Cross-column batching of the scalar stat ops (mean/min/.../HLL into
-        (K, n) matrix reductions) was tried in round 4 and MEASURED SLOWER
-        on TPU (full 105-analyzer bench: 181ms per-column vs 256ms batched,
-        interleaved best-of-5): the (K, n) stacks materialize copies of
-        buffers XLA otherwise streams per-column, and the XLA scheduler
-        already overlaps the per-column kernels well. Keep ops per-column.
+        The scalar stat ops are NOT merged here. Round 4 tried it by
+        STACKING per-column slices into a new (K, n) array and measured it
+        slower (181 ms per-column against 256 ms batched, on a link and a
+        benchmark that are gone): the stack was a copy on top of the
+        copies the slices already were. What that comment could not see:
+        a column sliced out of the packed (C, n) plane is itself a
+        re-layout copy (one sublane in eight of every tile, written back
+        to HBM as 1-D arrays: ~24 of the 46 ms of a 10M x 20 suite on the
+        v5e, PERF.md PR 27). The batching that pays needs no stack: the
+        planner (ops/scan_plan.py) routes where-free one-column
+        statistics onto scan_engine.PlaneStats, which reduces the planes
+        where they lie, along their rows, and the ops stay one per
+        analyzer with their own leaves. Nothing to merge at this level.
 
         Returns (exec_ops, plan) where plan[i] = (exec_index, extractor or
         None) for scannable[i]."""

@@ -88,6 +88,20 @@ def _col_mask(val, xp):
     return val.mask
 
 
+def _plane(where, column, stats, from_stats):
+    """The plane declaration of a ScanOp (ops/scan_engine.py): without a
+    ``where``, the op's partial is ``from_stats`` of ONE column's
+    statistics, so the planner may take them from the batched reduction
+    over the packed planes where the layout has the column there."""
+    if where is not None:
+        return {}
+    return {
+        "plane_column": column,
+        "plane_stats": stats,
+        "plane_update": from_stats,
+    }
+
+
 def _empty_state_failure(analyzer: "StandardScanAnalyzer"):
     return EmptyStateException(
         f"Empty state for analyzer {analyzer!r}, all input values were NULL."
@@ -183,6 +197,10 @@ class Completeness(StandardScanAnalyzer):
         return ScanOp(
             tuple(sorted(cols)), update, {"matches": "sum", "count": "sum"},
             dictionary_baked=_string_baked(table, wcols),
+            **_plane(
+                self.where, col, (),
+                lambda st: {"matches": st["count"], "count": st["rows"]},
+            ),
         )
 
     def state_from_scan_result(self, result) -> Optional[NumMatchesAndCount]:
@@ -334,6 +352,10 @@ class _ExtremumAnalyzer(StandardScanAnalyzer):
         return ScanOp(
             tuple(sorted(cols)), update, {"value": tag, "n": "sum"},
             dictionary_baked=_string_baked(table, wcols),
+            **_plane(
+                self.where, col, (tag,),
+                lambda st: {"value": st[tag], "n": st["count"]},
+            ),
         )
 
     def state_from_scan_result(self, result):
@@ -459,6 +481,10 @@ class Mean(StandardScanAnalyzer):
         return ScanOp(
             tuple(sorted(cols)), update, {"sum": "sum", "count": "sum"},
             dictionary_baked=_string_baked(table, wcols),
+            **_plane(
+                self.where, col, ("sum",),
+                lambda st: {"sum": st["sum"], "count": st["count"]},
+            ),
         )
 
     def state_from_scan_result(self, result) -> Optional[MeanState]:
@@ -494,6 +520,10 @@ class Sum(StandardScanAnalyzer):
         return ScanOp(
             tuple(sorted(cols)), update, {"sum": "sum", "n": "sum"},
             dictionary_baked=_string_baked(table, wcols),
+            **_plane(
+                self.where, col, ("sum",),
+                lambda st: {"sum": st["sum"], "n": st["count"]},
+            ),
         )
 
     def state_from_scan_result(self, result) -> Optional[SumState]:
@@ -538,6 +568,10 @@ class StandardDeviation(StandardScanAnalyzer):
             tuple(sorted(cols)), update,
             {"n": "gather", "avg": "gather", "m2": "gather"},
             dictionary_baked=_string_baked(table, wcols),
+            **_plane(
+                self.where, col, ("m2",),
+                lambda st: {"n": st["count"], "avg": st["mean"], "m2": st["m2"]},
+            ),
         )
 
     def state_from_scan_result(self, result) -> Optional[StandardDeviationState]:

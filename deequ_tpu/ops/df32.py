@@ -154,38 +154,65 @@ def _f32 (xp, x):
     return xp.asarray(np.float32(x))
 
 
-def _pair_tree_sum(s, c, xp, levels: int = TREE_LEVELS):
-    """Reduce (s, c) f32 arrays to one f64 scalar: `levels` halving rounds
-    of TwoSum with exact error accumulation, then an f64 tail reduce over
-    the n/2^levels survivors.
+def _halves(x, fill, xp):
+    """``x`` cut in two along the last axis, an odd length padded with
+    one ``fill`` first."""
+    m = x.shape[-1]
+    if m % 2:
+        pad = xp.full(x.shape[:-1] + (1,), fill, dtype=x.dtype)
+        x = xp.concatenate([x, pad], axis=-1)
+        m += 1
+    return x[..., : m // 2], x[..., m // 2:]
 
-    Non-finite inputs poison TwoSum's error channel (inf - inf = NaN), so
-    a plain f32 sum of the raw planes rides along as the IEEE-correct
-    fallback: inf columns sum to inf (or NaN for mixed-sign infs / NaN
-    data), matching the f64 path and the reference's JVM doubles."""
-    naive = (xp.sum(s) + xp.sum(c)).astype(xp.float64)
-    for _ in range(levels):
-        m = s.shape[0]
-        if m <= 1:
-            break
-        if m % 2:
-            pad = xp.zeros((1,), dtype=s.dtype)
-            s = xp.concatenate([s, pad])
-            c = xp.concatenate([c, pad])
-            m += 1
-        half = m // 2
-        s, err = two_sum(s[:half], s[half:])
-        c = c[:half] + c[half:] + err
-    tree = xp.sum(s.astype(xp.float64)) + xp.sum(c.astype(xp.float64))
+
+def _pair_tree_sum(hi, lo, ok, xp, levels: int = TREE_LEVELS):
+    """Sum the pair values where ok along the LAST axis, in f64: `levels`
+    halving rounds of TwoSum with exact error accumulation over
+    (s, c) = (where(ok, hi, 0), where(ok, lo, 0)), then an f64 tail
+    reduce over the n/2^levels survivors. A 1-D column gives a scalar; a
+    (C, n) plane gives C sums, each by the very same steps.
+
+    The mask is applied to the HALVES of the first round, not to the
+    whole planes: the same values, but nothing of whole length stands
+    between the operands and the first TwoSum, so the compiler reads the
+    planes where they lie and writes n/2 survivors (it wrote both masked
+    planes out in full, and read them back, when they existed).
+
+    Non-finite inputs poison TwoSum's error channel (inf - inf = NaN).
+    The `s` channel is immune: at every round it is the plain f32 a + b,
+    so the sum of its survivors is the IEEE-correct fallback: inf
+    columns sum to inf (or NaN for mixed-sign infs / NaN data), matching
+    the f64 path and the reference's JVM doubles. The lo plane is finite
+    by construction (split_pair_np zeroes non-finite residuals,
+    int32_pair is exact), so it cannot move an inf or a NaN."""
+    z = _f32(xp, 0.0)
+    if hi.shape[-1] <= 1 or levels < 1:
+        s, c = xp.where(ok, hi, z), xp.where(ok, lo, z)
+    else:
+        (ok_a, ok_b), (hi_a, hi_b), (lo_a, lo_b) = (
+            _halves(ok, False, xp), _halves(hi, 0.0, xp), _halves(lo, 0.0, xp)
+        )
+        s, err = two_sum(xp.where(ok_a, hi_a, z), xp.where(ok_b, hi_b, z))
+        c = xp.where(ok_a, lo_a, z) + xp.where(ok_b, lo_b, z) + err
+        for _ in range(levels - 1):
+            if s.shape[-1] <= 1:
+                break
+            (s_a, s_b), (c_a, c_b) = _halves(s, 0.0, xp), _halves(c, 0.0, xp)
+            s, err = two_sum(s_a, s_b)
+            c = c_a + c_b + err
+    tree = xp.sum(s.astype(xp.float64), axis=-1) + xp.sum(
+        c.astype(xp.float64), axis=-1
+    )
     # tree is NaN only when non-finite values were present (finite inputs
-    # cannot overflow under PAIR_SAFE_MAX); the naive sum then carries the
-    # correct IEEE result
-    return xp.where(xp.isnan(tree), naive, tree)
+    # cannot overflow under PAIR_SAFE_MAX)
+    return xp.where(
+        xp.isnan(tree), xp.sum(s, axis=-1).astype(xp.float64), tree
+    )
 
 
 def _sum_product_pair(p, e, xp):
-    """Reduce a product pair (p, e) to an f64 scalar: p in f64, e in f32
-    with one final convert.
+    """Reduce a product pair (p, e) along the last axis to f64: p in f64,
+    e in f32 with one final convert.
 
     Product pairs do NOT use the compensated f32 tree: the p channel's
     producer is a multiply, and XLA's fusion duplicates that multiply into
@@ -197,7 +224,9 @@ def _sum_product_pair(p, e, xp):
     contributes error ~6e-8 * sum|e| ~ 4e-15 * sum|p|, far below the
     1e-12 target. Cost: one full-length f64 reduce per moment column —
     only the moment/co-moment ops pay it, plain sums keep the f32 tree."""
-    return xp.sum(p.astype(xp.float64)) + xp.sum(e).astype(xp.float64)
+    return xp.sum(p.astype(xp.float64), axis=-1) + xp.sum(e, axis=-1).astype(
+        xp.float64
+    )
 
 
 def merge_tags_f64(is_sum, is_min, acc, new, xp):
@@ -221,45 +250,62 @@ def merge_tags_f64(is_sum, is_min, acc, new, xp):
     )
 
 
+# Every masked_* reduction below runs along the LAST axis: a 1-D column
+# (a where-filtered op, the expression evaluator, the selection kernels)
+# gives scalars, and the packed (C, n) planes give C of each at once (the
+# fused step's plane statistics, scan_engine.PlaneStats) by the same
+# arithmetic, in the same order along the rows.
+
+
 def masked_sum(hi, lo, ok, xp):
-    """Sum of the pair values where ok — f64 scalar, ~1e-13 accurate."""
+    """Sum of the pair values where ok, in f64, ~1e-13 accurate."""
     if lo is None:
-        return xp.sum(xp.where(ok, hi, 0.0))
-    z = _f32(xp, 0.0)
-    s = xp.where(ok, hi, z)
-    c = xp.where(ok, lo, z)
-    return _pair_tree_sum(s, c, xp)
+        return xp.sum(xp.where(ok, hi, 0.0), axis=-1)
+    return _pair_tree_sum(hi, lo, ok, xp)
 
 
 def masked_count(ok, xp):
     """Row count as i32 (chunks are < 2^31 rows by construction)."""
-    return xp.sum(ok, dtype=xp.int32)
+    return xp.sum(ok, axis=-1, dtype=xp.int32)
 
 
-def masked_extremum(hi, lo, ok, xp, mode: str):
-    """Exact min/max of pair values where ok, as an f64 scalar.
-
-    Two-stage: extremum over hi, then over lo among the hi-ties. Exact
-    because hi is the rounded-to-nearest f32 of x: hi_a < hi_b implies
-    x_a <= x_b, so the true extremum lives in the hi-tie group.
-    """
+def extremum_hi(hi, ok, xp, mode: str):
+    """First stage of the exact extremum: min/max of the hi plane where
+    ok (the identity where nothing is ok). All there is to it for a
+    wide-f64 column, which has no lo plane."""
     red = xp.min if mode == "min" else xp.max
-    if lo is None:
-        ident = np.inf if mode == "min" else -np.inf
-        return red(xp.where(ok, hi, ident))
     ident = _f32(xp, np.inf if mode == "min" else -np.inf)
-    gh = xp.where(ok, hi, ident)
-    eh = red(gh)
-    gl = xp.where(ok & (gh == eh), lo, ident)
-    el = red(gl)
+    return red(xp.where(ok, hi, ident), axis=-1)
+
+
+def extremum_tie(hi, lo, ok, eh, xp, mode: str):
+    """Second stage: the lo extremum among the rows whose hi equals the
+    first stage's ``eh``, joined to it in f64. Exact because hi is the
+    rounded-to-nearest f32 of x: hi_a < hi_b implies x_a <= x_b, so the
+    true extremum lives in the hi-tie group."""
+    red = xp.min if mode == "min" else xp.max
+    ident = _f32(xp, np.inf if mode == "min" else -np.inf)
+    gl = xp.where(ok & (hi == eh[..., None]), lo, ident)
+    el = red(gl, axis=-1)
     # all-masked chunks: eh = +/-inf and el = +/-inf; callers guard on the
     # separate count, and inf + inf keeps the sign
     return eh.astype(xp.float64) + el.astype(xp.float64)
 
 
+def masked_extremum(hi, lo, ok, xp, mode: str):
+    """Exact min/max of pair values where ok, in f64: the extremum over
+    hi, then over lo among the hi-ties."""
+    if lo is None:
+        return extremum_hi(hi, ok, xp, mode)
+    return extremum_tie(hi, lo, ok, extremum_hi(hi, ok, xp, mode), xp, mode)
+
+
 def _center(hi, lo, mean64, ok, xp):
     """(x - mean) as a renormalized f32 pair, masked rows zeroed.
-    mean64 is an f64 SCALAR (scalar f64 ops are free on TPU)."""
+    mean64 is f64 with one entry per reduced row of ``hi``: a SCALAR for
+    a column, a (C,) vector broadcast over the rows of a (C, n) plane
+    (f64 ops on that few values are free on TPU)."""
+    mean64 = mean64[..., None]
     mh = mean64.astype(xp.float32)
     ml = (mean64 - mh.astype(xp.float64)).astype(xp.float32)
     if lo is None:
@@ -294,19 +340,23 @@ def _mul_pair(ah, al, bh, bl, xp):
     return p, e
 
 
+def centered_m2(hi, lo, mean, ok, xp):
+    """Sum of squared deviations from ``mean`` where ok, in f64: the
+    second sweep of the chunk moments (it needs the first one's mean)."""
+    dh, dl = _center(hi, lo, mean, ok, xp)
+    if dl is None:
+        return xp.sum(dh * dh, axis=-1)
+    p, e = _sqr_pair(dh, dl, xp)
+    return _sum_product_pair(p, e, xp)
+
+
 def masked_moments(hi, lo, ok, xp):
     """(count_i32, sum_f64, mean_f64, m2_f64) — the Welford chunk moments
     (reference StandardDeviation.scala:37-44 merges these across chunks)."""
     cnt = masked_count(ok, xp)
     s = masked_sum(hi, lo, ok, xp)
     mean = s / xp.maximum(cnt, 1)
-    dh, dl = _center(hi, lo, mean, ok, xp)
-    if dl is None:
-        m2 = xp.sum(dh * dh)
-    else:
-        p, e = _sqr_pair(dh, dl, xp)
-        m2 = _sum_product_pair(p, e, xp)
-    return cnt, s, mean, m2
+    return cnt, s, mean, centered_m2(hi, lo, mean, ok, xp)
 
 
 def masked_comoments(a_hi, a_lo, b_hi, b_lo, ok, xp):
@@ -323,9 +373,9 @@ def masked_comoments(a_hi, a_lo, b_hi, b_lo, ok, xp):
     if dal is None or dbl is None:
         da64 = dah if dal is None else dah.astype(xp.float64) + dal.astype(xp.float64)
         db64 = dbh if dbl is None else dbh.astype(xp.float64) + dbl.astype(xp.float64)
-        ck = xp.sum(da64 * db64)
-        x_mk = xp.sum(da64 * da64)
-        y_mk = xp.sum(db64 * db64)
+        ck = xp.sum(da64 * db64, axis=-1)
+        x_mk = xp.sum(da64 * da64, axis=-1)
+        y_mk = xp.sum(db64 * db64, axis=-1)
     else:
         pc, ec = _mul_pair(dah, dal, dbh, dbl, xp)
         ck = _sum_product_pair(pc, ec, xp)

@@ -248,6 +248,21 @@ class ScanOp:
     # True when `update` runs a full device sort per chunk (the KLL
     # summary kernels) — the census behind ScanStats.device_sort_passes
     sorts_chunk: bool = False
+    # plane seam (ops/scan_plan.py): set by an analyzer whose partial is
+    # made of the statistics of ONE column with no `where`. plane_stats
+    # names what it needs beyond the two counts ("sum", "min", "max",
+    # "m2"), plane_update maps that column's statistics (PlaneStats.of,
+    # which adds "mean" to "m2") to the SAME partial
+    # `update` returns. The planner swaps it in per scan ATTEMPT when the
+    # column rides the (hi, lo) pair planes of the attempt's packer: the
+    # statistics of all such columns come out of one batched reduction
+    # along the rows of the planes, where `update` slices its column out
+    # and reduces it alone.
+    plane_column: Optional[str] = None
+    plane_stats: Tuple[str, ...] = ()
+    plane_update: Optional[Callable[[Dict[str, Any]], Any]] = None
+    # the planner's mark on an op it routed so: the plan's PlaneRoute
+    plane_route: Optional["PlaneRoute"] = None
 
 
 class ScanStats:
@@ -355,6 +370,10 @@ class ScanStats:
         # across the mesh (one psum or all_gather each, _tag_collective);
         # counted on the host at the dispatch, 0 without a mesh
         self.mesh_collectives = 0
+        # ops of dispatched plans that read their scalars out of the
+        # batched plane statistics (ScanPlan.plane_ops per dispatch of a
+        # step; the host-side census, as mesh_collectives)
+        self.plane_ops = 0
         # static plan lint (deequ_tpu/lint/plan_lint.py, armed via
         # run_scan(plan_lint=...) / DEEQU_TPU_PLAN_LINT): finding rows
         # the jaxpr pass produced for this process's scans, and how many
@@ -712,6 +731,106 @@ def _warn_pair_compare_once(name: str, col=None) -> None:
     )
 
 
+@dataclass(frozen=True)
+class PlaneRoute:
+    """The columns one plan routed onto the batched plane statistics, in
+    the order of their rows on the pair planes, each with the statistics
+    its ops need (``ScanOp.plane_stats``). One per plan (ops/scan_plan.py), shared by its routed
+    updates: the key under which a trace computes the statistics once."""
+
+    columns: Tuple[Tuple[str, Tuple[str, ...]], ...]
+
+
+class ColumnVals(dict):
+    """What ``unpack_vals`` hands out: the per-column Vals by name, and
+    beside them ``plane``, the statistics of the packed pair planes."""
+
+    plane: "PlaneStats" = None
+
+
+class PlaneStats:
+    """Statistics of pair-plane columns computed where the planes lie: one
+    batched reduction along the rows of ``hi[a:b]`` / ``lo[a:b]`` for all
+    the columns of a run, never a column sliced out on its own. Lives for
+    one trace of a step; nothing is computed until a routed op asks.
+
+    Two sweeps, as the mathematics orders them. Sweep 1: counts,
+    compensated sums, hi extrema. Sweep 2, which needs sweep 1's mean and
+    extrema: centred squares, the lo extremum among the hi ties. The
+    arithmetic is ops/df32.py's, the same a per-column ``update`` runs."""
+
+    def __init__(self, packer, hi, lo, masks, row_valid, xp):
+        self._packer = packer
+        self._hi, self._lo, self._masks = hi, lo, masks
+        self._row_valid = row_valid
+        self._xp = xp
+        self._memo: Dict[PlaneRoute, Dict[str, Dict[str, Any]]] = {}
+
+    def of(self, route: PlaneRoute) -> Dict[str, Dict[str, Any]]:
+        """``{column: {"count", "rows", "sum", "mean", "m2", "min",
+        "max"}}`` (what the route asked for) as scalars of this trace."""
+        stats = self._memo.get(route)
+        if stats is None:
+            stats = self._memo[route] = self._compute(route)
+        return stats
+
+    def _runs(self, route: PlaneRoute):
+        """Cut the routed columns into runs that are ONE static slice of
+        each plane: consecutive hi/lo rows whose mask rows are consecutive
+        too, or absent throughout (null-free columns ship none). A layout
+        that alternates degrades to runs of one row, never to a gather."""
+        hi_row, mask_row = self._packer._hi_row, self._packer._mask_row
+        runs: List[Tuple[int, Optional[int], List[str], set]] = []
+        for name, needs in route.columns:
+            row, mrow = hi_row[name], mask_row.get(name)
+            if runs:
+                start, mstart, names, wanted = runs[-1]
+                k = len(names)
+                if row == start + k and mrow == (
+                    None if mstart is None else mstart + k
+                ):
+                    names.append(name)
+                    wanted.update(needs)
+                    continue
+            runs.append((row, mrow, [name], set(needs)))
+        return runs
+
+    def _compute(self, route: PlaneRoute) -> Dict[str, Dict[str, Any]]:
+        from deequ_tpu.ops import df32
+
+        xp = self._xp
+        row_valid = self._row_valid
+        with jax.named_scope("deequ.plane.sweep1"):
+            rows = df32.masked_count(row_valid, xp)
+        stats: Dict[str, Dict[str, Any]] = {}
+        for start, mstart, names, wanted in self._runs(route):
+            stop = start + len(names)
+            hi, lo = self._hi[start:stop], self._lo[start:stop]
+            if mstart is None:
+                ok = xp.broadcast_to(row_valid, hi.shape)
+            else:
+                ok = self._masks[mstart:mstart + len(names)] & row_valid
+            run: Dict[str, Any] = {}
+            with jax.named_scope("deequ.plane.sweep1"):
+                run["count"] = df32.masked_count(ok, xp)
+                if wanted & {"sum", "m2"}:
+                    run["sum"] = df32.masked_sum(hi, lo, ok, xp)
+                tops = {
+                    mode: df32.extremum_hi(hi, ok, xp, mode)
+                    for mode in ("min", "max") if mode in wanted
+                }
+            with jax.named_scope("deequ.plane.sweep2"):
+                if "m2" in wanted:
+                    run["mean"] = run["sum"] / xp.maximum(run["count"], 1)
+                    run["m2"] = df32.centered_m2(hi, lo, run["mean"], ok, xp)
+                for mode, top in tops.items():
+                    run[mode] = df32.extremum_tie(hi, lo, ok, top, xp, mode)
+            for i, name in enumerate(names):
+                stats[name] = {k: v[i] for k, v in run.items()}
+                stats[name]["rows"] = rows
+        return stats
+
+
 class _ChunkPacker:
     """Packs one chunk of a table into a handful of contiguous host buffers
     (two-float f32 pair planes, wide f64 values, narrow i32 values,
@@ -871,8 +990,10 @@ class _ChunkPacker:
     def unpack_vals(
         self, values, hi, lo, narrow_i, masks, codes, xp, row_valid=None,
         col_luts=None, enc=None,
-    ) -> Dict[str, Val]:
-        """Slice the packed buffers back into per-column Vals (inside jit).
+    ) -> ColumnVals:
+        """Slice the packed buffers back into per-column Vals (inside jit),
+        with the planes' own statistics beside them (``.plane``: what the
+        plan routed there is reduced in place, PlaneStats).
 
         Numeric Vals carry the two-float pair: ``data`` = f32 hi plane,
         ``lo`` = f32 lo plane (None for wide-f64 columns). Reductions go
@@ -887,7 +1008,8 @@ class _ChunkPacker:
         narrow plane uses (integral). Validity is ``code >= 0``."""
         from deequ_tpu.ops.df32 import int32_pair
 
-        vals: Dict[str, Val] = {}
+        vals = ColumnVals()
+        vals.plane = PlaneStats(self, hi, lo, masks, row_valid, xp)
         for name in self.enc_names:
             code = enc[self._enc_row[name]].astype(xp.int32)
             mask = code >= 0
@@ -1255,6 +1377,10 @@ def op_scope(op: "ScanOp") -> str:
 
 
 def _scoped_update(op: "ScanOp", vals, row_valid, local_n):
+    if op.plane_route is not None:
+        # the batched sweeps lower under their own names, not under the
+        # scope of whichever routed op is traced first
+        vals.plane.of(op.plane_route)
     with jax.named_scope(op_scope(op)):
         return op.update(vals, row_valid, jnp, local_n)
 
@@ -1901,8 +2027,10 @@ def _record_kernel_passes(plan_ir, chunks: int) -> None:
     the histogram selection kernel — the observable behind the config-3
     zero-sort contract — and, for selection dispatches, the histogram
     kernel-variant census (each selection summary runs three bincount
-    passes under the plan's resolved hist_variant)."""
+    passes under the plan's resolved hist_variant); and how many ops
+    read their scalars out of the batched plane statistics."""
     if chunks:
+        SCAN_STATS.plane_ops += plan_ir.plane_ops * chunks
         SCAN_STATS.device_sort_passes += plan_ir.sort_ops * chunks
         SCAN_STATS.device_select_passes += plan_ir.select_ops * chunks
         if plan_ir.select_ops and plan_ir.hist_variant != "none":
@@ -2827,6 +2955,13 @@ def run_scan_group(
         if layout is None:
             layout = _ChunkPacker(first_cols, chunk).layout()
         packer = _ChunkPacker(first_cols, chunk, layout=layout)
+        # grouped micro-batches are packed fresh per call (never
+        # resident): the sort path's kernels, the plane route of the
+        # shared layout
+        from deequ_tpu.ops.scan_plan import plan_scan_ops
+
+        plan_ir = plan_scan_ops(ops, packer, resident=False)
+        ops = plan_ir.ops
 
     # stack per-table packed buffers along a leading K axis
     stacked = None
@@ -2935,13 +3070,8 @@ def run_scan_group(
     # one enqueue here (a program's first call also traces and compiles)
     with seam("build" if cached is None else "dispatch", tables=K):
         device_out = vstep(*bufs, lut_stacked)
-    # grouped micro-batches are packed fresh per call (never resident):
-    # the kernel census is the sort path's, once per table in the stack
-    from deequ_tpu.ops.scan_plan import plan_scan_ops
-
-    _record_kernel_passes(
-        plan_scan_ops(ops, None, resident=False), K
-    )
+    # the kernel census, once per table in the stack
+    _record_kernel_passes(plan_ir, K)
 
     folders = []
     for _ in range(K):
@@ -3149,12 +3279,9 @@ def _run_scan_stream(
     from deequ_tpu.ops.scan_plan import plan_scan_ops
 
     # streaming chunks are never resident: the planner keeps the sort
-    # path (selection only fires on resident attempts) but still supplies
-    # the per-chunk kernel census for ScanStats
-    plan_ir = plan_scan_ops(
-        ops, None, resident=False, select_kernel=select_kernel
-    )
-    ops = plan_ir.ops
+    # path (selection only fires on resident attempts). What it reads off
+    # a packer layout is resolved per batch (plan_cols below), against
+    # the layout that batch is packed under
     needed = sorted({c for op in ops for c in op.columns})
     schema = stream.schema
     if not needed and len(schema.column_names):
@@ -3213,7 +3340,7 @@ def _run_scan_stream(
     unbuilt: set = set()
 
     def dispatch_staged(entry) -> None:
-        fn, device_args, luts, idx = entry
+        fn, device_args, luts, idx, plan_ir = entry
         building = id(fn) in unbuilt
         unbuilt.discard(id(fn))
         flat = device_call(
@@ -3312,13 +3439,19 @@ def _run_scan_stream(
         if packer.enc_names and not encoded_counted[0]:
             encoded_counted[0] = True
             SCAN_STATS.encoded_scan_passes += 1
+        # what depends on the layout (the plane route, the encoded
+        # declaration) is resolved against THIS batch's packer
+        batch_ir = plan_scan_ops(
+            ops, packer, resident=False, select_kernel=select_kernel
+        )
+        batch_ops = batch_ir.ops
 
         lut_arrays = _collect_luts(
-            ops, {c: packer.col_dict.get(c) for c in packer.string_names}, mesh
+            batch_ops, {c: packer.col_dict.get(c) for c in packer.string_names}, mesh
         )
         lut_arrays.update(_collect_enc_luts(packer, mesh))
         lut_sig = _lut_sig(lut_arrays)
-        prog_key = _ops_prog_key(ops, chunk, lut_sig)
+        prog_key = _ops_prog_key(batch_ops, chunk, lut_sig)
         sig = (tuple(sorted(layout.items())), lut_sig)
 
         prog = None
@@ -3338,19 +3471,19 @@ def _run_scan_stream(
         else:
             SCAN_STATS.programs_built += 1
             step_fn, shape_fn, raw_flat = _build_step_fns(
-                ops, packer.unpack_view(), mesh, local_n,
+                batch_ops, packer.unpack_view(), mesh, local_n,
                 tuple(sorted(lut_arrays)),
             )
             shapes = None
             unbuilt.add(id(step_fn))
-        return packer, lut_arrays, prog_key, sig, global_key, (
+        return packer, lut_arrays, prog_key, sig, global_key, batch_ir, (
             step_fn, shape_fn, raw_flat, shapes
         )
 
     def process_cols(cols: Dict[str, Column], n: int) -> None:
         nonlocal current_prog
         with seam("plan"):
-            packer, lut_arrays, prog_key, sig, global_key, prog = (
+            packer, lut_arrays, prog_key, sig, global_key, batch_ir, prog = (
                 plan_cols(cols)
             )
         step_fn, shape_fn, raw_flat, shapes = prog
@@ -3367,11 +3500,7 @@ def _run_scan_stream(
                 # lint checks THIS signature's packer-derived plan, so
                 # encoded-ingest contracts hold per program
                 _maybe_plan_lint(
-                    plan_scan_ops(
-                        ops, packer, resident=False,
-                        select_kernel=select_kernel,
-                    ),
-                    raw_flat, args, lut_arrays,
+                    batch_ir, raw_flat, args, lut_arrays,
                     prog_key, packer, mesh, plan_lint,
                 )
                 linted_sigs.add(sig)
@@ -3403,7 +3532,7 @@ def _run_scan_stream(
             )
             SCAN_STATS.record_staged(chunk_bytes, overlapped)
             pending_stage.append(
-                (step_fn, device_args, lut_arrays, chunk_counter[0])
+                (step_fn, device_args, lut_arrays, chunk_counter[0], batch_ir)
             )
             chunk_counter[0] += 1
             if len(pending_stage) > 1:

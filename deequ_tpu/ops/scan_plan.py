@@ -29,9 +29,19 @@ Because an OOM retry evicts residency before re-planning, a fault during
 a selection pass lands the next attempt on the sort path automatically —
 the ladder needs no knowledge of kernel variants at all.
 
+A second decision rides the same seam: an op whose partial is made of
+the statistics of ONE where-free column (``ScanOp.plane_update``:
+Completeness, Mean, Sum, StandardDeviation, Minimum, Maximum) is routed
+onto the batched plane statistics when that column lies on the (hi, lo)
+pair planes of THIS attempt's packer layout. All such columns are then
+reduced where they lie, in one batched reduction along the rows of the
+planes (``scan_engine.PlaneStats``), and the routed ops read their
+scalars out of its result. Read from the layout alone: no switch.
+
 The resolved plan also carries the per-chunk kernel census
-(``sort_ops``/``select_ops``) that the executor turns into
-``ScanStats.device_sort_passes`` / ``device_select_passes``.
+(``sort_ops``/``select_ops``/``plane_ops``) that the executor turns into
+``ScanStats.device_sort_passes`` / ``device_select_passes`` /
+``plane_ops``.
 """
 
 from __future__ import annotations
@@ -142,6 +152,8 @@ class ScanPlan:
     resident: bool
     select_ops: int = 0
     sort_ops: int = 0
+    #: ops that read their scalars out of the batched plane statistics
+    plane_ops: int = 0
     variant: str = "none"
     #: histogram kernel tier of the plan's bincount passes ("none" when
     #: the plan runs no histogram passes at all) — see class doc
@@ -362,6 +374,51 @@ def _selectable(op, packer) -> bool:
     return all(c in keyed for c in op.select_columns)
 
 
+def _plane_route(ops: Sequence, packer):
+    """The PlaneRoute of one attempt (None when nothing routes): every op
+    that declares a plane variant and whose column lies on the pair
+    planes of this packer layout, the columns in the order of their
+    plane rows, each with the union of what its ops ask for."""
+    from deequ_tpu.ops.scan_engine import PlaneRoute
+
+    if packer is None:
+        return None
+    row = {name: i for i, name in enumerate(packer.pair_names)}
+    wanted = {}
+    for op in ops:
+        if op.plane_update is not None and op.plane_column in row:
+            wanted.setdefault(op.plane_column, set()).update(op.plane_stats)
+    if not wanted:
+        return None
+    return PlaneRoute(
+        tuple(
+            (name, tuple(sorted(wanted[name])))
+            for name in sorted(wanted, key=row.__getitem__)
+        )
+    )
+
+
+def _plane_routed(op, route):
+    """``op`` with its update replaced by the read of its column's
+    statistics out of the trace's PlaneStats. Place, tags, leaves and
+    extractor stay; the cache key changes so a cached per-column program
+    is never taken for this one."""
+    column, from_stats = op.plane_column, op.plane_update
+
+    def plane_update(vals, row_valid, xp, n):
+        return from_stats(vals.plane.of(route)[column])
+
+    # a field-for-field copy: dataclasses.replace re-runs __init__ over
+    # every field, and a profiler suite routes a hundred ops each run
+    routed = object.__new__(type(op))
+    routed.__dict__.update(op.__dict__)
+    routed.update = plane_update
+    routed.plane_route = route
+    if op.cache_key is not None:
+        routed.cache_key = ("plane", op.cache_key)
+    return routed
+
+
 def _bind_hist_variant(update, variant: str):
     """Wrap a resolved update so the ambient histogram variant is bound
     exactly while THIS op's portion of the program traces — the traced
@@ -417,11 +474,17 @@ def plan_scan_ops(
             ),
             rows=rows,
         )
+    route = _plane_route(ops, packer)
+    on_plane = (
+        {name for name, _ in route.columns} if route is not None else ()
+    )
     resolved = []
     n_select = 0
     n_sort = 0
     for op, sel in zip(ops, routed):
-        if sel:
+        if op.plane_update is not None and op.plane_column in on_plane:
+            resolved.append(_plane_routed(op, route))
+        elif sel:
             key = (
                 ("select", hist_variant, op.cache_key)
                 if op.cache_key is not None
@@ -464,6 +527,7 @@ def plan_scan_ops(
         resident=resident,
         select_ops=n_select,
         sort_ops=n_sort,
+        plane_ops=sum(op.plane_route is not None for op in resolved),
         variant=variant,
         hist_variant=hist_variant,
         fold_tags=tuple(

@@ -334,6 +334,13 @@ def build_serve_plan(table, analyzers: List, key_hint=None) -> ServePlan:
             )
             layout = packer.layout()
             view = packer.unpack_view()
+            # members pack against this layout, so what the planner
+            # reads off it (the plane route) is resolved once, here: the
+            # coalesced program then runs the arithmetic a serial run of
+            # one member runs (the bit-identity contract)
+            from deequ_tpu.ops.scan_plan import plan_scan_ops
+
+            exec_ops = plan_scan_ops(exec_ops, packer, resident=False).ops
     elif scanning:
         # every scan op failed to build: nothing to coalesce
         coalescable = False

@@ -189,6 +189,113 @@ def test_row_sharded_step_compiles_with_all_reduce(as_tpu, topo):
     assert per_device < whole / 4 * 1.3
 
 
+def _profile_step(chunk, mesh, plane, rows):
+    """The fused step of the `profile10m` / `profile80m` cells: 20 pair
+    columns with nulls, the source's five analyzers on each, planned the
+    way ``_run_scan_once`` plans it. Returns (jitted step, abstract args,
+    resolved plan)."""
+    from deequ_tpu.analyzers import (
+        Completeness, Maximum, Mean, Minimum, StandardDeviation,
+    )
+    from deequ_tpu.analyzers.runner import AnalysisRunner
+    from deequ_tpu.data.table import Column, ColumnarTable, DType
+    from deequ_tpu.ops.scan_engine import _build_step_fns, _ChunkPacker
+    from deequ_tpu.ops.scan_plan import plan_scan_ops
+
+    rng = np.random.default_rng(3)
+    table = ColumnarTable.from_columns([
+        Column(f"c{i}", DType.FRACTIONAL, values=rng.normal(100.0 + i, 5.0, 512),
+               mask=rng.random(512) >= 0.1)
+        for i in range(20)
+    ])
+    analyzers = [
+        a(c) for c in table.column_names
+        for a in (Completeness, Mean, StandardDeviation, Minimum, Maximum)
+    ]
+    ops, scannable, failures = AnalysisRunner._build_scan_ops(table, analyzers)
+    assert not failures and len(scannable) == 100
+    exec_ops, _ = AnalysisRunner._coalesce_scan_ops(ops)
+    packer = _ChunkPacker({c: table[c] for c in table.column_names}, chunk)
+    assert len(packer.pair_names) == len(packer.masked_names) == 20
+    plan = plan_scan_ops(exec_ops, packer, resident=True, rows=chunk)
+    n_dev = int(np.prod(mesh.devices.shape)) if mesh is not None else 1
+    step_fn, _, _ = _build_step_fns(
+        plan.ops, packer.unpack_view(), mesh, chunk // n_dev, ()
+    )
+    return step_fn, _chunk_avals(packer, chunk, plane, rows) + ({},), plan
+
+
+def _row_sized_fusions(compiled, local_n):
+    """(fusions of the entry computation, those that read or write an
+    array with a row axis, the planes' parameter names)."""
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    lines = [line for line in entry.splitlines() if " fusion(" in line]
+    # the halving tree's levels stop at local_n / 32 columns
+    widths = {str(local_n >> k) for k in range(6)}
+    shaped = re.compile(r"\[(?:\d+,)?(\d+)\]")
+    row_sized = [
+        line for line in lines
+        if widths & set(shaped.findall(line.split(", kind=")[0]))
+    ]
+    planes = set(re.findall(
+        rf"%(\S+) = \w+\[20,{local_n}\]\S* parameter\(", entry
+    ))
+    return lines, row_sized, planes
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_profile_step_reduces_the_planes_where_they_lie(as_tpu, topo, chips):
+    """The guard of the plane route (PR 27): all 100 ops of the profiler
+    suite read their scalars out of the batched plane statistics, and the
+    compiled step pulls no column out of a plane: no fusion writes a 1-D
+    f32[n] / pred[n] copy of a plane parameter (the per-column program
+    held forty such re-layout copies, ~24 of its 46 ms a suite)."""
+    from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+    from jax.sharding import PartitionSpec as P
+
+    from deequ_tpu.parallel.mesh import ROW_AXIS
+
+    chunk = 1 << 20
+    if chips == 1:
+        mesh = None
+        plane = rows = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices), (ROW_AXIS,))
+        plane = NamedSharding(mesh, P(None, ROW_AXIS))
+        rows = NamedSharding(mesh, P(ROW_AXIS))
+    step_fn, avals, plan = _profile_step(chunk, mesh, plane, rows)
+    assert plan.plane_ops == 100
+    assert all(op.plane_route is plan.ops[0].plane_route for op in plan.ops)
+    compiled = step_fn.lower(*avals).compile()
+    local_n = chunk // chips
+    fusions, row_sized, planes = _row_sized_fusions(compiled, local_n)
+    assert len(planes) == 3  # hi, lo, masks
+    # one row of a plane written out on its own, as f32[n] or f32[1,n]:
+    # the per-column program wrote one per column and plane (reading
+    # the plane itself, or a prefetched copy of it)
+    column = re.compile(rf"(?:f32|pred)\[(?:1,)?{local_n}\]")
+    for line in row_sized:
+        assert not column.search(line.split(" fusion(")[0]), line
+    assert 5 <= len(row_sized) <= 40
+    if chips == 1:
+        # (on a mesh each of the 220 state leaves adds a fusion of four
+        # elements around its collective, as the per-column step did)
+        assert len(fusions) <= 40
+        assert "all-reduce" not in compiled.as_text()
+    else:
+        assert "all-reduce" in compiled.as_text()
+
+
+def test_profile_step_fits_beside_the_table_at_10m_rows(as_tpu, one_chip):
+    step_fn, avals, _ = _profile_step(10_000_000, None, one_chip, one_chip)
+    mem = step_fn.lower(*avals).compile().memory_analysis()
+    # the batched sweeps keep more alive than the per-column program did
+    # (2.9 GB of temporaries against 0.8): still one program beside the
+    # resident table in 16 GB of HBM
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 14 << 30
+
+
 def test_hll_mxu_fold_compiles(as_tpu, one_chip):
     import jax.numpy as jnp
 

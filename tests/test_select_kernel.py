@@ -451,16 +451,20 @@ def test_plan_scan_ops_census():
     ops = [
         ApproxQuantile("c0", 0.5).scan_op(table),
         Mean("c0").scan_op(table),
+        Mean("c0", where="c1 > 0").scan_op(table),
     ]
     plan = plan_scan_ops(ops, packer, resident=True, select_kernel=True)
-    assert (plan.select_ops, plan.sort_ops) == (1, 0)
+    assert (plan.select_ops, plan.sort_ops, plan.plane_ops) == (1, 0, 1)
     assert plan.ops[0].update is not ops[0].update
-    assert plan.ops[1].update is ops[1].update
+    # a where-free statistic of a pair-plane column reads the batched
+    # plane statistics (resident or not); a filtered one keeps its update
+    assert plan.ops[1].plane_route is not None
+    assert plan.ops[2].update is ops[2].update
     off = plan_scan_ops(ops, packer, resident=True, select_kernel=False)
     assert (off.select_ops, off.sort_ops) == (0, 1)
     assert off.ops[0].update is ops[0].update
     nonres = plan_scan_ops(ops, packer, resident=False, select_kernel=True)
-    assert (nonres.select_ops, nonres.sort_ops) == (0, 1)
+    assert (nonres.select_ops, nonres.sort_ops, nonres.plane_ops) == (0, 1, 1)
 
 
 # -- fault-ladder composition -------------------------------------------
