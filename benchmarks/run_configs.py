@@ -323,9 +323,7 @@ def enforce_config3_contract(
     one selection pass — otherwise the harness REFUSES to report config
     3 (AssertionError), like PR 4's one-fetch assert. Returns True when
     the contract bound (and held), False when it legitimately does not
-    apply (non-resident, kernel disabled, or DEEQU_TPU_COMPUTE=f64 —
-    wide-f64 columns have no u32 key plane, so the planner's sort
-    routing is correct there).
+    apply (non-resident or kernel disabled).
 
     ``select_enabled``: the RESOLVED kernel switch of the run the
     snapshot came from; pass it whenever the run pinned the kernel
@@ -336,8 +334,7 @@ def enforce_config3_contract(
 
     if select_enabled is None:
         select_enabled = select_kernel_enabled()
-    wide_forced = os.environ.get("DEEQU_TPU_COMPUTE", "").lower() == "f64"
-    if not (resident and select_enabled and not wide_forced):
+    if not (resident and select_enabled):
         return False
     assert snap["device_sort_passes"] == 0, (
         "config-3 contract violation: resident selection path ran "

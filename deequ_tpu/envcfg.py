@@ -26,10 +26,10 @@ now uniform):
 - ``flag01`` — ``'' | '0' | '1'`` strictly; anything else raises
   (the DEEQU_TPU_SELECT_KERNEL / DEEQU_TPU_ENCODED_INGEST posture,
   now shared by every on/off switch);
-- ``lenient_flag`` — any value other than ``'0'`` is on (the historical
-  DEEQU_TPU_DEVICE_FOLD / DEEQU_TPU_FUSED_RESIDENT contract: scripts in
-  the wild export ``=yes``; tightening those two retroactively would
-  flip behavior under existing deployments);
+- ``lenient_flag`` — any value other than ``'0'`` is on
+  (DEEQU_TPU_DISABLE_NATIVE, the one variable left of this kind:
+  scripts in the wild export ``=yes``; tightening it retroactively
+  would flip behavior under existing deployments);
 - ``int`` / ``float`` — parsed with optional ``minimum``; empty/unset
   yields the default. ``zero_disables=True`` maps 0 (and negatives) to
   None — the watchdog/deadline convention "0 means off";
@@ -163,22 +163,6 @@ def registry_snapshot() -> Dict[str, dict]:
 SCAN_WINDOW = register(EnvVar(
     "DEEQU_TPU_SCAN_WINDOW", "int", default=None, minimum=1,
     doc="pipelined-dispatch window (chunks in flight) for fused scans",
-))
-DEVICE_FOLD = register(EnvVar(
-    "DEEQU_TPU_DEVICE_FOLD", "lenient_flag", default=True,
-    doc="0 reverts to the host-side per-chunk partial fold (A/B hatch)",
-))
-FUSED_RESIDENT = register(EnvVar(
-    "DEEQU_TPU_FUSED_RESIDENT", "lenient_flag", default=True,
-    doc="0 drops the single-dispatch fused resident loop (A/B hatch)",
-))
-TRANSFER_F32 = register(EnvVar(
-    "DEEQU_TPU_TRANSFER_F32", "flag01", default=False,
-    doc="1 ships fractional columns hi-plane only (lossy, opt-in)",
-))
-COMPUTE = register(EnvVar(
-    "DEEQU_TPU_COMPUTE", "choice", default=None, choices=("f64", "F64"),
-    doc="f64 opts out of the two-float compute path (slow, bit-exact)",
 ))
 SELECT_KERNEL = register(EnvVar(
     "DEEQU_TPU_SELECT_KERNEL", "flag01", default=True,

@@ -315,7 +315,7 @@ def _check_fold_merge(plan_ir) -> List[LintFinding]:
 
 #: the packer's pre-decoded full-width planes — an encoded column found
 #: on one of these defeats the encoded-ingest contract
-_DECODED_PLANES = ("wide", "pair", "hi_only", "narrow_i32")
+_DECODED_PLANES = ("wide", "pair", "narrow_i32")
 
 
 def _check_encoded_ingest(plan_ir, census: Optional[Counter]) -> List[LintFinding]:

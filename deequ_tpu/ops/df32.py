@@ -22,8 +22,8 @@ used (ops/hll.py:_f64_key_u64 splits f64 exactly this way because
 XLA:TPU rejects f64->u64 bitcasts) — so sketches stay bit-identical.
 
 Every helper takes ``lo=None`` to mean "data is plain f64" (the escape
-hatch for |x| > f32_max columns and DEEQU_TPU_COMPUTE=f64) and falls back
-to the straight f64 reduction.
+hatch for the wide plane: |x| > f32_max, huge integers and
+predicate-compared columns) and falls back to the straight f64 reduction.
 """
 
 from __future__ import annotations

@@ -139,34 +139,19 @@ def _resolve_scan_window(window: Optional[int] = None) -> int:
     return window
 
 
-def _device_fold_enabled() -> bool:
-    """Escape hatch: DEEQU_TPU_DEVICE_FOLD=0 reverts to the host-side
-    per-chunk partial fold (one device->host fetch PER CHUNK instead of
-    per scan) — for A/B numerics comparison and emergencies."""
-    from deequ_tpu.envcfg import env_value
-
-    return env_value("DEEQU_TPU_DEVICE_FOLD")
-
-
-def _fused_resident_enabled() -> bool:
-    """The fused resident loop compiles the chunk step INSIDE a lax.scan;
-    XLA's optimizer may fuse/contract the compensated f32 reductions
-    differently there than in the standalone per-chunk program, shifting
-    f64 sum leaves by ~1 ulp vs the host fold (deterministic per
-    program; documented in docs/numerics.md). DEEQU_TPU_FUSED_RESIDENT=0
-    keeps the per-chunk device fold (bit-identical to the host fold,
-    still one fetch) while dropping only the single-dispatch fusion."""
-    from deequ_tpu.envcfg import env_value
-
-    return env_value("DEEQU_TPU_FUSED_RESIDENT")
-
-
 def device_foldable(op: "ScanOp") -> bool:
     """True when ``op``'s chunk partials can fold ON DEVICE: sum/min/max
     leaves merge elementwise and 'gather' leaves append into a
     fixed-capacity device buffer. Ops with a ``compact()`` hook (KLL)
     need host-side compaction mid-fold and keep the host path."""
     return op.compact is None
+
+
+def _folds_on_device(ops: Sequence["ScanOp"]) -> bool:
+    """The one rule for "fold the chunk partials on the device": every op
+    is ``device_foldable``. Otherwise the host fold (``_PartialFolder``,
+    one fetch per chunk) is the only fold that can compact mid-scan."""
+    return all(device_foldable(op) for op in ops)
 
 
 def _auto_chunk_rows_from_dtypes(
@@ -655,25 +640,6 @@ def _packs_as_pair(col: Column) -> bool:
     return cached
 
 
-def _transfer_f32() -> bool:
-    """Opt-in lossy mode: fractional columns transfer ONLY the hi plane
-    (half the bytes) and compute with lo = 0. Metric values then reflect
-    f32-rounded inputs — acceptable for profiling/monitoring, off by
-    default."""
-    from deequ_tpu.envcfg import env_value
-
-    return env_value("DEEQU_TPU_TRANSFER_F32")
-
-
-def _compute_f64() -> bool:
-    """Opt-out of the two-float compute path: fractional columns ship and
-    compute as f64 (the pre-round-4 behavior; ~10x slower device compute
-    on TPU, bit-identical to host f64 math)."""
-    from deequ_tpu.envcfg import env_value
-
-    return env_value("DEEQU_TPU_COMPUTE") is not None
-
-
 def _enc_eligible(col: Column) -> bool:
     """True when the column can ride the encoded (int16 dictionary-code)
     plane: it carries a ColumnChunk encoding whose dictionary fits the
@@ -712,9 +678,10 @@ def _warn_pair_compare_once(name: str, col=None) -> None:
     """A persisted/stream-pinned layout already routed this column over the
     ~49-bit f32 pair, but a predicate now compares it at a boundary; the
     layout can't change mid-flight, so comparisons may be ~1e-16 (relative)
-    off exact f64. Re-persist the table (or set DEEQU_TPU_COMPUTE=f64) for
-    exact predicate semantics. Deduped per Column OBJECT — a different
-    table reusing the same column name still gets its own warning."""
+    off exact f64. Re-persisting the table after the check is declared
+    routes the column over the wide plane (exact predicate semantics).
+    Deduped per Column OBJECT — a different table reusing the same column
+    name still gets its own warning."""
     key = (id(col), name)
     if key in _PAIR_COMPARE_WARNED:
         return
@@ -725,8 +692,8 @@ def _warn_pair_compare_once(name: str, col=None) -> None:
         f"column {name!r} is compared at a predicate boundary but was "
         "persisted/pinned on the two-float f32 plane (~49 mantissa bits); "
         "exact-equality predicates may miss values within ~1e-16 relative. "
-        "Re-persist the table after declaring the check, or set "
-        "DEEQU_TPU_COMPUTE=f64.",
+        "Re-persist the table (or restart the stream) after declaring the "
+        "check: the marked column then takes the wide f64 plane.",
         stacklevel=3,
     )
 
@@ -779,10 +746,10 @@ class PlaneStats:
         each plane: consecutive hi/lo rows whose mask rows are consecutive
         too, or absent throughout (null-free columns ship none). A layout
         that alternates degrades to runs of one row, never to a gather."""
-        hi_row, mask_row = self._packer._hi_row, self._packer._mask_row
+        pair_row, mask_row = self._packer._pair_row, self._packer._mask_row
         runs: List[Tuple[int, Optional[int], List[str], set]] = []
         for name, needs in route.columns:
-            row, mrow = hi_row[name], mask_row.get(name)
+            row, mrow = pair_row[name], mask_row.get(name)
             if runs:
                 start, mstart, names, wanted = runs[-1]
                 k = len(names)
@@ -845,9 +812,9 @@ class _ChunkPacker:
       ~48-bit lossless, every O(n) device op runs on native f32 units;
     - int32-safe integral + boolean -> i32 plane (exact pair split happens
       on device);
-    - huge integers, |x| > f32_max fractionals, and DEEQU_TPU_COMPUTE=f64
-      -> wide f64 plane (XLA software-f64 fallback);
-    - DEEQU_TPU_TRANSFER_F32=1 -> hi plane only (lossy, opt-in);
+    - huge integers, |x| > f32_max fractionals and predicate-compared
+      columns (``_exact_compare``) -> wide f64 plane (XLA software-f64
+      fallback): the data selects it, nothing else does;
     - null-free columns ship no mask row (validity is just row_valid);
     - dictionary-ENCODED numeric columns (``encode_ingest=True``, round
       8) -> int16 ``enc`` code plane, 2 bytes/row, null = -1 (no mask
@@ -871,7 +838,6 @@ class _ChunkPacker:
             # each batch against it, see _layout_upgrades)
             self.narrow_i32 = list(layout["narrow_i32"])
             self.pair_names = list(layout["pair"])
-            self.hi_only_names = list(layout["hi_only"])
             self.wide_names = list(layout["wide"])
             self.masked_names = list(layout["masked"])
             self.enc_names = list(layout.get("enc", ()))
@@ -879,37 +845,20 @@ class _ChunkPacker:
                 if getattr(cols.get(n), "_exact_compare", False):
                     _warn_pair_compare_once(n, cols.get(n))
         else:
-            f32_mode = _transfer_f32()
-            f64_mode = _compute_f64()
             # encoded routing first: enc columns leave the decoded-plane
             # classification entirely (and their classification must not
             # touch .values — that would force the decode the plane
-            # exists to avoid). Non-default numeric modes keep the
-            # decoded planes: wide-f64 has no (hi, lo) gather domain and
-            # hi-only is already half-width.
+            # exists to avoid)
             self.enc_names = (
                 [n for n in numeric if _enc_eligible(cols[n])]
-                if encode_ingest and not f64_mode and not f32_mode
+                if encode_ingest
                 else []
             )
             enc_set = set(self.enc_names)
             decoded = [n for n in numeric if n not in enc_set]
             self.narrow_i32 = [n for n in decoded if _packs_as_i32(cols[n])]
-            self.pair_names = []
-            self.hi_only_names = []
-            if not f64_mode:
-                for n in decoded:
-                    if cols[n].dtype != DType.FRACTIONAL:
-                        continue
-                    if f32_mode:
-                        self.hi_only_names.append(n)
-                    elif _packs_as_pair(cols[n]):
-                        self.pair_names.append(n)
-            routed = (
-                set(self.narrow_i32)
-                | set(self.pair_names)
-                | set(self.hi_only_names)
-            )
+            self.pair_names = [n for n in decoded if _packs_as_pair(cols[n])]
+            routed = set(self.narrow_i32) | set(self.pair_names)
             self.wide_names = [n for n in decoded if n not in routed]
             # null-free columns don't ship a mask row at all — their
             # validity is just row_valid (saves 1 byte/row/column);
@@ -918,10 +867,8 @@ class _ChunkPacker:
                 n for n in decoded if not bool(cols[n].mask.all())
             ]
         self.numeric_names = numeric
-        # the hi buffer carries pair columns first, then hi-only columns
-        self._hi_row = {
-            n: i for i, n in enumerate(self.pair_names + self.hi_only_names)
-        }
+        # a pair column's row on the hi plane and on the lo plane
+        self._pair_row = {n: i for i, n in enumerate(self.pair_names)}
         self._mask_row = {n: i for i, n in enumerate(self.masked_names)}
         self._enc_row = {n: i for i, n in enumerate(self.enc_names)}
         self.cols = cols
@@ -955,7 +902,7 @@ class _ChunkPacker:
             return out
 
         values = buf(self.wide_names, np.float64, 0.0)
-        hi = buf(self.pair_names + self.hi_only_names, np.float32, 0.0)
+        hi = buf(self.pair_names, np.float32, 0.0)
         lo = buf(self.pair_names, np.float32, 0.0)
         narrow_i = buf(self.narrow_i32, np.int32, 0)
         masks = buf(self.masked_names, np.bool_, False)
@@ -968,13 +915,8 @@ class _ChunkPacker:
             values[i, :n] = self.cols[name].values[start:stop]
         for i, name in enumerate(self.pair_names):
             split_pair_np(
-                self.cols[name].values[start:stop],
-                hi[self._hi_row[name], :n],
-                lo[i, :n],
+                self.cols[name].values[start:stop], hi[i, :n], lo[i, :n]
             )
-        with np.errstate(over="ignore", invalid="ignore"):
-            for name in self.hi_only_names:
-                hi[self._hi_row[name], :n] = self.cols[name].values[start:stop]
         for i, name in enumerate(self.narrow_i32):
             narrow_i[i, :n] = self.cols[name].values[start:stop]
         for name, i in self._mask_row.items():
@@ -1022,8 +964,6 @@ class _ChunkPacker:
                 h = xp.where(mask, xp.take(luts["_enc_hi"], safe), 0.0)
                 l = xp.where(mask, xp.take(luts["_enc_lo"], safe), 0.0)
             vals[name] = Val("num", h, mask, lo=l)
-        pair_set = set(self.pair_names)
-        hi_only_set = set(self.hi_only_names)
         narrow_set = set(self.narrow_i32)
         enc_set = set(self.enc_names)
         wide_row = {n: i for i, n in enumerate(self.wide_names)}
@@ -1047,17 +987,12 @@ class _ChunkPacker:
                 else:
                     h, l = int32_pair(data_i, xp)
                     vals[name] = Val("num", h, mask, lo=l)
-            elif name in pair_set:
-                h = hi[self._hi_row[name]]
-                l = lo[self.pair_names.index(name)]
+            elif name in self._pair_row:
+                h = hi[self._pair_row[name]]
+                l = lo[self._pair_row[name]]
                 if mask is None:
                     mask = xp.ones(h.shape, dtype=bool)
                 vals[name] = Val("num", h, mask, lo=l)
-            elif name in hi_only_set:
-                h = hi[self._hi_row[name]]
-                if mask is None:
-                    mask = xp.ones(h.shape, dtype=bool)
-                vals[name] = Val("num", h, mask, lo=xp.zeros_like(h))
             else:
                 data = values[wide_row[name]]
                 if mask is None:
@@ -1077,7 +1012,6 @@ class _ChunkPacker:
         return {
             "narrow_i32": tuple(self.narrow_i32),
             "pair": tuple(self.pair_names),
-            "hi_only": tuple(self.hi_only_names),
             "wide": tuple(self.wide_names),
             "masked": tuple(self.masked_names),
             "enc": tuple(self.enc_names),
@@ -1090,12 +1024,11 @@ class _ChunkPacker:
         view.string_names = self.string_names
         view.narrow_i32 = self.narrow_i32
         view.pair_names = self.pair_names
-        view.hi_only_names = self.hi_only_names
         view.wide_names = self.wide_names
         view.numeric_names = self.numeric_names
         view.masked_names = self.masked_names
         view.enc_names = self.enc_names
-        view._hi_row = self._hi_row
+        view._pair_row = self._pair_row
         view._mask_row = self._mask_row
         view._enc_row = self._enc_row
         view.cols = None  # pack() is not available on a view
@@ -1159,10 +1092,6 @@ class DeviceTableCache:
         self.mesh = mesh
         self.nbytes = nbytes
         self.device_count = device_count
-        # lazily-built (n_chunks, ...) stacked views for the fused
-        # single-dispatch lax.scan loop — a second HBM copy, so gated on
-        # the resident budget and dropped with the residency on eviction
-        self._stacked = None
         # (op cache_keys, chunk) -> (step_fn, shapes): reused traced
         # programs, LRU-bounded so long-lived services with varied analyzer
         # sets don't accumulate executables without limit
@@ -1170,35 +1099,11 @@ class DeviceTableCache:
         _ACTIVE_CACHES.add(self)
 
     @property
-    def resident_bytes(self) -> int:
-        """The table's HBM footprint over all its devices: a built
-        stacked fused-loop copy doubles it."""
-        return self.nbytes * (2 if self._stacked is not None else 1)
-
-    @property
     def per_device_bytes(self) -> int:
         """What each device of the mesh holds of it: every buffer is
         row-sharded over a chunk that the mesh divides, so the shares are
         equal."""
-        return self.resident_bytes // self.device_count
-
-    def stacked_chunks(self):
-        """The resident chunks stacked along a leading chunk axis (for the
-        one-dispatch fused loop), or None when a second copy of the table
-        would blow a device's HBM budget. Built once per cache."""
-        if len(self.device_chunks) < 2:
-            return None
-        if self._stacked is None:
-            if (
-                resident_bytes_per_device() + self.per_device_bytes
-                > self.MAX_RESIDENT_BYTES
-            ):
-                return None
-            self._stacked = tuple(
-                jnp.stack([c[j] for c in self.device_chunks])
-                for j in range(8)
-            )
-        return self._stacked
+        return self.nbytes // self.device_count
 
     def get_program(self, key):
         return self.programs.get(key)
@@ -1241,7 +1146,7 @@ _GLOBAL_PROGRAMS = _BoundedLRU(64)
 def total_resident_bytes() -> int:
     """Bytes resident over ALL devices: the ledger that returns to zero
     when every table is unpersisted."""
-    return sum(c.resident_bytes for c in _ACTIVE_CACHES)
+    return sum(c.nbytes for c in _ACTIVE_CACHES)
 
 
 def resident_bytes_per_device() -> int:
@@ -1269,8 +1174,8 @@ def persist_table(
     what is resident there already; past it the typed ``MemoryError``.
 
     Columns carrying a dictionary encoding stay ENCODED in HBM (int16
-    code plane + dictionary LUTs, 2-8x smaller than the decoded planes —
-    raising the fused-resident ceiling); scans decode via a fused gather.
+    code plane + dictionary LUTs, 2-8x smaller than the decoded planes);
+    scans decode via a fused gather.
     ``encode`` overrides the DEEQU_TPU_ENCODED_INGEST default.
     """
     from deequ_tpu.ops.scan_plan import encoded_ingest_enabled
@@ -1387,8 +1292,7 @@ def _scoped_update(op: "ScanOp", vals, row_valid, local_n):
 
 def _build_step_fns(ops, unpacker, mesh, local_n, lut_keys: Tuple[str, ...] = ()):
     """Build (jitted flat step fn, shape fn, raw flat fn) for one packer
-    layout — the raw (unjitted) flat fn is what the fused resident
-    ``lax.scan`` loop composes into its single dispatch.
+    layout — the raw (unjitted) flat fn is what the plan lint traces.
 
     The flat step computes every op's partial state for one packed chunk,
     merges across the mesh with per-leaf collectives, and concatenates all
@@ -1586,7 +1490,6 @@ def _global_prog_key(prog_key, packer, mesh):
         tuple(packer.wide_names),
         tuple(packer.narrow_i32),
         tuple(packer.pair_names),
-        tuple(packer.hi_only_names),
         tuple(packer.masked_names),
         tuple(packer.string_names),
         tuple(packer.enc_names),
@@ -2135,11 +2038,10 @@ def _evict_device_cache(table) -> int:
         return 0
     freed = cache.nbytes
     # drop the buffers eagerly — the WeakSet entry dies with the cache,
-    # but the device arrays must not wait for a GC cycle mid-OOM (the
-    # stacked fused-loop copy and any in-flight fold accumulator die
-    # with the residency: a bisected retry starts a fresh fold)
+    # but the device arrays must not wait for a GC cycle mid-OOM (any
+    # in-flight fold accumulator dies with the attempt: a bisected retry
+    # starts a fresh fold)
     cache.device_chunks = []
-    cache._stacked = None
     cache.programs.clear()
     # the cache object may outlive the eviction (a caller's reference, a
     # pending GC cycle): zero its accounting and drop it from the live
@@ -2226,9 +2128,10 @@ def run_scan(
     DEVICE (left-to-right chunk order) and the whole pass performs
     exactly one device->host fetch of the final flat state vector — the
     one-fetch-per-scan contract, observable as
-    ``SCAN_STATS.device_fetches``. Ops with ``compact()`` hooks keep the
-    host fold (one fetch per chunk); ``DEEQU_TPU_DEVICE_FOLD=0`` forces
-    the host fold everywhere.
+    ``SCAN_STATS.device_fetches``. The ops alone select the fold
+    (``_folds_on_device``): one with a ``compact()`` hook, or a gather
+    leaf past ``MAX_FOLD_CAPACITY`` chunks, keeps the host fold (one
+    fetch per chunk).
 
     ``window`` bounds in-flight chunks (pipelined dispatch); default 3,
     overridable process-wide via ``DEEQU_TPU_SCAN_WINDOW``.
@@ -2391,8 +2294,7 @@ def run_scan(
     scan_id = next(_SCAN_IDS)
     from deequ_tpu.ops import scan_executors
 
-    kind = scan_executors.classify(table, mesh)
-    if kind == "streaming":
+    if scan_executors.classify(table, mesh) == "streaming":
         return scan_executors.run_streaming_scan(
             table, ops,
             chunk_rows=chunk_rows, mesh=mesh, defer=defer,
@@ -2443,10 +2345,10 @@ def run_scan(
             else "unhealthy_backend",
             consecutive_faults=DEVICE_HEALTH.consecutive_faults,
         )
-    # the executor split (round 19): resident and sharded scans share one
-    # ladder body in ops/scan_executors.py (the mesh rungs self-gate on
-    # mesh size); re-classify after quarantine may have shrunk the mesh
-    return scan_executors.EXECUTORS[scan_executors.classify(table, mesh)](
+    # resident and sharded scans share one ladder body (the mesh rungs
+    # self-gate on mesh size, so a mesh shrunk by quarantine needs no
+    # second look)
+    return scan_executors.run_laddered_scan(
         table, ops,
         chunk_rows=chunk_rows, mesh=mesh, defer=defer,
         on_device_error=on_device_error,
@@ -2616,8 +2518,7 @@ def _run_scan_once(
     use_fold = (
         n_chunks > 1
         and (not has_gather or n_chunks <= MAX_FOLD_CAPACITY)
-        and _device_fold_enabled()
-        and all(device_foldable(op) for op in ops)
+        and _folds_on_device(ops)
     )
     plan: Optional[_DeviceFoldPlan] = None
     acc = None
@@ -2655,16 +2556,15 @@ def _run_scan_once(
     if cache is not None:
         SCAN_STATS.resident_passes += 1
         SCAN_STATS.bytes_resident += cache.nbytes
-        # static plan lint BEFORE any dispatch (including the fused
-        # stack allocation): the resident chunks supply the arg shapes
+        # static plan lint BEFORE any dispatch: the resident chunks supply
+        # the arg shapes
         if cache.device_chunks:
             _maybe_plan_lint(
                 plan_ir, raw_flat, cache.device_chunks[0], lut_arrays,
                 prog_key, packer, mesh, plan_lint,
                 fallback=bool(scan_ctx.get("fallback")),
             )
-
-        def ensure_shapes(args):
+        for ci, args in enumerate(cache.device_chunks):
             if folder.shapes is None:
                 folder.shapes = device_call(
                     lambda: jax.eval_shape(shape_fn, *args, lut_arrays),
@@ -2678,74 +2578,14 @@ def _run_scan_once(
                     _GLOBAL_PROGRAMS.put(
                         global_key, (step_fn, folder.shapes, raw_flat)
                     )
-
-        # fused resident loop: one jitted lax.scan over the stacked
-        # resident chunks — per-chunk partials never exist as separate
-        # dispatches, the whole pass is ONE dispatch + ONE fetch
-        fused = None
-        fused_built = False
-        stacked = None
-        if use_fold and mesh is None and n_chunks > 1 and _fused_resident_enabled():
-            # the stack is the largest new HBM allocation of the scan (a
-            # second copy of the table): run it at the execute boundary
-            # so a real RESOURCE_EXHAUSTED raises TYPED and feeds the
-            # same eviction/bisection policy as any other device OOM
-            stacked = device_call(
-                cache.stacked_chunks, "execute",
-                what="resident chunk stack", deadline=device_deadline,
-            )
-            if stacked is not None:
-                ensure_shapes(cache.device_chunks[0])
-                plan = _fold_plan_for(ops, folder.shapes, n_chunks)
-                fused_key = (
-                    ("fused", prog_key, n_chunks)
-                    if prog_key is not None
-                    else None
-                )
-                fused = (
-                    cache.get_program(fused_key) if fused_key else None
-                )
-                if fused is None:
-                    SCAN_STATS.programs_built += 1
-                    fused_built = True
-                    fplan, rflat = plan, raw_flat
-
-                    def _fused(stacked_bufs, luts):
-                        def body(acc_c, chunk_args):
-                            flat = rflat(*chunk_args, luts)
-                            return fplan.merge_body(acc_c, flat), None
-
-                        out, _ = jax.lax.scan(
-                            body, jnp.asarray(fplan._init_np), stacked_bufs
-                        )
-                        return out
-
-                    fused = jax.jit(_fused)
-                    if fused_key:
-                        cache.put_program(fused_key, fused)
-                else:
-                    SCAN_STATS.programs_reused += 1
-        if fused is not None:
-            acc = device_call(
-                lambda: fused(stacked, lut_arrays),
-                "execute", what="fused resident scan dispatch",
+            flat = device_call(
+                lambda: step_fn(*args, lut_arrays),
+                "execute", what=f"chunk {ci} dispatch",
                 deadline=device_deadline,
-                hook_ctx={**scan_ctx, "chunk_index": 0},
-                seam_name="build" if fused_built else None,
+                hook_ctx={**scan_ctx, "chunk_index": ci},
+                seam_name=dispatch_seam(),
             )
-            _record_kernel_passes(plan_ir, n_chunks)
-            folded = n_chunks
-        else:
-            for ci, args in enumerate(cache.device_chunks):
-                ensure_shapes(args)
-                flat = device_call(
-                    lambda: step_fn(*args, lut_arrays),
-                    "execute", what=f"chunk {ci} dispatch",
-                    deadline=device_deadline,
-                    hook_ctx={**scan_ctx, "chunk_index": ci},
-                    seam_name=dispatch_seam(),
-                )
-                after_dispatch(flat, ci)
+            after_dispatch(flat, ci)
     else:
         # double-buffered host->device staging (round 8, the Eiger
         # discipline): chunk k+1's async device_put is ISSUED before
@@ -3211,7 +3051,6 @@ def _layout_upgrades(layout: dict, cols: Dict[str, Column]) -> Optional[dict]:
             n for n in layout["narrow_i32"] if n not in promote_set
         ),
         "pair": tuple(n for n in layout["pair"] if n not in promote_set),
-        "hi_only": layout["hi_only"],
         "wide": tuple(list(layout["wide"]) + promote + enc_demote),
         "masked": tuple(list(layout["masked"]) + need_mask),
         "enc": tuple(
@@ -3325,9 +3164,7 @@ def _run_scan_stream(
     # per chunk, the accumulator drains only when its fixed gather
     # capacity fills (STREAM_FOLD_CAPACITY chunks) and once at the end —
     # a TB-scale stream fetches O(chunks/capacity) times
-    use_fold = _device_fold_enabled() and all(
-        device_foldable(op) for op in ops
-    )
+    use_fold = _folds_on_device(ops)
     fold_state: Dict[str, Any] = {"plan": None, "acc": None, "filled": 0}
     # double-buffered staging across the whole stream (batch boundaries
     # included): each entry is a transferred-but-undispatched chunk WITH

@@ -1,25 +1,25 @@
-"""Scan EXECUTORS — the run strategies behind ``run_scan``, split out of
+"""Scan executors — the run strategies behind ``run_scan``, split out of
 the engine by the round-19 plan optimizer.
 
 ``ops/scan_engine.run_scan`` owns RESOLUTION (switch/env/deadline/budget
 resolution, recorder scoping, mesh quarantine) and then hands the scan to
-exactly one executor here, chosen by :func:`classify`:
+one of the two executors here, by :func:`classify`:
 
-- ``"streaming"`` — one governed pass over a streaming table (no retry
-  ladder: a half-consumed stream cannot rewind);
-- ``"resident"`` — the in-memory fault ladder on a single device
-  (encoded-demote -> OOM-bisect -> CPU-fallback rungs);
-- ``"sharded"`` — the same ladder on a multi-chip mesh, with the
-  mesh rungs (reshard/straggler) armed;
-- ``"packed"`` — the serving-side coalesced executor
-  (serve/executor.py): many tenant suites in one padded program.
+- ``"streaming"`` — :func:`run_streaming_scan`: one governed pass over a
+  streaming table (no retry ladder: a half-consumed stream cannot
+  rewind);
+- ``"resident"`` / ``"sharded"`` — :func:`run_laddered_scan`: the
+  in-memory fault ladder (encoded-demote -> OOM-bisect -> CPU-fallback
+  rungs), on one device or on a multi-chip mesh with the mesh rungs
+  (reshard/straggler) armed.
 
-``"resident"`` and ``"sharded"`` share one ladder body on purpose — the
-mesh rungs self-gate on mesh size, and splitting the loop would fork the
-re-plan-per-attempt contract into two copies that drift. Every rung
-re-enters ``_engine._run_scan_once``, which re-plans (selection variant,
-encoded ingest, chunk shape, lint) per attempt — the executor split moves
-code, not behavior.
+One ladder body serves both on purpose — the mesh rungs self-gate on mesh
+size, and splitting the loop would fork the re-plan-per-attempt contract
+into two copies that drift. Every rung re-enters
+``_engine._run_scan_once``, which re-plans (selection variant, encoded
+ingest, chunk shape, lint) per attempt. The serving coalescer
+(``serve/executor.run_coalesced``) and the windows engine
+(``windows/engine.drive``) are called by their own layers, not from here.
 
 Engine internals are reached via the lazy module attribute
 (``_engine()._run_scan_once`` etc.), never ``from``-imported: tests
@@ -52,13 +52,9 @@ def _mesh_size(m) -> int:
     return math.prod(m.devices.shape) if m is not None else 1
 
 
-def classify(table, mesh=None, packed: bool = False) -> str:
-    """The executor-selection policy: which run strategy this scan takes.
-    ``packed`` is asserted by the serving coalescer (it already holds a
-    batch of tenant suites); everything else derives from the table and
-    mesh shape."""
-    if packed:
-        return "packed"
+def classify(table, mesh=None) -> str:
+    """The executor-selection policy: which run strategy this scan takes,
+    derived from the table and the mesh shape."""
     if getattr(table, "is_streaming", False):
         return "streaming"
     if _mesh_size(mesh) > 1:
@@ -391,38 +387,3 @@ def run_laddered_scan(
                     )
                     continue
                 raise
-
-
-def run_packed(requests, tenants=None):
-    """The serving-side packed executor: many tenant suites coalesced
-    into one padded program (serve/executor.py owns the packing; this is
-    the policy-driver entry so ``classify`` covers every strategy)."""
-    from deequ_tpu.serve.executor import run_coalesced
-
-    return run_coalesced(requests, tenants=tenants)
-
-
-def run_windowed_scan(stream, batches, flush=False):
-    """The windowed executor (round 20): advance a
-    ``deequ_tpu.windows.WindowedStream`` over ``batches`` — every open
-    event-time pane folds in ONE dispatch per batch (the
-    ``variant="windowed"`` plan's contract), a resumed stream skips the
-    batches its recovered state already folded, and the return value is
-    the list of WindowClose records the advancing watermark produced
-    (the windows engine owns the pane program; this is the policy-driver
-    entry so the executor registry covers the windowed strategy)."""
-    from deequ_tpu.windows.engine import drive
-
-    return drive(stream, batches, flush=flush)
-
-
-#: executor registry — ``classify()``'s kinds to their run strategies.
-#: "resident" and "sharded" intentionally share the ladder body (the
-#: mesh rungs self-gate on mesh size).
-EXECUTORS = {
-    "streaming": run_streaming_scan,
-    "resident": run_laddered_scan,
-    "sharded": run_laddered_scan,
-    "packed": run_packed,
-    "windowed": run_windowed_scan,
-}

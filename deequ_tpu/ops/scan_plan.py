@@ -80,9 +80,9 @@ def encoded_ingest_enabled(param: Optional[bool] = None) -> bool:
 def select_kernel_enabled(param: Optional[bool] = None) -> bool:
     """Resolve the selection-kernel switch: explicit argument wins, then
     the DEEQU_TPU_SELECT_KERNEL env var ('0' disables — the A/B and
-    regression-triage escape hatch, mirroring DEEQU_TPU_FUSED_RESIDENT;
-    parsed via the deequ_tpu/envcfg registry), then on. Validated: the
-    argument must be bool-like, the env var one of '', '0', '1'."""
+    regression-triage escape hatch; parsed via the deequ_tpu/envcfg
+    registry), then on. Validated: the argument must be bool-like, the
+    env var one of '', '0', '1'."""
     from deequ_tpu.envcfg import env_value
 
     if param is not None:
@@ -357,9 +357,9 @@ def plan_windowed_scan(
 
 def _selectable(op, packer) -> bool:
     """True when every column the op's selection kernel keys on rides a
-    (hi, lo) plane in this packer layout: two-float pairs, i32-split
-    integrals, or hi-only (lossy f32) — anything but the wide-f64 plane,
-    whose 64-bit keys the u32 radix passes cannot cover."""
+    (hi, lo) plane in this packer layout: two-float pairs or i32-split
+    integrals — anything but the wide-f64 plane, whose 64-bit keys the
+    u32 radix passes cannot cover."""
     if packer is None:
         return False
     # encoded columns qualify: the dictionary gather reconstructs the
@@ -368,7 +368,6 @@ def _selectable(op, packer) -> bool:
     keyed = (
         set(packer.pair_names)
         | set(packer.narrow_i32)
-        | set(packer.hi_only_names)
         | set(getattr(packer, "enc_names", ()))
     )
     return all(c in keyed for c in op.select_columns)

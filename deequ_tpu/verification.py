@@ -47,9 +47,9 @@ class VerificationResult:
       ``drain_wait_seconds``): the observable for the
       one-fetch-per-scan contract — for a grouping-free run,
       ``device_fetches`` exceeding ``scan_passes`` means per-chunk round
-      trips somewhere (a non-device-foldable op, or
-      DEEQU_TPU_DEVICE_FOLD=0); grouping passes add their own bounded
-      O(G) materializations.
+      trips somewhere (a non-device-foldable op keeps the host
+      fold); grouping passes add their own bounded O(G)
+      materializations.
 
     Mesh faults get the same reported-never-silent treatment:
 

@@ -719,9 +719,7 @@ class WindowedStream:
 
 def drive(stream: WindowedStream, batches, flush: bool = False) -> List[WindowClose]:
     """Advance ``stream`` over ``batches`` (an iterable of host batch
-    dicts), skipping every batch a resumed stream already folded. The
-    windowed executor seam (``ops/scan_executors.run_windowed_scan``)
-    delegates here."""
+    dicts), skipping every batch a resumed stream already folded."""
     closes: List[WindowClose] = []
     skip = stream.next_batch_index
     for i, batch in enumerate(batches):

@@ -94,8 +94,7 @@ def _chunk_avals(packer, chunk, plane, rows):
     for one packed chunk — the shapes ``_ChunkPacker.pack`` would emit."""
     return (
         _aval((len(packer.wide_names), chunk), np.float64, plane),
-        _aval((len(packer.pair_names) + len(packer.hi_only_names), chunk),
-              np.float32, plane),
+        _aval((len(packer.pair_names), chunk), np.float32, plane),
         _aval((len(packer.pair_names), chunk), np.float32, plane),
         _aval((len(packer.narrow_i32), chunk), np.int32, plane),
         _aval((len(packer.masked_names), chunk), np.bool_, plane),
@@ -434,8 +433,7 @@ def test_coalesced_tenant_step_compiles(topo, one_chip, monkeypatch):
     _tree, _flat, vstep = real_build(big, lut_keys, op_order=op_order)
     avals = (
         _aval((K, len(layout["wide"]), n), np.float64, one_chip),
-        _aval((K, len(layout["pair"]) + len(layout["hi_only"]), n),
-              np.float32, one_chip),
+        _aval((K, len(layout["pair"]), n), np.float32, one_chip),
         _aval((K, len(layout["pair"]), n), np.float32, one_chip),
         _aval((K, len(layout["narrow_i32"]), n), np.int32, one_chip),
         _aval((K, len(layout["masked"]), n), np.bool_, one_chip),

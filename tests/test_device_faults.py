@@ -836,9 +836,9 @@ def test_oom_mid_fold_restarts_device_accumulator_cleanly():
     assert SCAN_STATS.snapshot()["device_fetches"] == 1
 
 
-def test_fused_resident_scan_survives_injected_oom():
-    """An OOM at the fused single-dispatch resident loop evicts the
-    stacked residency and bisects like any other scan — correct metrics,
+def test_multi_chunk_resident_scan_survives_injected_oom():
+    """An OOM at the first dispatch of a multi-chunk resident scan evicts
+    the residency and bisects like any other scan — correct metrics,
     recorded degradation."""
     from deequ_tpu.ops.scan_engine import persist_table
 
@@ -855,7 +855,7 @@ def test_fused_resident_scan_survives_injected_oom():
         result = run_scan(
             table, [a.scan_op(table) for a in basic_analyzers()],
         )
-    assert table._device_cache is None  # residency (and stack) evicted
+    assert table._device_cache is None  # residency evicted
     assert SCAN_STATS.oom_bisections == 1
     for got, want in zip(result, clean):
         gl = list(got.values()) if isinstance(got, dict) else [got]

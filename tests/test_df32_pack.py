@@ -206,23 +206,15 @@ def _mixed_columns(n):
     }
 
 
-@pytest.mark.parametrize("hi_only", [False, True])
-def test_chunk_packer_planes_are_the_parents(hi_only, monkeypatch):
+def test_chunk_packer_planes_are_the_parents():
     """A mixed table, two chunks of which the second has a padded tail:
     every plane equals the one the parent's ``pack`` made, rebuilt here
     from the oracle."""
-    if hi_only:
-        monkeypatch.setenv("DEEQU_TPU_TRANSFER_F32", "1")
     n, chunk = BLOCK + 1000, BLOCK + 64
     cols = _mixed_columns(n)
     packer = _ChunkPacker(cols, chunk)
-    fractional = ["frac", "dense", "huge"]
-    if hi_only:
-        assert packer.hi_only_names == fractional and not packer.pair_names
-        assert packer.wide_names == ["big_int"]
-    else:
-        assert packer.pair_names == ["frac", "dense"]
-        assert packer.wide_names == ["huge", "big_int"]
+    assert packer.pair_names == ["frac", "dense"]
+    assert packer.wide_names == ["huge", "big_int"]
     assert packer.narrow_i32 == ["small_int", "flag"]
     assert packer.masked_names == ["frac", "huge", "big_int"]
 
@@ -236,15 +228,10 @@ def test_chunk_packer_planes_are_the_parents(hi_only, monkeypatch):
             return out
 
         with np.errstate(over="ignore", invalid="ignore"):
-            if hi_only:
-                want_hi = plane(fractional, np.float32, 0.0,
-                                lambda c: cols[c].values.astype(np.float32))
-                want_lo = np.empty((0, chunk), dtype=np.float32)
-            else:
-                want_hi = plane(packer.pair_names, np.float32, 0.0,
-                                lambda c: oracle_split(cols[c].values)[0])
-                want_lo = plane(packer.pair_names, np.float32, 0.0,
-                                lambda c: oracle_split(cols[c].values)[1])
+            want_hi = plane(packer.pair_names, np.float32, 0.0,
+                            lambda c: oracle_split(cols[c].values)[0])
+            want_lo = plane(packer.pair_names, np.float32, 0.0,
+                            lambda c: oracle_split(cols[c].values)[1])
         want = (
             plane(packer.wide_names, np.float64, 0.0,
                   lambda c: cols[c].values),

@@ -749,7 +749,7 @@ def test_plan_encoded_decode_rule_catches_drift():
     )
     routed_wide = ScanPlan(
         layout=(
-            ("enc", ()), ("wide", ("x",)), ("pair", ()), ("hi_only", ()),
+            ("enc", ()), ("wide", ("x",)), ("pair", ()),
             ("narrow_i32", ()), ("masked", ()),
         ),
         **base,
@@ -758,7 +758,7 @@ def test_plan_encoded_decode_rule_catches_drift():
     assert [f.rule for f in findings] == ["plan-encoded-decode"]
     missing = ScanPlan(
         layout=(
-            ("enc", ()), ("wide", ()), ("pair", ()), ("hi_only", ()),
+            ("enc", ()), ("wide", ()), ("pair", ()),
             ("narrow_i32", ()), ("masked", ()),
         ),
         **base,
@@ -766,7 +766,7 @@ def test_plan_encoded_decode_rule_catches_drift():
     assert [f.rule for f in lint_plan(missing)] == ["plan-encoded-decode"]
     healthy = ScanPlan(
         layout=(
-            ("enc", ("x",)), ("wide", ()), ("pair", ()), ("hi_only", ()),
+            ("enc", ("x",)), ("wide", ()), ("pair", ()),
             ("narrow_i32", ()), ("masked", ()),
         ),
         **base,

@@ -130,18 +130,37 @@ def test_two_tables_shares_add_up_on_a_device(monkeypatch):
 
 
 @pytest.mark.parametrize("n", [1, 4])
-def test_the_stacked_copy_is_held_to_a_devices_budget(n, monkeypatch):
-    table = small_table()
+def test_a_resident_table_takes_one_loop_and_no_second_copy(n, monkeypatch):
+    """On one device as on a mesh, a persisted table of N chunks is N
+    dispatches of ONE program, N fold merges and one fetch, and the scan
+    leaves on the device what ``persist()`` put there."""
+    from deequ_tpu.ops.device_policy import install_scan_fault_hook
+
+    table = small_table(rows=4000)
+    ops = moments_ops(table)
+    dispatched, merges = [], []
+    merge = eng._DeviceFoldPlan.merge
+    monkeypatch.setattr(
+        eng._DeviceFoldPlan, "merge",
+        lambda plan, acc, new: merges.append(1) or merge(plan, acc, new))
     with use_mesh(mesh_of(n)):
         cache = persist_table(table, chunk_rows=1024)
-        share = cache.per_device_bytes
-        monkeypatch.setattr(DeviceTableCache, "MAX_RESIDENT_BYTES",
-                            2 * share - 1)
-        assert cache.stacked_chunks() is None
-        monkeypatch.setattr(DeviceTableCache, "MAX_RESIDENT_BYTES", 2 * share)
-        assert cache.stacked_chunks() is not None
-        assert cache.per_device_bytes == 2 * share
-        assert total_resident_bytes() == 2 * cache.nbytes
+        assert len(cache.device_chunks) == 4
+        assert total_resident_bytes() == cache.nbytes
+        previous = install_scan_fault_hook(
+            lambda boundary, ctx: dispatched.append(ctx["chunk_index"]))
+        try:
+            SCAN_STATS.reset()
+            run_scan(table, ops)
+        finally:
+            install_scan_fault_hook(previous)
+        assert dispatched == [0, 1, 2, 3] and len(merges) == 4
+        assert SCAN_STATS.programs_built == 1
+        assert len(cache.programs) == 1
+        assert SCAN_STATS.chunks_processed == 4
+        assert SCAN_STATS.device_fetches == 1
+        assert total_resident_bytes() == cache.nbytes
+        assert cache.per_device_bytes == cache.nbytes // n
         table.unpersist()
     assert total_resident_bytes() == 0
 

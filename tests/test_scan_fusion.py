@@ -2,6 +2,8 @@
 reference's SparkMonitor job-count tests (AnalysisRunnerTests.scala:51-120:
 6 shareable analyzers fused = 1 job; grouping analyzers = 2 jobs)."""
 
+import pytest
+
 from deequ_tpu.analyzers import (
     ApproxCountDistinct,
     Completeness,
@@ -753,6 +755,84 @@ def test_multi_chunk_resident_scan_is_one_fetch():
         table.unpersist()
 
 
+def test_resident_scan_bit_identical_to_host_packed_scan():
+    """A persisted multi-chunk table takes the same per-chunk step and
+    the same left-to-right device fold as the host-packed scan of the
+    same rows at the same chunk_rows: every leaf of every op's state is
+    BIT-identical (docs/numerics.md; no resident scan is excused)."""
+    import jax
+    import numpy as np
+
+    from deequ_tpu.analyzers import Correlation
+    from deequ_tpu.ops.scan_engine import persist_table, run_scan
+
+    table = _fold_table()
+    analyzers = _fold_analyzers() + [Correlation("a", "b")]
+    ops = _fold_ops(table, analyzers)
+
+    SCAN_STATS.reset()
+    packed = run_scan(table, ops, chunk_rows=4096)
+    assert SCAN_STATS.resident_passes == 0
+    assert SCAN_STATS.bytes_packed > 0
+
+    persist_table(table, chunk_rows=4096)
+    try:
+        SCAN_STATS.reset()
+        resident = run_scan(table, ops)
+        assert SCAN_STATS.resident_passes == 1
+        assert SCAN_STATS.bytes_packed == 0
+        assert SCAN_STATS.chunks_processed == 8
+        assert SCAN_STATS.device_fetches == 1
+    finally:
+        table.unpersist()
+    for i, (x, y) in enumerate(zip(packed, resident)):
+        for ap, ar in zip(jax.tree.leaves(x), jax.tree.leaves(y)):
+            ap, ar = np.asarray(ap), np.asarray(ar)
+            assert ap.dtype == ar.dtype, (i, ap.dtype, ar.dtype)
+            assert np.array_equal(ap, ar, equal_nan=True), (i, ap, ar)
+
+
+@pytest.mark.parametrize("name,value", [
+    ("DEEQU_TPU_DEVICE_FOLD", "0"),
+    ("DEEQU_TPU_FUSED_RESIDENT", "0"),
+    ("DEEQU_TPU_TRANSFER_F32", "1"),
+    ("DEEQU_TPU_COMPUTE", "f64"),
+])
+def test_closed_hatches_steer_nothing(name, value, monkeypatch):
+    """The four A/B switches of the scan path are gone from the registry,
+    and exporting one changes neither the packer's layout, nor the fetch
+    count, nor a bit of the result."""
+    import jax
+    import numpy as np
+
+    from deequ_tpu.envcfg import registry_snapshot
+    from deequ_tpu.ops.scan_engine import _ChunkPacker, persist_table, run_scan
+
+    def scan():
+        table = _fold_table()
+        cols = {n: table[n] for n in table.column_names}
+        layout = _ChunkPacker(cols, 4096).layout()
+        ops = _fold_ops(table, _fold_analyzers())
+        persist_table(table, chunk_rows=4096)
+        try:
+            SCAN_STATS.reset()
+            result = run_scan(table, ops)
+            counts = (SCAN_STATS.device_fetches, SCAN_STATS.chunks_processed,
+                      SCAN_STATS.programs_built + SCAN_STATS.programs_reused)
+        finally:
+            table.unpersist()
+        return layout, counts, [np.asarray(x) for x in jax.tree.leaves(result)]
+
+    layout, counts, leaves = scan()
+    assert counts == (1, 8, 1)
+    assert layout["pair"] == ("a",) and layout["wide"] == ()
+    monkeypatch.setenv(name, value)
+    assert name not in registry_snapshot()
+    layout_set, counts_set, leaves_set = scan()
+    assert layout_set == layout and counts_set == counts
+    assert all(x.tobytes() == y.tobytes() for x, y in zip(leaves, leaves_set))
+
+
 def test_device_fold_bit_identical_to_host_fold(monkeypatch):
     """Device-folded partials (per-chunk merge + gather capacity) must be
     BIT-identical to the host fold at the same chunking — sum/min/max
@@ -761,6 +841,7 @@ def test_device_fold_bit_identical_to_host_fold(monkeypatch):
     import jax
     import numpy as np
 
+    import deequ_tpu.ops.scan_engine as se
     from deequ_tpu.analyzers import Correlation
     from deequ_tpu.ops.scan_engine import run_scan
 
@@ -768,13 +849,13 @@ def test_device_fold_bit_identical_to_host_fold(monkeypatch):
     analyzers = _fold_analyzers() + [Correlation("a", "b")]
     ops = _fold_ops(table, analyzers)
 
-    monkeypatch.setenv("DEEQU_TPU_DEVICE_FOLD", "0")
-    SCAN_STATS.reset()
-    host = run_scan(table, ops, chunk_rows=4096)
+    with monkeypatch.context() as host_fold:
+        host_fold.setattr(se, "_folds_on_device", lambda ops: False)
+        SCAN_STATS.reset()
+        host = run_scan(table, ops, chunk_rows=4096)
     host_fetches = SCAN_STATS.device_fetches
     assert host_fetches == 8  # one per chunk: what the fold removes
 
-    monkeypatch.setenv("DEEQU_TPU_DEVICE_FOLD", "1")
     SCAN_STATS.reset()
     folded = run_scan(table, ops, chunk_rows=4096)
     assert SCAN_STATS.device_fetches == 1
@@ -905,16 +986,18 @@ def test_streaming_scan_fetches_once(monkeypatch):
     """The fused streaming pass device-folds across batches: a many-batch
     stream of device-foldable ops drains once (vs once per chunk), and
     metrics match the host-folded stream bit-for-bit (same chunking)."""
+    import deequ_tpu.ops.scan_engine as se
     from deequ_tpu.data.streaming import stream_table
 
     table = _fold_table()
     analyzers = _fold_analyzers()
-    monkeypatch.setenv("DEEQU_TPU_DEVICE_FOLD", "0")
-    SCAN_STATS.reset()
-    ref = AnalysisRunner.do_analysis_run(stream_table(table, 4096), analyzers)
+    with monkeypatch.context() as host_fold:
+        host_fold.setattr(se, "_folds_on_device", lambda ops: False)
+        SCAN_STATS.reset()
+        ref = AnalysisRunner.do_analysis_run(
+            stream_table(table, 4096), analyzers)
     assert SCAN_STATS.device_fetches == 8  # host fold: one per chunk
 
-    monkeypatch.setenv("DEEQU_TPU_DEVICE_FOLD", "1")
     SCAN_STATS.reset()
     ctx = AnalysisRunner.do_analysis_run(stream_table(table, 4096), analyzers)
     assert SCAN_STATS.chunks_processed == 8
@@ -932,10 +1015,11 @@ def test_stream_fold_capacity_overflow_drains_and_continues(monkeypatch):
 
     table = _fold_table()
     analyzers = _fold_analyzers()
-    monkeypatch.setenv("DEEQU_TPU_DEVICE_FOLD", "0")
-    ref = AnalysisRunner.do_analysis_run(stream_table(table, 4096), analyzers)
+    with monkeypatch.context() as host_fold:
+        host_fold.setattr(se, "_folds_on_device", lambda ops: False)
+        ref = AnalysisRunner.do_analysis_run(
+            stream_table(table, 4096), analyzers)
 
-    monkeypatch.setenv("DEEQU_TPU_DEVICE_FOLD", "1")
     monkeypatch.setattr(se, "STREAM_FOLD_CAPACITY", 3)
     SCAN_STATS.reset()
     ctx = AnalysisRunner.do_analysis_run(stream_table(table, 4096), analyzers)

@@ -408,13 +408,14 @@ def test_select_kernel_validation():
             del os.environ["DEEQU_TPU_SELECT_KERNEL"]
 
 
-def test_planner_keeps_sort_for_wide_f64_columns(monkeypatch):
-    """DEEQU_TPU_COMPUTE=f64 routes columns onto the wide plane — no u32
-    key domain, so the planner must keep the sort path even when
+def test_planner_keeps_sort_for_wide_f64_columns():
+    """One value above f32_max routes its column onto the wide plane — no
+    u32 key domain, so the planner must keep the sort path even when
     resident."""
-    monkeypatch.setenv("DEEQU_TPU_COMPUTE", "f64")
     table = _two_col_table()
+    table["c0"].values[0] = 1e39
     table.persist()
+    assert table._device_cache.packer.wide_names == ["c0"]
     SCAN_STATS.reset()
     ctx = AnalysisRunner.do_analysis_run(table, [ApproxQuantile("c0", 0.5)])
     assert SCAN_STATS.device_select_passes == 0

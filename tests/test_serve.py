@@ -733,7 +733,7 @@ def test_packed_lint_decoded_plane_drift_names_member(single_device):
     ]
     table, ops, plan_ir = _packed_quantile_plan(members)
     layout = (
-        ("enc", ()), ("hi_only", ()), ("masked", ()),
+        ("enc", ()), ("masked", ()),
         ("narrow_i32", ("i",)), ("pair", ("x",)), ("wide", ()),
     )
     plan_ir = replace(plan_ir, layout=layout)
