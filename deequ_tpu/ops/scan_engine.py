@@ -270,6 +270,9 @@ class ScanStats:
         self.chunks_processed = 0
         self.rows_scanned = 0
         self.bytes_packed = 0
+        # bytes of host-packed staging planes served from a buffer the
+        # staging pool had kept mapped (_StagingPool), of bytes_packed
+        self.staging_bytes_reused = 0
         self.grouping_passes = 0
         self.kll_passes = 0
         self.scan_seconds = 0.0
@@ -885,7 +888,13 @@ class _ChunkPacker:
             n: cols[n].encoding.dictionary for n in self.enc_names
         }
 
-    def pack(self, start: int, stop: int):
+    def pack(self, start: int, stop: int, take=np.empty):
+        """Rows ``[start, stop)`` as one chunk's planes. ``take(shape,
+        dtype)`` supplies each plane's memory and says who owns it
+        afterwards: ``np.empty`` (fresh planes, the caller's for good) or a
+        ``_StagingLease.take`` (planes that go back to the staging pool).
+        What ``take`` hands out may hold another chunk's bytes, so EVERY
+        byte of every plane is written here, the tail past ``stop`` too."""
         from deequ_tpu.ops.df32 import split_pair_np
 
         chunk = self.chunk
@@ -896,7 +905,7 @@ class _ChunkPacker:
             # shipped chunk-width buffers of padding over the (slow) link
             # on every chunk — for a numeric-only table that was ~1/3 of
             # all transferred bytes
-            out = np.empty((len(names), chunk), dtype=dtype)
+            out = take((len(names), chunk), dtype)
             if n < chunk and names:
                 out[:, n:] = fill
             return out
@@ -925,8 +934,9 @@ class _ChunkPacker:
             codes[j, :n] = self.cols[name].codes[start:stop]
         for i, name in enumerate(self.enc_names):
             enc[i, :n] = self.cols[name].encoding.codes[start:stop]
-        row_valid = np.zeros(chunk, dtype=np.bool_)
+        row_valid = take((chunk,), np.bool_)
         row_valid[:n] = True
+        row_valid[n:] = False
         return values, hi, lo, narrow_i, masks, codes, row_valid, enc
 
     def unpack_vals(
@@ -1037,6 +1047,126 @@ class _ChunkPacker:
         view.col_dict = self.col_dict
         view.enc_dict = self.enc_dict
         return view
+
+
+def _staging_capacity(nbytes: int) -> int:
+    """The bytes mapped for a plane of ``nbytes``: rounded up to an eighth
+    of its power of two (at most 12.5% more, in pages never touched), so
+    that a partition some rows longer than the last one still fits the
+    buffer the last one left."""
+    granule = 1 << max(nbytes.bit_length() - 4, 0)
+    return -(-nbytes // granule) * granule
+
+
+class _StagingPool:
+    """Byte buffers that stay mapped between host-packed chunks.
+
+    A chunk's planes are far above glibc's mmap threshold, so fresh ones
+    are mapped, faulted in page by page and unmapped again for every chunk:
+    that first touch, not the split, was four fifths of ``pack``
+    (docs/ingest.md, "Staging planes: lease and release"). Free buffers are
+    kept oldest first; a request takes the smallest one that holds it and is
+    at most twice its size, else maps a new one. What comes back over
+    ``max_bytes`` pushes the oldest out, to be freed as any array is."""
+
+    def __init__(self, max_bytes: int, min_plane_bytes: int):
+        self.max_bytes = max_bytes
+        self.min_plane_bytes = min_plane_bytes
+        self._lock = threading.Lock()
+        self._free: List[np.ndarray] = []
+        self._free_bytes = 0
+
+    def free_bytes(self) -> int:
+        return self._free_bytes
+
+    def take(self, nbytes: int) -> Tuple[np.ndarray, bool]:
+        """A uint8 buffer of at least ``nbytes``, and whether it was
+        mapped before."""
+        with self._lock:
+            fits = [
+                i for i, b in enumerate(self._free)
+                if nbytes <= b.nbytes <= 2 * nbytes
+            ]
+            if fits:
+                buf = self._free.pop(
+                    min(fits, key=lambda i: self._free[i].nbytes)
+                )
+                self._free_bytes -= buf.nbytes
+                return buf, True
+        return np.empty(_staging_capacity(nbytes), dtype=np.uint8), False
+
+    def give(self, bufs: Sequence[np.ndarray]) -> None:
+        dropped = []  # unmapped after the lock is released
+        with self._lock:
+            self._free.extend(bufs)
+            self._free_bytes += sum(b.nbytes for b in bufs)
+            while self._free_bytes > self.max_bytes:
+                dropped.append(self._free.pop(0))
+                self._free_bytes -= dropped[-1].nbytes
+
+    def clear(self) -> None:
+        with self._lock:
+            self._free, self._free_bytes = [], 0
+
+
+# What the pool may retain: the planes one host-packed scan can hold at once
+# (DEFAULT_SCAN_WINDOW - 1 chunks in flight, one staged, one being packed),
+# which all come back when it ends.
+STAGING_POOL_MAX_BYTES = (DEFAULT_SCAN_WINDOW + 1) * DEFAULT_CHUNK_BYTES
+# A plane under the size cut is np.empty's. Read on the v5e's host (PR 29,
+# benchmarks/staging_probe.py: three planes at once, allocate + fill + free
+# against lock + fill of buffers kept mapped, a young heap): up to 128 KiB
+# the two are equal (7.5 against 8.5 us: malloc recycles what is under its
+# mmap threshold, and the lock is a tenth on top); from 256 KiB fresh
+# planes are mapped and faulted in every time, 817 against 20.6 us (x40),
+# x16-59 from there to 64 MiB. After large frees glibc's dynamic threshold
+# recycles some sizes up to ~28 MiB, never one over 32 MiB. At the cell's
+# 100 MB planes: split into fresh planes 246.8 ms, into mapped ones 45.2 ms.
+STAGING_POOL_MIN_PLANE_BYTES = 1 << 18
+_STAGING_POOL = _StagingPool(
+    STAGING_POOL_MAX_BYTES, STAGING_POOL_MIN_PLANE_BYTES
+)
+
+
+class _StagingLease:
+    """The pooled buffers under ONE chunk's planes, from ``pack`` until
+    that chunk's device result is known ready. ``release`` is called on
+    the success path only (after ``_block_throttle`` returned for the
+    chunk's result, or a drain fetched it or the accumulator it was
+    merged into): a ready result means the step ran, so the transfer that
+    read the planes is complete. Not after ``put``: the TPU runtime reads
+    the numpy buffer until the asynchronous transfer completes, and the
+    CPU backend may alias it without a copy. On any other path (an
+    exception, a timeout, an abandoned worker, a scan never resolved) the
+    lease is simply dropped and the garbage collector frees the planes
+    once nothing reads them, as it frees fresh ones."""
+
+    __slots__ = ("_bufs",)
+
+    def __init__(self):
+        self._bufs: List[np.ndarray] = []
+
+    def take(self, shape, dtype) -> np.ndarray:
+        dtype = np.dtype(dtype)
+        nbytes = math.prod(shape) * dtype.itemsize
+        if nbytes < _STAGING_POOL.min_plane_bytes:
+            return np.empty(shape, dtype=dtype)
+        buf, reused = _STAGING_POOL.take(nbytes)
+        if reused:
+            SCAN_STATS.staging_bytes_reused += nbytes
+        self._bufs.append(buf)
+        return buf[:nbytes].view(dtype).reshape(shape)
+
+    def release(self) -> None:
+        bufs, self._bufs = self._bufs, []
+        # a watchdog worker abandoned at its deadline belongs to a run that
+        # already failed: what it holds is dropped like that run's leases
+        if bufs and not current_watchdog_call_abandoned():
+            _STAGING_POOL.give(bufs)
+
+
+# what a resident chunk holds: nothing was packed for it
+_NO_LEASE = _StagingLease()
 
 
 class _BoundedLRU:
@@ -1780,9 +1910,13 @@ class DeferredScan:
         in_flight,
         inline: bool = False,
         scan_id: Optional[int] = None,
+        leases: Sequence[_StagingLease] = (),
     ):
         self._folder = folder
         self._in_flight = in_flight
+        # the staging planes of host-packed chunks whose results are still
+        # in flight: back to the pool once every pending result is fetched
+        self._leases = leases
         # resolved-inline scans (run_scan defer=False) drain inside the
         # attempt's own scan_attempt seam; a genuinely deferred scan
         # opens one around its BLOCKING drain segment — the wall between
@@ -1796,6 +1930,7 @@ class DeferredScan:
     def result(self) -> List[Any]:
         if not self._done:
             pending = self._in_flight
+            leases, self._leases = self._leases, ()
             self._in_flight = []
             self._done = True
             with (
@@ -1806,6 +1941,8 @@ class DeferredScan:
                 try:
                     for device_result in pending:
                         self._folder.drain(device_result)
+                    for lease in leases:
+                        lease.release()
                 except BaseException as e:  # noqa: BLE001 — a retry must
                     # not re-fold already-drained chunks into the
                     # accumulator, and even a KeyboardInterrupt mid-drain
@@ -1880,6 +2017,10 @@ def _fetch_deferred(pending: Sequence["DeferredScan"]) -> None:
     parts = device_call(
         materialize, "fetch", what="deferred scan fetch", deadline=deadline,
     )
+    for s in pending:  # every pending result is on the host: all ran
+        leases, s._leases = s._leases, ()
+        for lease in leases:
+            lease.release()
     # the batched round trip is a device->host fetch like any other —
     # attribute it so the one-fetch contract stays observable (the
     # per-scan folder.drain calls below see numpy slices and count
@@ -2504,6 +2645,9 @@ def _run_scan_once(
     put = _make_put(mesh)
 
     in_flight = []
+    # beside each result in flight, the lease on the staging planes its
+    # chunk was packed into
+    held: List[_StagingLease] = []
     # on-device partial fold: the per-chunk state vectors merge into ONE
     # device-resident accumulator (exact left-to-right chunk order), so
     # the whole scan fetches once — per-chunk fetches pay the link's
@@ -2535,11 +2679,13 @@ def _run_scan_once(
         )
         folded += 1
 
-    def after_dispatch(flat, ci) -> None:
-        """Fold or queue one chunk's result, window-bounded."""
+    def after_dispatch(flat, ci, lease=_NO_LEASE) -> None:
+        """Fold or queue one chunk's result, window-bounded. The oldest
+        result, once known ready, hands its chunk's planes back."""
         _record_kernel_passes(plan_ir, 1)
         SCAN_STATS.mesh_collectives += collectives
         in_flight.append(flat)
+        held.append(lease)
         if use_fold:
             fold_chunk(flat, ci)
             # throttle, don't drain: block on (not fetch) the oldest
@@ -2550,8 +2696,10 @@ def _run_scan_once(
                     in_flight.pop(0), f"chunk throttle (window at {ci})",
                     device_deadline,
                 )
+                held.pop(0).release()
         elif len(in_flight) >= window:
             folder.drain(in_flight.pop(0))
+            held.pop(0).release()
 
     if cache is not None:
         SCAN_STATS.resident_passes += 1
@@ -2597,7 +2745,7 @@ def _run_scan_once(
         pending_stage: List[Tuple] = []
 
         def dispatch_staged(entry) -> None:
-            device_args, ci = entry
+            device_args, ci, lease = entry
             flat = device_call(
                 lambda: step_fn(*device_args, lut_arrays),
                 "execute", what=f"chunk {ci} dispatch",
@@ -2605,13 +2753,14 @@ def _run_scan_once(
                 hook_ctx={**scan_ctx, "chunk_index": ci},
                 seam_name=dispatch_seam(),
             )
-            after_dispatch(flat, ci)
+            after_dispatch(flat, ci, lease)
 
         for ci in range(n_chunks):
             start = ci * chunk
             stop = min(start + chunk, n_rows)
+            lease = _StagingLease()
             with seam("pack", chunk=ci):
-                args = packer.pack(start, stop)
+                args = packer.pack(start, stop, take=lease.take)
             chunk_bytes = sum(a.nbytes for a in args)
             SCAN_STATS.bytes_packed += chunk_bytes
             if ci == 0:
@@ -2643,7 +2792,7 @@ def _run_scan_once(
                 bytes=chunk_bytes, overlapped=overlapped,
             )
             SCAN_STATS.record_staged(chunk_bytes, overlapped)
-            pending_stage.append((device_args, ci))
+            pending_stage.append((device_args, ci, lease))
             if len(pending_stage) > 1:
                 dispatch_staged(pending_stage.pop(0))
         while pending_stage:
@@ -2654,7 +2803,7 @@ def _run_scan_once(
         in_flight = [acc]
     deferred = DeferredScan(
         folder, in_flight, inline=not defer,
-        scan_id=scan_ctx.get("scan_id"),
+        scan_id=scan_ctx.get("scan_id"), leases=held,
     )
     if defer:
         return deferred
@@ -3158,6 +3307,8 @@ def _run_scan_stream(
 
     folder = _PartialFolder(ops, deadline=device_deadline)
     in_flight = []
+    # beside each result in flight, the lease on its chunk's staging planes
+    held: List[_StagingLease] = []
     chunk_counter = [0]
     encoded_counted = [False]
     # on-device partial fold across the WHOLE stream: instead of a fetch
@@ -3177,7 +3328,7 @@ def _run_scan_stream(
     unbuilt: set = set()
 
     def dispatch_staged(entry) -> None:
-        fn, device_args, luts, idx, plan_ir = entry
+        fn, device_args, luts, idx, plan_ir, lease = entry
         building = id(fn) in unbuilt
         unbuilt.discard(id(fn))
         flat = device_call(
@@ -3211,11 +3362,13 @@ def _run_scan_stream(
             )
             fold_state["filled"] += 1
             in_flight.append(flat)
+            held.append(lease)
             if len(in_flight) >= window:
                 _block_throttle(
                     in_flight.pop(0), "stream chunk throttle",
                     device_deadline,
                 )
+                held.pop(0).release()
             # only gather leaves grow with the chunk count: a
             # gather-free accumulator never overflows, so it folds
             # the WHOLE stream into one final fetch (and never pays
@@ -3227,8 +3380,10 @@ def _run_scan_stream(
                 drain_fold()
         else:
             in_flight.append(flat)
+            held.append(lease)
             if len(in_flight) >= window:
                 folder.drain(in_flight.pop(0))
+                held.pop(0).release()
 
     def drain_fold() -> None:
         if fold_state["acc"] is None:
@@ -3236,6 +3391,10 @@ def _run_scan_stream(
         folder.fold_plan = fold_state["plan"]
         folder.fold_filled = fold_state["filled"]
         folder.drain(fold_state["acc"])
+        # the fetched accumulator had merged every chunk dispatched so far
+        # (release is idempotent: their throttle pops find nothing left)
+        for lease in held:
+            lease.release()
         fold_state["acc"] = None
         fold_state["filled"] = 0
     layout: Optional[dict] = None
@@ -3326,8 +3485,9 @@ def _run_scan_stream(
         step_fn, shape_fn, raw_flat, shapes = prog
         for start in range(0, max(n, 1), chunk):
             stop = min(start + chunk, n)
+            lease = _StagingLease()
             with seam("pack", chunk=chunk_counter[0]):
-                args = packer.pack(start, stop)
+                args = packer.pack(start, stop, take=lease.take)
             chunk_bytes = sum(a.nbytes for a in args)
             SCAN_STATS.bytes_packed += chunk_bytes
             if sig not in linted_sigs:
@@ -3369,7 +3529,8 @@ def _run_scan_stream(
             )
             SCAN_STATS.record_staged(chunk_bytes, overlapped)
             pending_stage.append(
-                (step_fn, device_args, lut_arrays, chunk_counter[0], batch_ir)
+                (step_fn, device_args, lut_arrays, chunk_counter[0],
+                 batch_ir, lease)
             )
             chunk_counter[0] += 1
             if len(pending_stage) > 1:
@@ -3397,4 +3558,6 @@ def _run_scan_stream(
     else:
         for device_result in in_flight:
             folder.drain(device_result)
+        for lease in held:
+            lease.release()
     return folder.merged

@@ -44,7 +44,9 @@ class VerificationResult:
       last exception) — retries are no longer invisible to callers;
     - ``scan_stats`` — fused-scan transport telemetry for the run
       (``scan_passes``, ``device_fetches``, ``bytes_fetched``,
-      ``drain_wait_seconds``): the observable for the
+      ``drain_wait_seconds``; ``bytes_packed`` and, of them,
+      ``staging_bytes_reused``: planes served from the staging pool,
+      docs/ingest.md): the observable for the
       one-fetch-per-scan contract — for a grouping-free run,
       ``device_fetches`` exceeding ``scan_passes`` means per-chunk round
       trips somewhere (a non-device-foldable op keeps the host
@@ -312,6 +314,8 @@ class VerificationSuite:
                 "device_fetches",
                 "bytes_fetched",
                 "drain_wait_seconds",
+                "bytes_packed",
+                "staging_bytes_reused",
                 "budget_charges",
                 "budget_exhaustions",
             )
