@@ -17,9 +17,11 @@ JSON object:
 - ``host_span_s_by_seam``: the seams' own durations in the trace;
 - ``device_s_by_scope`` / ``device_s_by_family``: exclusive device seconds by
   the ``jax.named_scope`` of each XLA op (``deequ.<Analyzer>.<column>``),
-  read from the ``tf_op`` stat of each ``XLA Ops`` event's metadata; a
-  fusion carries ONE op's name, so a fused pass over several analyzers'
-  reductions counts under one of them.
+  read from the ``tf_op`` stat of each ``XLA Ops`` event's metadata: the
+  INNERMOST ``deequ.*`` scope of the name (``deequ.select.pass2`` inside the
+  coalesced op's ``deequ.select.<columns>``); a fusion carries ONE op's
+  name, so a fused pass over several analyzers' reductions counts under
+  one of them.
 
 It reads the benchmark's cells and its trace arithmetic
 (``chipbench.trace_reduce``) and changes nothing of either. Off a TPU it
@@ -148,12 +150,12 @@ def device_events_by_scope(trace_dir: str):
                 continue
             e = dict(_fields(event))
             op_name = op_names.get(e.get(1), "")
-            found = SCOPE.search(op_name)
+            found = SCOPE.findall(op_name)  # outermost first
             census["scoped" if found else "unscoped"] += 1
             if len(sample) < 5 and op_name not in sample:
                 sample.append(op_name)
             start = (t0_ps + e.get(2, 0)) * 1e-12
-            events.append((found.group(0) if found else "(no scope)",
+            events.append((found[-1] if found else "(no scope)",
                            start, start + e.get(3, 0) * 1e-12))
     return events, census, sample
 

@@ -55,6 +55,7 @@ from deequ_tpu.ops.kll import (
     DEFAULT_SKETCH_SIZE,
     KLLSketchState,
 )
+from deequ_tpu.obs.recorder import seam
 from deequ_tpu.ops.scan_engine import SCAN_STATS, ScanOp
 from deequ_tpu.tryresult import Failure, Success, Try
 
@@ -379,6 +380,25 @@ def _make_kll_compact(K: int, sketch_size: int):
     return compact
 
 
+#: reduction tags of one KLL chunk summary (ops/kll_device.py) plus the
+#: ``summaries`` leaf ``_counted`` adds
+_KLL_TAGS = {
+    "items": "gather",
+    "weights": "gather",
+    "count": "sum",
+    "min": "min",
+    "max": "max",
+    "summaries": "sum",
+}
+
+
+def _counted(summary: dict, xp) -> dict:
+    """A chunk summary with a ``summaries`` leaf of ones, shaped like its
+    ``count``: summed over chunks and shards, it tells the host fold how
+    many summaries were gathered into the result it folds."""
+    return {**summary, "summaries": xp.ones_like(summary["count"])}
+
+
 def _kll_scan_op(
     table: ColumnarTable,
     column: str,
@@ -406,7 +426,9 @@ def _kll_scan_op(
         rows = _rows(vals, row_valid, xp, n, pred)
         v = vals[col]
         valid = rows & v.mask
-        return chunk_summary(v.data, valid, sketch_size, n, xp, lo=v.lo)
+        return _counted(
+            chunk_summary(v.data, valid, sketch_size, n, xp, lo=v.lo), xp
+        )
 
     def update_select(vals, row_valid, xp, n):
         rows = _rows(vals, row_valid, xp, n, pred)
@@ -425,17 +447,12 @@ def _kll_scan_op(
                 "set DEEQU_TPU_SELECT_KERNEL=0 to fall back to the sort "
                 "path"
             )
-        return chunk_summary_select(
-            v.data, valid, sketch_size, n, xp, lo=v.lo
+        return _counted(
+            chunk_summary_select(v.data, valid, sketch_size, n, xp, lo=v.lo),
+            xp,
         )
 
-    tags = {
-        "items": "gather",
-        "weights": "gather",
-        "count": "sum",
-        "min": "min",
-        "max": "max",
-    }
+    tags = dict(_KLL_TAGS)
     # where-free single-column KLL ops are coalescible into one batched
     # sort (see _kll_multi_scan_op / runner._coalesce_scan_ops)
     hint = ("kll", sketch_size, column) if where is None else None
@@ -487,7 +504,9 @@ def _kll_multi_scan_op(columns: Tuple[str, ...], sketch_size: int) -> ScanOp:
                 ]
             )
             L = None
-        return chunk_summary_batched(X, M, sketch_size, n, xp, lo=L)
+        return _counted(
+            chunk_summary_batched(X, M, sketch_size, n, xp, lo=L), xp
+        )
 
     def update_select(vals, row_valid, xp, n):
         wide = [c for c in columns if vals[c].lo is None]
@@ -504,15 +523,11 @@ def _kll_multi_scan_op(columns: Tuple[str, ...], sketch_size: int) -> ScanOp:
         X = xp.stack([vals[c].data for c in columns])
         M = xp.stack([vals[c].mask & row_valid for c in columns])
         L = xp.stack([vals[c].lo for c in columns])
-        return chunk_summary_select_batched(X, M, sketch_size, n, xp, lo=L)
+        return _counted(
+            chunk_summary_select_batched(X, M, sketch_size, n, xp, lo=L), xp
+        )
 
-    tags = {
-        "items": "gather",
-        "weights": "gather",
-        "count": "sum",
-        "min": "min",
-        "max": "max",
-    }
+    tags = dict(_KLL_TAGS)
     # same huge-sketch gate as the single-column op: the batched
     # selection histograms scale O(k*256) per MEMBER column
     selectable = sketch_size <= MAX_SELECT_SKETCH_SIZE
@@ -541,6 +556,7 @@ def _kll_multi_extract(result, j: int, K: int) -> dict:
         "count": np.asarray(result["count"])[j],
         "min": np.asarray(result["min"])[j],
         "max": np.asarray(result["max"])[j],
+        "summaries": np.asarray(result["summaries"])[j],
     }
 
 
@@ -552,9 +568,12 @@ def _kll_state_from_result(
     count = int(np.asarray(result["count"]))
     if count == 0:
         return None
-    sketch = fold_summaries(
-        result["items"], result["weights"], sketch_size, shrinking_factor
-    )
+    summaries = int(np.asarray(result.get("summaries", 1)))
+    with seam("sketch_fold", summaries=summaries):
+        sketch = fold_summaries(
+            result["items"], result["weights"], sketch_size, shrinking_factor
+        )
+    SCAN_STATS.kll_summaries_folded += summaries
     if sketch is None:
         return None
     # the summary weights must account for every valid row (KLL compaction

@@ -362,6 +362,13 @@ def masked_moments(hi, lo, ok, xp):
 def masked_comoments(a_hi, a_lo, b_hi, b_lo, ok, xp):
     """Correlation co-moment chunk state (n, x_avg, y_avg, ck, x_mk, y_mk)
     (reference Correlation.scala:37-52)."""
+    import jax
+
+    with jax.named_scope("deequ.comoments"):
+        return _comoments(a_hi, a_lo, b_hi, b_lo, ok, xp)
+
+
+def _comoments(a_hi, a_lo, b_hi, b_lo, ok, xp):
     cnt = masked_count(ok, xp)
     denom = xp.maximum(cnt, 1)
     sa = masked_sum(a_hi, a_lo, ok, xp)

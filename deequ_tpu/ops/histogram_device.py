@@ -179,7 +179,8 @@ def map_under_vmap(fn):
             a if batched else jnp.broadcast_to(a, (axis_size,) + a.shape)
             for a, batched in zip(args, in_batched)
         )
-        return jax.lax.map(lambda member: mapped(*member), args), True
+        out = jax.lax.map(lambda member: mapped(*member), args)
+        return out, jax.tree.map(lambda _: True, out)
 
     return mapped
 

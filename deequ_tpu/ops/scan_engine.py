@@ -148,9 +148,11 @@ def device_foldable(op: "ScanOp") -> bool:
 
 
 def _folds_on_device(ops: Sequence["ScanOp"]) -> bool:
-    """The one rule for "fold the chunk partials on the device": every op
-    is ``device_foldable``. Otherwise the host fold (``_PartialFolder``,
-    one fetch per chunk) is the only fold that can compact mid-scan."""
+    """The rule for "fold the chunk partials on the device" wherever the
+    scan is not bounded beforehand: every op is ``device_foldable``.
+    Otherwise the host fold (``_PartialFolder``, one fetch per chunk) is
+    the only fold that can compact mid-scan. (A RESIDENT table folds on
+    the device whatever its ops: ``_run_scan_once``.)"""
     return all(device_foldable(op) for op in ops)
 
 
@@ -275,6 +277,10 @@ class ScanStats:
         self.staging_bytes_reused = 0
         self.grouping_passes = 0
         self.kll_passes = 0
+        # chunk (or shard) summaries the host folded into KLL sketches
+        # (analyzers/sketches._kll_state_from_result, the sketch_fold
+        # seam): columns x resident chunks on the selection path
+        self.kll_summaries_folded = 0
         self.scan_seconds = 0.0
         self.resident_passes = 0
         self.bytes_resident = 0
@@ -2269,10 +2275,11 @@ def run_scan(
     DEVICE (left-to-right chunk order) and the whole pass performs
     exactly one device->host fetch of the final flat state vector — the
     one-fetch-per-scan contract, observable as
-    ``SCAN_STATS.device_fetches``. The ops alone select the fold
-    (``_folds_on_device``): one with a ``compact()`` hook, or a gather
-    leaf past ``MAX_FOLD_CAPACITY`` chunks, keeps the host fold (one
-    fetch per chunk).
+    ``SCAN_STATS.device_fetches``. The ops and the residency select the
+    fold: off a resident table an op with a ``compact()`` hook
+    (``_folds_on_device``), and anywhere a gather leaf past
+    ``MAX_FOLD_CAPACITY`` chunks, keeps the host fold (one fetch per
+    chunk).
 
     ``window`` bounds in-flight chunks (pipelined dispatch); default 3,
     overridable process-wide via ``DEEQU_TPU_SCAN_WINDOW``.
@@ -2662,7 +2669,10 @@ def _run_scan_once(
     use_fold = (
         n_chunks > 1
         and (not has_gather or n_chunks <= MAX_FOLD_CAPACITY)
-        and _folds_on_device(ops)
+        # a resident table's chunks are what HBM holds of it: what its
+        # scan gathers (the KLL summaries) is bounded before it starts
+        # and needs no compaction on the way, so it fetches once too
+        and (cache is not None or _folds_on_device(ops))
     )
     plan: Optional[_DeviceFoldPlan] = None
     acc = None

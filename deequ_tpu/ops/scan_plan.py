@@ -101,9 +101,10 @@ class ScanPlan:
     ``ops`` are the concrete ScanOps the executor traces (variant
     substitutions applied, cache keys rewritten so traced-program caches
     can never serve a sort-path program to a selection-path scan or vice
-    versa). ``sort_ops``/``select_ops`` count ops per chunk dispatch that
-    run a device sort / a histogram selection — the executor multiplies
-    by chunks processed into ScanStats.
+    versa). ``sort_ops``/``select_ops`` count the column summaries per chunk
+    dispatch that a device sort / a histogram selection computes (a
+    coalesced KLL op counts each member column) — the executor
+    multiplies by chunks processed into ScanStats.
 
     The remaining fields are the plan's DECLARED contracts — the metadata
     the static plan lint (deequ_tpu/lint/plan_lint.py) checks the traced
@@ -418,6 +419,12 @@ def _plane_routed(op, route):
     return routed
 
 
+def _summary_members(op) -> int:
+    """Column summaries one dispatch of a KLL op computes: a coalesced
+    op (``_kll_multi_scan_op``) sorts or selects each member column."""
+    return max(len(op.select_columns), 1)
+
+
 def _bind_hist_variant(update, variant: str):
     """Wrap a resolved update so the ambient histogram variant is bound
     exactly while THIS op's portion of the program traces — the traced
@@ -498,11 +505,11 @@ def plan_scan_ops(
                     cache_key=key,
                 )
             )
-            n_select += 1
+            n_select += _summary_members(op)
         else:
             resolved.append(op)
             if op.sorts_chunk:
-                n_sort += 1
+                n_sort += _summary_members(op)
     if n_select and not n_sort:
         variant = "select"
     elif n_sort and not n_select:

@@ -2,9 +2,7 @@
 range-narrowing instead of a full sort.
 
 ``ops/kll_device.chunk_summary_batched`` pins each KLL stratum boundary by
-sorting the whole chunk (one vmapped XLA sort per pass — the one workload,
-BASELINE config 3, where the round-5 engine lost to a CPU core on
-*compute*; not measured on this machine yet, ROADMAP A2). But the summary only
+sorting the whole chunk (one vmapped XLA sort per pass). But the summary only
 ever READS k+W rank positions out of the sorted array; a comparison sort
 computes n*log(n) order information to answer k+W rank queries. CPU
 engines answer the same queries with introselect in O(n); the accelerator
@@ -18,25 +16,48 @@ equivalent built here is a *batched multi-rank radix selection*:
      sort path pads them with literal +inf, so they join the same tie
      group and ranks resolve to identical values.
   2. Narrow every target rank simultaneously with THREE histogram
-     passes over the 16+8+8-bit radix digits. Each pass is one fused
-     ``segment_sum``/bincount dispatch covering all columns and all
-     targets at once: an element's segment row comes from a dense
-     prefix->row lookup table (scattered from the <= R active target
-     prefixes — no sorted structure of the DATA ever exists), its
-     bucket from its own next radix digit; each target then walks the
-     cumulative counts of its row to pick the bucket holding its rank,
-     narrowing its [lo, hi) key range by the digit width. After the
-     third pass every stratum midpoint and quantile rank is pinned to
-     the exact 32-bit key at that rank.
+     passes over the 16+8+8-bit radix digits: per target the counts of
+     the next digit among the elements under the target's prefix; each
+     target then walks the cumulative counts of its row to pick the
+     bucket holding its rank, narrowing its [lo, hi) key range by the
+     digit width. After the third pass every stratum midpoint and
+     quantile rank is pinned to the exact 32-bit key at that rank. No
+     sorted structure of the DATA ever exists.
   3. Reconstruct the f64 item per target: the selected f32 hi value
      plus a deterministically-chosen lo-plane rider (tie rule below),
      and extract the < w exact-remainder elements by threshold +
-     stable tie-split + scatter compaction.
+     stable tie-split + compaction.
 
-Passes touch each element O(1) times (shift/gather/scatter-add in native
-u32/i32 ops — no f64 emulation, no u64: XLA:TPU rejects f64->u64
-bitcasts, ops/hll.py). The output contract is IDENTICAL to
-``kll_device.chunk_summary``: the same {items, weights, count, min, max}
+Two formulations compute step 2 and the rider, bit for bit alike; the
+plan's histogram variant (ops/histogram_device.py) picks one:
+
+- ``"onehot"`` (what ``resolve_hist_variant`` gives an accelerator):
+  :func:`_multirank_onehot`. Every pass is a blocked MATMUL over the
+  rows: an element's membership in each target's prefix is a broadcast
+  compare, a (block, R) 0/1 plane, and the counts are its transpose
+  times the one-hot of the digit on the MXU. No per-element gather or
+  scatter anywhere.
+- ``"scatter"`` / ``"pallas"`` (a CPU backend's default; the A/B hatch):
+  :func:`_multirank_lut`. An element's target row comes from a dense
+  prefix->row lookup table (scattered from the <= R active target
+  prefixes), the counts from a bincount under that variant, the rider
+  from one scatter-min.
+
+Read on the TPU v5e (PERF.md section 6, PR 30; one column of one resident
+chunk, n = 4,772,185, k = 256): the LUT formulation with one-hot
+bincounts 492.7 ms — of a 86 s suite over 50 columns x 3 chunks, 69.8%
+was the remainder's n-element f64 scatter compaction, 17.5% the two LUT
+gathers, 7.2% the scatter-min, 3.8% the three histogram passes; the sort
+summary 81.7 ms (x6 faster than the selection it was to replace); the
+matmul formulation with the compaction as a GATHER (W binary searches
+over the running count of remainder elements) 41.4 ms. On the TPU a
+gather or scatter walks its n elements one after another (32-43 ms a
+4.77M-element pass, 364 ms in f64); a matmul pass costs 5-12 ms.
+
+Passes touch each element O(1) times in native u32/i32 ops — no f64
+emulation, no u64: XLA:TPU rejects f64->u64 bitcasts, ops/hll.py. The
+output contract is IDENTICAL to ``kll_device.chunk_summary``: the same
+{items, weights, count, min, max}
 summary with the same strata/remainder layout, so ``fold_summaries`` and
 the whole KLL merge algebra (host sketches, persisted states, incremental
 merges) are untouched.
@@ -58,30 +79,36 @@ kernel determinism"):
   element (the summary is order-insensitive; ``fold_summaries`` sorts
   per level).
 
-jnp-only: the histogram passes are scatter/gather programs with no numpy
-mirror here — the host reference for tests is the sort path itself.
+jnp-only: no numpy mirror here — the host reference for tests is the sort
+path itself.
 """
 
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
+from deequ_tpu.ops.histogram_device import (
+    _plane_dtype,
+    current_hist_variant,
+    map_under_vmap,
+)
 from deequ_tpu.ops.kll_device import strata_capacity, strata_weight
 
-# radix digit plan: 16 bits in the first pass (a plain bincount — every
-# target still shares the single full-range interval), then 8+8 with
-# dense prefix->row LUTs (2^16 and (R*256)-entry tables). Three passes
-# pin all 32 key bits.
+# radix digit plan: 16 bits in the first pass (every target still shares
+# the single full-range interval), then 8+8 under each target's own
+# prefix. Three passes pin all 32 key bits.
 _PASS1_BITS = 16
 _PASS_BITS = 8
 _B = 1 << _PASS_BITS
 
 # largest sketch size the selection kernel accepts: the pass-2/3
-# histograms and LUTs are O(k * 256) i32 PER COLUMN (~17MB at this cap,
-# x50 coalesced columns under vmap) — buffers chunk bisection cannot
-# shrink, unlike the sort path whose footprint is O(n). Ops above the
-# cap keep the sort path (the analyzers attach no selection variant).
+# histograms (and the LUT formulation's tables) are O(k * 256) i32 per
+# column (~17MB at this cap) — buffers chunk bisection cannot shrink,
+# unlike the sort path whose footprint is O(n) — and the matmul
+# formulation's work grows with k (R * 256 MACs a row a pass). Ops above
+# the cap keep the sort path (the analyzers attach no selection variant).
 # Default sketches sit far below (KLLSketch k=2048, ApproxQuantile's
 # default relative_error=0.01 gives k=256); only extreme precision
 # requests (relative_error below ~1.4e-4, i.e. k = 2.3/eps > 16384)
@@ -118,13 +145,14 @@ def inverse_monotone_u32(u, xp):
 
 
 def _segment_count(seg, num_segments: int, xp):
-    """Histogram of i32 segment ids under the routed kernel tier
-    (ops/histogram_device.py): the scatter variant traces ``at[].add``
-    exactly as before round 14 (``at[].add`` rather than segment_sum —
-    same scatter, but without materializing the all-ones operand,
-    measured ~2x faster on CPU); the one-hot/pallas variants replace
-    the scatter with a blocked matmul / Mosaic grid kernel. The ambient
-    variant is bound by the planner around the whole selection update
+    """Histogram of i32 segment ids for the LUT formulation, under the
+    routed kernel tier (ops/histogram_device.py): the scatter variant
+    traces ``at[].add`` exactly as before round 14 (``at[].add`` rather
+    than segment_sum — same scatter, but without materializing the
+    all-ones operand, measured ~2x faster on CPU); the pallas variant
+    replaces the scatter with a Mosaic grid kernel (the one-hot variant
+    never gets here: ``_select_u32_multirank``). The ambient variant is
+    bound by the planner around the whole selection update
     (ops/scan_plan._bind_hist_variant), so all three passes of one
     summary trace the SAME kernel shape — the plan-hist-scatter lint
     contract."""
@@ -148,6 +176,156 @@ def _bucket_of_rank(tcum, rank_rem, xp):
 
 
 def _select_u32_multirank(u, ranks, xp):
+    """The multi-rank selection under the AMBIENT histogram variant
+    (ops/histogram_device.py): ``"onehot"`` is the blocked matmul
+    formulation (:func:`_multirank_onehot`, what an accelerator plan
+    resolves to), anything else the LUT formulation
+    (:func:`_multirank_lut`) with its bincounts under that variant. Both
+    return the same ``(keys, tie_rank, min_tie_index)``, bit for bit."""
+    if current_hist_variant() == "onehot":
+        return _multirank_onehot(u, ranks)
+    return _multirank_lut(u, ranks, xp)
+
+
+#: rows a block of the one-hot passes holds at most (a block's f32 counts
+#: stay far below 2^24, so they are exact), and the elements its two
+#: planes may hold together: the (block, R) membership plane grows with
+#: the sketch, so the block shrinks (2^16 rows up to R = 768)
+_ONEHOT_BLOCK_ROWS = 1 << 16
+_ONEHOT_PLANE_ELEMENTS = 1 << 26
+
+
+def _row_blocks(a, block: int):
+    """``a`` as ``(blocks, block)`` rows, the tail zero-padded."""
+    blocks = -(-a.shape[0] // block)
+    pad = blocks * block - a.shape[0]
+    if pad:
+        a = jnp.concatenate([a, jnp.zeros((pad,), a.dtype)])
+    return a.reshape(blocks, block)
+
+
+def _multirank_onehot_body(u, ranks):
+    """:func:`_multirank_lut`'s result with no per-element gather or
+    scatter: every pass is a blocked matmul over the rows.
+
+    A histogram pass needs, per target, the counts of the next radix
+    digit among the elements that share the target's prefix. The LUT
+    formulation finds an element's target row by a gather through a
+    dense prefix table and counts by a scatter-add; on the TPU each is a
+    serial walk over the n elements. Here an element's membership in
+    EVERY target's prefix is one broadcast compare, a ``(block, R)`` 0/1
+    plane, and the counts are that plane's transpose times the one-hot
+    of the digit, ``(R, 256)`` on the MXU with f32 accumulation (exact:
+    products are 0/1, a block holds 2^16 rows). Targets that share a
+    prefix get equal rows, so nothing is deduplicated and no table is
+    built. The tie rider (the smallest index among the elements equal to
+    each selected key) is a fourth pass of the same shape: a masked min
+    over the block's rows.
+    """
+    R = ranks.shape[0]
+    n = u.shape[0]
+    plane = _plane_dtype(jnp)
+    fits = _ONEHOT_PLANE_ELEMENTS // (R + _B)
+    block = max(_B, min(
+        _ONEHOT_BLOCK_ROWS,
+        1 << (fits.bit_length() - 1),  # the power of two at or under it
+        1 << (max(n, 1) - 1).bit_length(),
+    ))
+    rows_u = _row_blocks(u, block)
+    # each block with the index of its first row: a row's index says
+    # whether it is padding (>= n: in no target's prefix, tied with no key)
+    blocks = (rows_u, jnp.arange(rows_u.shape[0], dtype=jnp.int32) * block)
+    in_block = jnp.arange(block, dtype=jnp.int32)
+    digits = jnp.arange(_B, dtype=jnp.int32)[None, :]
+    rank_rem = ranks.astype(jnp.int32)
+
+    def histogram(rows: int, member_and_digit):
+        """``(rows, 256)`` counts: per block ``member^T @ one_hot(digit)``."""
+
+        def add_block(counts, ub_start):
+            ub, start = ub_start
+            member, digit = member_and_digit(ub, start + in_block < n)
+            onehot = (digit[:, None] == digits).astype(plane)
+            block_counts = jnp.matmul(
+                member.astype(plane).T, onehot,
+                preferred_element_type=jnp.float32,
+            )
+            return counts + block_counts.astype(jnp.int32), None
+
+        counts, _ = jax.lax.scan(
+            add_block, jnp.zeros((rows, _B), jnp.int32), blocks
+        )
+        return counts
+
+    # -- pass 1: the leading 16 bits as two 8-bit digits, one interval ---
+    def leading(ub, live):
+        d1 = (ub >> jnp.uint32(_PASS1_BITS)).astype(jnp.int32)
+        return ((d1 >> _PASS_BITS)[:, None] == digits) & live[:, None], (
+            d1 & (_B - 1)
+        )
+
+    with jax.named_scope("deequ.select.pass1"):
+        hist1 = histogram(_B, leading).reshape(-1)
+    cum1 = jnp.cumsum(hist1)
+    pfx = jnp.searchsorted(cum1, rank_rem, side="right").astype(jnp.int32)
+    below = jnp.where(pfx > 0, cum1[jnp.maximum(pfx - 1, 0)], 0)
+    rank_rem = rank_rem - below.astype(jnp.int32)
+
+    # -- pass 2: the elements under each target's 16-bit prefix ---------
+    def under_prefix(ub, live):
+        d1 = (ub >> jnp.uint32(_PASS1_BITS)).astype(jnp.int32)
+        d2 = ((ub >> jnp.uint32(_PASS_BITS)) & jnp.uint32(_B - 1))
+        return (d1[:, None] == pfx[None, :]) & live[:, None], (
+            d2.astype(jnp.int32)
+        )
+
+    with jax.named_scope("deequ.select.pass2"):
+        hist2 = histogram(R, under_prefix)
+    bucket2, below2 = _bucket_of_rank(
+        jnp.cumsum(hist2, axis=1), rank_rem, jnp
+    )
+    rank_rem = rank_rem - below2
+    pfx24 = pfx * _B + bucket2
+
+    # -- pass 3: the elements under each target's 24-bit prefix ---------
+    def under_prefix24(ub, live):
+        d = (ub >> jnp.uint32(_PASS_BITS)).astype(jnp.int32)
+        return (d[:, None] == pfx24[None, :]) & live[:, None], (
+            (ub & jnp.uint32(_B - 1)).astype(jnp.int32)
+        )
+
+    with jax.named_scope("deequ.select.pass3"):
+        hist3 = histogram(R, under_prefix24)
+    bucket3, below3 = _bucket_of_rank(
+        jnp.cumsum(hist3, axis=1), rank_rem, jnp
+    )
+    rank_rem = rank_rem - below3
+    keys = (pfx24.astype(jnp.uint32) << jnp.uint32(_PASS_BITS)) | (
+        bucket3.astype(jnp.uint32)
+    )
+
+    # -- tie rider: the smallest index among each key's elements --------
+    def min_index(best, ub_start):
+        ub, start = ub_start
+        index = jnp.minimum(start + in_block, n)  # padding: past the end
+        tied = ub[:, None] == keys[None, :]
+        return jnp.minimum(
+            best, jnp.where(tied, index[:, None], n).min(axis=0)
+        ), None
+
+    with jax.named_scope("deequ.select.rider"):
+        first, _ = jax.lax.scan(
+            min_index, jnp.full((R,), n, jnp.int32), blocks
+        )
+    return keys, rank_rem, jnp.minimum(first, n - 1)
+
+
+# one program whatever vmaps it: the batched one-hot matmul is the one
+# XLA:TPU miscompiles (histogram_device.map_under_vmap)
+_multirank_onehot = map_under_vmap(_multirank_onehot_body)
+
+
+def _multirank_lut(u, ranks, xp):
     """Resolve ``ranks`` (R target rank positions, i32, each in [0, n))
     against the ascending order of ``u`` ((n,) u32 keys): returns
 
@@ -221,12 +399,13 @@ def _select_u32_multirank(u, ranks, xp):
     # tie rider source: after pass 3 a (row3, digit3) cell holds exactly
     # one distinct key, so the pass-3 segment ids double as tie-group ids
     # — one scatter-min finds each target's minimum-index tie element
-    min_cell = (
-        xp.full((R * _B + 1,), n, dtype=xp.int32).at[seg3].min(idx)
-    )
-    min_tie_index = xp.minimum(
-        min_cell[lut3[id3_t] * _B + bucket3], n - 1
-    )
+    with jax.named_scope("deequ.select.rider"):
+        min_cell = (
+            xp.full((R * _B + 1,), n, dtype=xp.int32).at[seg3].min(idx)
+        )
+        min_tie_index = xp.minimum(
+            min_cell[lut3[id3_t] * _B + bucket3], n - 1
+        )
     return keys, rank_rem, min_tie_index
 
 
@@ -253,10 +432,11 @@ def chunk_summary_select(x, valid, sketch_size: int, local_n: int, xp, lo):
     # valid NaNs present (numpy sort order puts NaNs after the padding)
     # ranks in [r0, m) can legitimately resolve to padding +inf, and the
     # selection must reproduce exactly that
-    u = xp.where(valid, monotone_u32(x, xp), monotone_u32(
-        xp.asarray(np.float32(np.inf)), xp
-    ))
-    lo_plane = xp.where(valid, lo, xp.asarray(np.float32(0.0)))
+    with jax.named_scope("deequ.select.keys"):
+        u = xp.where(valid, monotone_u32(x, xp), monotone_u32(
+            xp.asarray(np.float32(np.inf)), xp
+        ))
+        lo_plane = xp.where(valid, lo, xp.asarray(np.float32(0.0)))
 
     m = valid.sum()
     w, n_strata = strata_weight(m, k, xp)
@@ -293,29 +473,37 @@ def chunk_summary_select(x, valid, sketch_size: int, local_n: int, xp, lo):
     # Both bounds are needed: rows the sort path pads with +inf can sit
     # at ranks >= m inside the same +inf tie group the remainder's top
     # ranks occupy, so "everything above the threshold" would overrun.
-    v_b, v_t = keys[k], keys[k + 1]
-    j0, j1 = tie_rank[k], tie_rank[k + 1]
-    has_rem = r0 < m.astype(xp.int32)
-    tie_b = u == v_b
-    tie_t = u == v_t
-    pos_b = xp.cumsum(tie_b.astype(xp.int32)) - 1
-    pos_t = xp.cumsum(tie_t.astype(xp.int32)) - 1
-    above = (u > v_b) | (tie_b & (pos_b >= j0))
-    below = (u < v_t) | (tie_t & (pos_t <= j1))
-    rem = has_rem & above & below
-    slot = xp.cumsum(rem.astype(xp.int32)) - 1
-    # item values come from the PADDED plane (invalid rows read as +inf,
-    # lo zeroed) — the exact array the sort path gathers from
-    x64 = xp.where(
-        valid, x, xp.asarray(np.float32(np.inf))
-    ).astype(xp.float64) + lo_plane.astype(xp.float64)
-    items_r = (
-        xp.zeros((W,), dtype=xp.float64)
-        .at[xp.where(rem, slot, W)]
-        .set(x64, mode="drop")
-    )
-    n_rem = xp.where(has_rem, m.astype(xp.int32) - r0, 0)
-    weights_r = xp.where(xp.arange(W, dtype=xp.int32) < n_rem, 1, 0)
+    with jax.named_scope("deequ.select.extract"):
+        v_b, v_t = keys[k], keys[k + 1]
+        j0, j1 = tie_rank[k], tie_rank[k + 1]
+        has_rem = r0 < m.astype(xp.int32)
+        tie_b = u == v_b
+        tie_t = u == v_t
+        pos_b, pos_t = xp.cumsum(
+            xp.stack([tie_b, tie_t]).astype(xp.int32), axis=1
+        ) - 1
+        above = (u > v_b) | (tie_b & (pos_b >= j0))
+        below = (u < v_t) | (tie_t & (pos_t <= j1))
+        rem = has_rem & above & below
+        # compaction by GATHER: slot s holds the element at which the
+        # running count of remainder elements first reaches s + 1 — W
+        # binary searches over the running count, where a scatter walks
+        # all n elements to drop all but < w of them
+        source = xp.minimum(
+            xp.searchsorted(
+                xp.cumsum(rem.astype(xp.int32)),
+                xp.arange(1, W + 1, dtype=xp.int32),
+                side="left", method="scan",
+            ),
+            x.shape[0] - 1,
+        )
+        # item values come from the PADDED plane (invalid rows read as
+        # +inf, lo zeroed) — the exact array the sort path gathers from
+        items_r = xp.where(
+            valid, x, xp.asarray(np.float32(np.inf))
+        )[source].astype(xp.float64) + lo_plane[source].astype(xp.float64)
+        n_rem = xp.where(has_rem, m.astype(xp.int32) - r0, 0)
+        weights_r = xp.where(xp.arange(W, dtype=xp.int32) < n_rem, 1, 0)
 
     items = xp.concatenate([items_s, items_r])
     weights = xp.concatenate([weights_s, weights_r])
@@ -334,15 +522,14 @@ def chunk_summary_select(x, valid, sketch_size: int, local_n: int, xp, lo):
 
 def chunk_summary_select_batched(X, M, sketch_size: int, local_n: int, xp, lo):
     """K columns at once: (K, n) values + (K, n) validity + (K, n) lo
-    planes -> summaries with a leading K axis. The histogram passes of
-    every column run in ONE vmapped dispatch per pass (a (K, R*B) fused
-    bincount), the batched analogue of ``chunk_summary_batched``'s
-    vmapped sort — at O(passes * n) work instead of O(n log n)
-    comparison sorting."""
-    import jax
-
-    return jax.vmap(
-        lambda xc, vc, lc: chunk_summary_select(
-            xc, vc, sketch_size, local_n, xp, lo=lc
-        )
-    )(X, M, lo)
+    planes -> summaries with a leading K axis. The members run one after
+    another (``lax.map``): a member's passes are whole-device programs
+    already, and its temporaries (the key plane, the running counts, the
+    blocks' one-hot planes) are n-sized — batched over K = 50 members
+    they stood beside a resident table as tens of GB."""
+    return jax.lax.map(
+        lambda member: chunk_summary_select(
+            member[0], member[1], sketch_size, local_n, xp, lo=member[2]
+        ),
+        (X, M, lo),
+    )

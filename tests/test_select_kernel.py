@@ -254,6 +254,78 @@ def test_monotone_u32_roundtrip_total_order():
     assert np.array_equal(back.view(np.uint32), vals.view(np.uint32))
 
 
+# -- the matmul formulation (what an accelerator plan resolves to) ---------
+
+
+def _select_under(variant, values, mask, k, batched=False):
+    """``chunk_summary_select`` traced under one histogram variant."""
+    from deequ_tpu.ops.histogram_device import active_hist_variant
+
+    n = values.shape[-1]
+    hi, lo = split_pair_np(np.asarray(values, dtype=np.float64).ravel())
+    hi, lo = hi.reshape(values.shape), lo.reshape(values.shape)
+
+    def summary(x, v, l):
+        with active_hist_variant(variant):
+            one = lambda xc, vc, lc: chunk_summary_select(  # noqa: E731
+                xc, vc, k, n, jnp, lo=lc
+            )
+            return jax.vmap(one)(x, v, l) if batched else one(x, v, l)
+
+    return {key: np.asarray(v) for key, v in jax.jit(summary)(
+        hi, mask, lo).items()}
+
+
+@pytest.mark.parametrize("case", sorted(_ADVERSARIAL))
+def test_matmul_formulation_matches_sort_reference_adversarial(case):
+    """The one-hot variant's passes are blocked matmuls with no LUT, no
+    scatter-min: the same summary as the sort path on every adversarial
+    input, and as the LUT formulation bit for bit."""
+    values, mask = _ADVERSARIAL[case]
+    if mask is None:
+        mask = np.ones(len(values), bool)
+    k = 64
+    a, lut = _summaries(values, mask, k)
+    b = _select_under("onehot", values, mask, k)
+    _assert_summary_equal(a, b, k)
+    for key in lut:
+        assert np.array_equal(lut[key], b[key], equal_nan=True), key
+
+
+@pytest.mark.parametrize("k", [256, 1024])
+def test_matmul_formulation_over_several_blocks(k):
+    """More rows than a block holds and no block size dividing them, fat
+    tie groups across block boundaries, nulls: bit-equal to the LUT
+    formulation (k = 1024 shrinks the block: the membership plane grows
+    with the sketch)."""
+    rng = np.random.default_rng(77)
+    n = 150_001
+    values = rng.normal(100.0, 5.0, n)
+    values[rng.integers(0, n, 4000)] = values[7]  # one fat tie group
+    values[::5] = np.round(values[::5], 1)        # many small ones
+    mask = rng.random(n) > 0.01
+    lut = _select_under("scatter", values, mask, k)
+    mm = _select_under("onehot", values, mask, k)
+    for key in lut:
+        assert np.array_equal(lut[key], mm[key], equal_nan=True), key
+    assert int(mm["count"]) == int(mask.sum())
+
+
+def test_matmul_formulation_under_vmap_maps_its_members():
+    """``vmap`` of the one-hot formulation runs the unbatched program per
+    member (``map_under_vmap``: the batched one-hot matmul is the one
+    XLA:TPU miscompiles): equal to the members run alone."""
+    rng = np.random.default_rng(78)
+    values = rng.normal(0.0, 3.0, (3, 9_001))
+    mask = rng.random((3, 9_001)) > 0.05
+    batched = _select_under("onehot", values, mask, 64, batched=True)
+    for j in range(3):
+        alone = _select_under("onehot", values[j], mask[j], 64)
+        for key in alone:
+            assert np.array_equal(batched[key][j], alone[key],
+                                  equal_nan=True), (j, key)
+
+
 # -- KLL merge algebra --------------------------------------------------
 
 

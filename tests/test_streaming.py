@@ -446,7 +446,8 @@ def test_kll_multi_compact_preserves_extraction_layout():
         items[j::K] = rng.normal(100.0 * (j + 1), 5.0, (chunks, T))
     result = {"items": items, "weights": weights,
               "count": np.full(K, chunks * T * 2.0),
-              "min": items.min(axis=0), "max": items.max(axis=0)}
+              "min": items.min(axis=0), "max": items.max(axis=0),
+              "summaries": np.full(K, chunks)}
 
     compacted = _make_kll_compact(K, k)(result)
     assert compacted["items"].shape[-1] == T  # trailing dim preserved
