@@ -50,9 +50,26 @@ was the remainder's n-element f64 scatter compaction, 17.5% the two LUT
 gathers, 7.2% the scatter-min, 3.8% the three histogram passes; the sort
 summary 81.7 ms (x6 faster than the selection it was to replace); the
 matmul formulation with the compaction as a GATHER (W binary searches
-over the running count of remainder elements) 41.4 ms. On the TPU a
-gather or scatter walks its n elements one after another (32-43 ms a
-4.77M-element pass, 364 ms in f64); a matmul pass costs 5-12 ms.
+over the running count of remainder elements) 41.4 ms (27.3 since PR
+31, below). On the TPU a gather or scatter walks its n elements one
+after another (32-43 ms a 4.77M-element pass, 364 ms in f64); a matmul
+pass costs 5-12 ms.
+
+Pass 1 (PERF.md section 6, PR 31): inside the 50-column step of the
+benchmark's cell ``quantiles12m50.qscan`` it took 19.7 ms a column
+summary where passes 2 and 3, the same 65,536 MACs a row, took 4.67
+and 4.83. XLA:TPU picks the emitter of a matmul fusion by the
+membership plane's row count and the size of its windows by whether it
+sees an iota behind the operand: 256 rows against an iota on BOTH sides
+(what pass 1 was) got a transposing emitter over 512 small windows a
+block. Now the 256 values of the top digit reach the compare as the
+prefixes of passes 2-3 do, an array of the run behind
+``lax.optimization_barrier``, padded to ``_PASS1_ROWS`` = 264 rows (off
+a multiple of 128; the padding matches no row): the compiler builds
+pass 1 as it builds pass 2, to the cycle of its own estimate, and on the
+chip it costs 4.67 ms. The histogram is the same, bit for bit (0/1
+products, f32 accumulation per block of at most 2^16 rows, integer
+fold), and so is every summary.
 
 Passes touch each element O(1) times in native u32/i32 ops — no f64
 emulation, no u64: XLA:TPU rejects f64->u64 bitcasts, ops/hll.py. The
@@ -195,6 +212,15 @@ _ONEHOT_BLOCK_ROWS = 1 << 16
 _ONEHOT_PLANE_ELEMENTS = 1 << 26
 
 
+#: rows of pass 1's membership plane: the 256 values of the top digit and
+#: one sublane tile of padding that matches no row. XLA:TPU picks a matmul
+#: fusion's emitter by this count (at a multiple of 128 a transposing one,
+#: twice as slow here) and its windows by whether it sees an iota behind
+#: the operand (small ones, twice as slow again): module docstring,
+#: tests/test_chip_compile.py
+_PASS1_ROWS = _B + 8
+
+
 def _row_blocks(a, block: int):
     """``a`` as ``(blocks, block)`` rows, the tail zero-padded."""
     blocks = -(-a.shape[0] // block)
@@ -258,14 +284,19 @@ def _multirank_onehot_body(u, ranks):
         return counts
 
     # -- pass 1: the leading 16 bits as two 8-bit digits, one interval ---
+    # the top digit's values reach the compare as the prefixes of passes
+    # 2-3 do, an array of the run and not an iota the compiler sees, with
+    # padding that matches no row (``_PASS1_ROWS``); the counts are the same
     def leading(ub, live):
         d1 = (ub >> jnp.uint32(_PASS1_BITS)).astype(jnp.int32)
-        return ((d1 >> _PASS_BITS)[:, None] == digits) & live[:, None], (
+        return ((d1 >> _PASS_BITS)[:, None] == heads[None, :]) & live[:, None], (
             d1 & (_B - 1)
         )
 
     with jax.named_scope("deequ.select.pass1"):
-        hist1 = histogram(_B, leading).reshape(-1)
+        heads = jnp.arange(_PASS1_ROWS, dtype=jnp.int32)
+        heads = jax.lax.optimization_barrier(jnp.where(heads < _B, heads, -1))
+        hist1 = histogram(_PASS1_ROWS, leading)[:_B].reshape(-1)
     cum1 = jnp.cumsum(hist1)
     pfx = jnp.searchsorted(cum1, rank_rem, side="right").astype(jnp.int32)
     below = jnp.where(pfx > 0, cum1[jnp.maximum(pfx - 1, 0)], 0)

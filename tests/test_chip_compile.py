@@ -387,6 +387,25 @@ def test_selection_kernel_compiles_at_config3_width(as_tpu, one_chip):
     # gathers W slots
     assert "convolution" in text and not re.search(r"\bscatter\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    # pass 1 is the program passes 2-3 are: its matmul fusion reads the
+    # leading digits as an operand (the barrier held through the TPU
+    # pipeline) and the compiler builds all three with one emitter. At 256
+    # rows against an iota on both sides it picked another, four times
+    # slower on the chip (PERF.md section 6, PR 31)
+    computations, emitters = text.split("\n}\n"), {}
+    for scope in ("pass1", "pass2", "pass3"):
+        (computation,) = [
+            c for c in computations
+            if re.search(rf"convolution\(.*deequ\.select\.{scope}/", c)
+        ]
+        name = re.match(r"(%\S+) \(", computation.lstrip()).group(1)
+        (call,) = [
+            line for line in text.splitlines() if f"calls={name}," in line
+        ]
+        emitters[scope] = re.search(r'"emitter":"(\w+)"', call).group(1)
+        if scope == "pass1":
+            assert re.search(r"= s32\[\d+\]\S* parameter\(", computation)
+    assert emitters["pass1"] == emitters["pass2"] == emitters["pass3"], emitters
 
 
 def test_coalesced_tenant_step_compiles(topo, one_chip, monkeypatch):

@@ -159,6 +159,32 @@ _ADVERSARIAL = {
         ),
         _RNG.random(2000) > 0.3,
     ),
+    # pass 1's whole digit range in one column: keys whose top 8 bits are
+    # 0x00 (-inf, the largest negatives) and 0xFF (+inf, every NaN), the
+    # denormals on both sides of the sign flip (0x7F / 0x80) between them
+    "top_byte_00_and_ff": (
+        np.concatenate([
+            np.full(40, -np.nan), np.full(40, np.nan),
+            np.full(60, -np.inf), np.full(60, np.inf),
+            _grid(-_RNG.uniform(1e38, 3.4e38, 400)),
+            _grid(_RNG.uniform(1e38, 3.4e38, 400)),
+            _grid(_RNG.integers(-300, 300, 500) * 1.401298464324817e-45),
+            _grid(_RNG.normal(0, 1, 1500)),
+        ])[_RNG.permutation(3000)],
+        None,
+    ),
+    # every row under ONE 16-bit prefix: pass 1 fills a single cell of
+    # its 256 x 256 histogram and passes 2-3 do all the narrowing
+    "one_prefix16": (
+        1.0 + _RNG.integers(0, 1 << 16, 4000) * 2.0 ** -23, None,
+    ),
+    # fewer rows than a radix digit has values (the block's floor)
+    "under_256_rows": (_grid(_RNG.normal(0, 1, 200)), None),
+    # one row over a whole block: the second block is one row and padding
+    "one_over_a_block": (
+        _grid(_RNG.normal(100, 5, (1 << 16) + 1)),
+        _RNG.random((1 << 16) + 1) > 0.01,
+    ),
 }
 
 
@@ -180,6 +206,17 @@ def test_select_matches_sort_reference_adversarial(case, k):
         assert sa.count == sb.count
         for la, lb in zip(sa.compactors, sb.compactors):
             assert np.array_equal(la, lb, equal_nan=True)
+
+
+def test_pass1_cases_span_the_leading_digit_range():
+    """What the pass-1 cases above claim of their keys: both ends of the
+    top byte in one column, and one 16-bit prefix over a whole column."""
+    keys = lambda case: np.asarray(monotone_u32(  # noqa: E731
+        jnp.asarray(split_pair_np(_ADVERSARIAL[case][0])[0]), jnp))
+    top = keys("top_byte_00_and_ff") >> 24
+    assert {0x00, 0x7F, 0x80, 0xFF} <= set(top.tolist())
+    assert np.unique(keys("one_prefix16") >> 16).size == 1
+    assert np.unique(keys("one_prefix16")).size > 256
 
 
 def test_valid_negative_nan_column_end_to_end_parity():
@@ -324,6 +361,34 @@ def test_matmul_formulation_under_vmap_maps_its_members():
         for key in alone:
             assert np.array_equal(batched[key][j], alone[key],
                                   equal_nan=True), (j, key)
+
+
+def test_pass1_leading_digits_stay_opaque_in_the_lowered_program():
+    """Pass 1 compares its rows against the 256 leading digits as a
+    RUN-TIME array, like the prefixes of passes 2-3, padded off a
+    multiple of 128 rows: an ``optimization_barrier`` keeps the compiler
+    from folding them back into an iota on both sides of the matmul (on
+    the v5e that fusion ran at a quarter of the speed of passes 2-3,
+    PERF.md section 6, PR 31). The barrier is in the lowered program,
+    once, and under pass 1's scope, so a clean-up cannot drop it unseen."""
+    from deequ_tpu.ops.select_device import (
+        _PASS1_ROWS,
+        _multirank_onehot_body,
+    )
+
+    lowered = jax.jit(_multirank_onehot_body).lower(
+        jax.ShapeDtypeStruct((70_001,), jnp.uint32),
+        jax.ShapeDtypeStruct((66,), jnp.int32),
+    )
+    text = lowered.as_text(debug_info=True)
+    barriers = [
+        line for line in text.splitlines()
+        if "stablehlo.optimization_barrier" in line
+    ]
+    assert len(barriers) == 1, barriers
+    assert _PASS1_ROWS > 256 and _PASS1_ROWS % 128
+    assert f"tensor<{_PASS1_ROWS}xi32>" in barriers[0]
+    assert "deequ.select.pass1/optimization_barrier" in text
 
 
 # -- KLL merge algebra --------------------------------------------------
