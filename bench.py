@@ -2367,8 +2367,13 @@ def measure_suggestion_loop(n_windows: int = 6):
                 data = window_table(w)
                 fetch0 = SCAN_STATS.device_fetches
                 batch0 = SCAN_STATS.coalesced_batches
+                group0 = SCAN_STATS.seam_grouping_count
                 engine.profile_tenant(data, "cold", w, monitor=monitor)
-                mixed_fetches += SCAN_STATS.device_fetches - fetch0
+                # a resident string column's histogram pass is one fetch
+                # of its own, outside the coalescer (counted since PR 32)
+                mixed_fetches += SCAN_STATS.device_fetches - fetch0 - (
+                    SCAN_STATS.seam_grouping_count - group0
+                )
                 mixed_batches += SCAN_STATS.coalesced_batches - batch0
                 engine.suggest("cold", w)
                 shadow = None

@@ -246,7 +246,7 @@ def main(argv=None) -> int:
         "seam_ms_per_op": seam_ms, "seam_count_per_op": seam_count,
         "unspanned_ms_per_op": span_ms - sum(
             seam_ms[n] for n in SEAM_NAMES
-            if not n.startswith(("persist", "grouping"))),
+            if not n.startswith("persist") and n != "grouping.host"),
         "setup": dict(phases), "persist": persist,
         "traced": {"window_s": hi - lo, "busy_s": busy,
                    "operations": traced_ops},

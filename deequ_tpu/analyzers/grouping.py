@@ -807,18 +807,54 @@ class Histogram(FrequencyBasedAnalyzer):
         # for free, so spilling folds it without a re-sort
         return self.compute_state_from(batch)
 
-    def calculate(self, table, aggregate_with=None, save_states_with=None):
-        # device top-N fast path: when nobody needs the mergeable frequency
-        # state and there is no binning UDF, counts are ranked ON DEVICE
-        # and only max_detail_bins (code, count) pairs are fetched/decoded —
-        # the engine-side top() of the reference (Histogram.scala:97-103).
-        # A high-cardinality column never materializes its groups on host.
-        if (
+    def takes_top_k_path(self, table, aggregate_with, save_states_with) -> bool:
+        """The device top-N fast path: when nobody needs the mergeable
+        frequency state and there is no binning UDF, counts are ranked ON
+        DEVICE and only max_detail_bins (code, count) pairs are
+        fetched/decoded — the engine-side top() of the reference
+        (Histogram.scala:97-103). A high-cardinality column never
+        materializes its groups on host."""
+        return (
             aggregate_with is None
             and save_states_with is None
             and self.binning_udf is None
             and not getattr(table, "is_streaming", False)
-        ):
+        )
+
+    def metric_from_top_k(self, stats) -> HistogramMetric:
+        """The metric of a ``segment.TopKCounts``.
+
+        Tie semantics: count ties at the truncation boundary break by
+        device rank order here (the reference's own top() is equally
+        tie-unstable, Histogram.scala:97-103), while the state path
+        breaks them deterministically by stringified key
+        (compute_metric_from). An r5 attempt to unify them by falling
+        back to the state path on a boundary tie was REVERTED:
+        high-cardinality columns (BASELINE config 4) are essentially
+        always tied at the boundary, and the fallback turned the
+        O(k)-fetch fast path into an O(G) group materialization — a
+        measured 10x regression."""
+        top = stats.top
+
+        def build_fast() -> Distribution:
+            # merge stringified collisions (e.g. 1 vs "1" -> "1") the
+            # same way the full path does
+            merged: Dict[str, int] = {}
+            for value, count in top:
+                key = _stringify(value)
+                merged[key] = merged.get(key, 0) + count
+            details = {
+                key: DistributionValue(count, count / stats.num_rows)
+                for key, count in merged.items()
+            }
+            return Distribution(details, number_of_bins=stats.num_groups)
+
+        from deequ_tpu.tryresult import Try
+
+        return HistogramMetric(self.column, Try.of(build_fast))
+
+    def calculate(self, table, aggregate_with=None, save_states_with=None):
+        if self.takes_top_k_path(table, aggregate_with, save_states_with):
             from deequ_tpu.analyzers.base import find_first_failing
             from deequ_tpu.ops.segment import group_top_k
 
@@ -831,34 +867,7 @@ class Histogram(FrequencyBasedAnalyzer):
                 from deequ_tpu.exceptions import wrap_if_necessary
 
                 return self.to_failure_metric(wrap_if_necessary(e))
-            # tie semantics: count ties at the truncation boundary break
-            # by device rank order here (the reference's own top() is
-            # equally tie-unstable, Histogram.scala:97-103), while the
-            # state path breaks them deterministically by stringified key
-            # (compute_metric_from). An r5 attempt to unify them by
-            # falling back to the state path on a boundary tie was
-            # REVERTED: high-cardinality columns (BASELINE config 4) are
-            # essentially always tied at the boundary, and the fallback
-            # turned the O(k)-fetch fast path into an O(G) group
-            # materialization — a measured 10x regression.
-            top = stats.top
-
-            def build_fast() -> Distribution:
-                # merge stringified collisions (e.g. 1 vs "1" -> "1") the
-                # same way the full path does
-                merged: Dict[str, int] = {}
-                for value, count in top:
-                    key = _stringify(value)
-                    merged[key] = merged.get(key, 0) + count
-                details = {
-                    key: DistributionValue(count, count / stats.num_rows)
-                    for key, count in merged.items()
-                }
-                return Distribution(details, number_of_bins=stats.num_groups)
-
-            from deequ_tpu.tryresult import Try
-
-            return HistogramMetric(self.column, Try.of(build_fast))
+            return self.metric_from_top_k(stats)
         return super().calculate(table, aggregate_with, save_states_with)
 
     def compute_metric_from(self, state: Optional[FrequenciesAndNumRows]) -> HistogramMetric:
@@ -950,3 +959,35 @@ class Histogram(FrequencyBasedAnalyzer):
         from deequ_tpu.exceptions import wrap_if_necessary
 
         return HistogramMetric(self.column, Failure(wrap_if_necessary(exception)))
+
+
+def resident_histograms(
+    table, analyzers, aggregate_with=None, save_states_with=None
+) -> Dict[Histogram, HistogramMetric]:
+    """The metrics of the Histograms among ``analyzers`` that take the
+    top-N fast path over string columns of a persist()ed table: all of
+    them from ONE dispatch and ONE fetch (``segment.resident_top_k``; one
+    by one, H Histograms were H dispatches and 3H round trips a run). An
+    analyzer that gets no metric here goes through ``calculate``, which
+    also turns whatever failed here into its failure metric."""
+    from deequ_tpu.analyzers.base import find_first_failing
+    from deequ_tpu.ops.segment import resident_top_k
+
+    batch = [
+        a for a in analyzers
+        if isinstance(a, Histogram)
+        and a.takes_top_k_path(table, aggregate_with, save_states_with)
+        and find_first_failing(table.schema, a.preconditions()) is None
+    ]
+    if not batch:
+        return {}
+    try:
+        stats = resident_top_k(
+            table, [(a.column, a.max_detail_bins) for a in batch]
+        )
+    # deequ-lint: ignore[bare-except] -- a failed batch falls back to calculate(), which re-raises into each analyzer's typed failure metric
+    except Exception:  # noqa: BLE001
+        return {}
+    if stats is None:
+        return {}
+    return {a: a.metric_from_top_k(s) for a, s in zip(batch, stats)}

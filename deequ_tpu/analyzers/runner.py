@@ -391,12 +391,18 @@ class AnalysisRunner:
                     aggregate_with, save_states_with,
                     group_memory_budget=budget,
                 )
-            for analyzer in own_pass:
-                if analyzer in spillable:
-                    continue
-                own_ctx.metric_map[analyzer] = analyzer.calculate(
-                    data, aggregate_with, save_states_with
-                )
+            from deequ_tpu.analyzers.grouping import resident_histograms
+
+            one_by_one = [a for a in own_pass if a not in spillable]
+            # a persist()ed table's Histograms: one dispatch, one fetch
+            own_ctx.metric_map.update(resident_histograms(
+                data, one_by_one, aggregate_with, save_states_with
+            ))
+            for analyzer in one_by_one:
+                if analyzer not in own_ctx.metric_map:
+                    own_ctx.metric_map[analyzer] = analyzer.calculate(
+                        data, aggregate_with, save_states_with
+                    )
 
         # (5) grouping analyzers share one frequency table per distinct
         # sorted grouping-column set (reference L175-190; partition built

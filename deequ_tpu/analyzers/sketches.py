@@ -23,6 +23,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from deequ_tpu.analyzers.base import (
@@ -131,7 +132,8 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
             if dtype == DType.STRING:
                 # host-precomputed packed (idx, rank) per distinct value:
                 # the device only gathers + unpacks with native i32 ops
-                packed = v.lut(f"hll_ir_p{p}")[xp.maximum(v.data, 0)]
+                with jax.named_scope("deequ.lut.gather"):
+                    packed = v.lut(f"hll_ir_p{p}")[xp.maximum(v.data, 0)]
                 idx = (packed >> xp.int32(6)).astype(xp.int32)
                 rank = (packed & xp.int32(0x3F)).astype(xp.int32)
                 valid = rows & (v.data >= 0)
@@ -176,6 +178,7 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
             {"registers": "max", "hash_version": "max"},
             luts=luts,
             dictionary_baked=_string_baked(table, wcols),
+            hll_folds=1,
         )
 
     def state_from_scan_result(self, result) -> Optional[ApproxCountDistinctState]:

@@ -477,7 +477,7 @@ def resolve_recorder(trace=None) -> Optional[FlightRecorder]:
 #: table: layer, where it is emitted, the metric that reads it)
 SEAM_NAMES = (
     "plan", "build", "pack", "stage", "dispatch", "drain", "fetch",
-    "states", "evaluate", "sketch_fold", "repository",
+    "states", "evaluate", "sketch_fold", "grouping", "repository",
     "persist.pack", "persist.stage", "grouping.host",
 )
 #: seams whose exclusive seconds ALSO feed an older ScanStats field,

@@ -113,6 +113,17 @@ def hist_accel_cap() -> int:
     return HIST_ONEHOT_MXU_MAX_SEGMENTS if configured is None else configured
 
 
+def hist_is_wide(widths, platform: Optional[str] = None) -> bool:
+    """True where the widest of ``widths`` is past the platform's one-hot
+    cap: such a bincount resolves to the scatter whatever its rows."""
+    if platform is None:
+        import jax
+
+        platform = jax.default_backend()
+    cap = hist_cpu_cap() if platform == "cpu" else hist_accel_cap()
+    return max(int(w) for w in widths) > cap
+
+
 def resolve_hist_variant(
     widths,
     rows: Optional[int] = None,
@@ -150,14 +161,7 @@ def resolve_hist_variant(
         return "scatter"
     if rows is not None and rows < HIST_MIN_ROWS:
         return "scatter"
-    if platform is None:
-        import jax
-
-        platform = jax.default_backend()
-    cap = hist_cpu_cap() if platform == "cpu" else hist_accel_cap()
-    if max(widths) <= cap:
-        return "onehot"
-    return "scatter"
+    return "scatter" if hist_is_wide(widths, platform) else "onehot"
 
 
 # -- compute watchdog --------------------------------------------------------

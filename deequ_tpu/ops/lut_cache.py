@@ -104,6 +104,9 @@ def dictionary_lut(
     if entry is not None and entry[0]() is dictionary:
         _MEMO[key] = entry  # re-insert: most recently used
         return entry[1]
+    from deequ_tpu.ops.scan_engine import SCAN_STATS
+
+    SCAN_STATS.lut_builds += 1
     lut = builder(dictionary)
     try:
         ref = weakref.ref(dictionary)

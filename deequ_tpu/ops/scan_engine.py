@@ -235,6 +235,9 @@ class ScanOp:
     # True when `update` runs a full device sort per chunk (the KLL
     # summary kernels) — the census behind ScanStats.device_sort_passes
     sorts_chunk: bool = False
+    # HLL register folds `update` runs per chunk (ApproxCountDistinct: 1)
+    # — the census behind ScanStats.hll_folds
+    hll_folds: int = 0
     # plane seam (ops/scan_plan.py): set by an analyzer whose partial is
     # made of the statistics of ONE column with no `where`. plane_stats
     # names what it needs beyond the two counts ("sum", "min", "max",
@@ -303,6 +306,18 @@ class ScanStats:
         self.hist_scatter_dispatches = 0
         self.hist_onehot_dispatches = 0
         self.hist_pallas_dispatches = 0
+        # of those, the bincount passes over a keyspace past the
+        # platform's one-hot cap (device_policy.hist_is_wide): the
+        # high-cardinality dictionaries of a string table
+        self.hist_wide_dispatches = 0
+        # HLL register folds: the ApproxCountDistinct ops of each
+        # dispatched plan, times its chunks (counted on the host, where
+        # _record_kernel_passes counts a dispatch)
+        self.hll_folds = 0
+        # dictionary LUTs BUILT (a memo miss of lut_cache.dictionary_lut:
+        # O(dictionary) host work, hashing included); a warm suite
+        # builds none
+        self.lut_builds = 0
         # device->host result bytes (grouping paths): the sparse group-by
         # contract is fetched bytes ~ O(k*G), never O(k*n)
         self.bytes_fetched = 0
@@ -494,14 +509,20 @@ class ScanStats:
         with self._fetch_lock:
             self.late_rows += int(n)
 
-    def record_hist_dispatch(self, variant: str, n: int = 1) -> None:
+    def record_hist_dispatch(
+        self, variant: str, n: int = 1, wide: bool = False
+    ) -> None:
         """Account ``n`` histogram/segment-fold kernel dispatches under
-        their resolved variant (ops/histogram_device.py tier). Written
-        from serve/fleet worker threads like the fetch ledger, so the
-        read-modify-write shares its lock."""
+        their resolved variant (ops/histogram_device.py tier); ``wide``
+        where their keyspace is past the one-hot cap
+        (device_policy.hist_is_wide). Written from serve/fleet worker
+        threads like the fetch ledger, so the read-modify-write shares
+        its lock."""
         field_name = f"hist_{variant}_dispatches"
         with self._fetch_lock:
             setattr(self, field_name, getattr(self, field_name) + int(n))
+            if wide:
+                self.hist_wide_dispatches += int(n)
 
     def record_fused_group_pass(self, n: int = 1) -> None:
         """Account ``n`` grouping passes that executed inside one fused
@@ -2083,6 +2104,7 @@ def _record_kernel_passes(plan_ir, chunks: int) -> None:
         SCAN_STATS.plane_ops += plan_ir.plane_ops * chunks
         SCAN_STATS.device_sort_passes += plan_ir.sort_ops * chunks
         SCAN_STATS.device_select_passes += plan_ir.select_ops * chunks
+        SCAN_STATS.hll_folds += plan_ir.hll_folds * chunks
         if plan_ir.select_ops and plan_ir.hist_variant != "none":
             SCAN_STATS.record_hist_dispatch(
                 plan_ir.hist_variant,

@@ -155,6 +155,8 @@ class ScanPlan:
     sort_ops: int = 0
     #: ops that read their scalars out of the batched plane statistics
     plane_ops: int = 0
+    #: HLL register folds per chunk dispatch (``ScanOp.hll_folds`` summed)
+    hll_folds: int = 0
     variant: str = "none"
     #: histogram kernel tier of the plan's bincount passes ("none" when
     #: the plan runs no histogram passes at all) — see class doc
@@ -534,6 +536,7 @@ def plan_scan_ops(
         select_ops=n_select,
         sort_ops=n_sort,
         plane_ops=sum(op.plane_route is not None for op in resolved),
+        hll_folds=sum(op.hll_folds for op in resolved),
         variant=variant,
         hist_variant=hist_variant,
         fold_tags=tuple(

@@ -346,20 +346,25 @@ from functools import lru_cache
 
 def _count_slots(slot, num_segments: int, variant: str):
     """Traced: counts over ``num_segments + 1`` slots under the routed
-    kernel tier (ops/histogram_device.py). The scatter variant keeps the
-    historical ``segment_sum``-of-ones formulation bit-for-bit; the
-    one-hot/pallas variants replace the scatter-add with the blocked
-    matmul / Mosaic grid kernel, exact by the tier's integer-count
-    contract."""
+    kernel tier (ops/histogram_device.py): the scatter variant is a
+    ``segment_sum`` of ones, the one-hot/pallas variants replace the
+    scatter-add with the blocked matmul / Mosaic grid kernel, exact by
+    the tier's integer-count contract. Slots and counts are int32
+    whenever the rows of the call fit (always, on a device): a 64-bit
+    scatter-add is emulated on the v5e, 16-22x the int32 one on the chip
+    (PERF.md section 6, PR 32). Callers widen before they add calls up or
+    ``psum``."""
+    dtype = jnp.int32 if slot.shape[0] < (1 << 31) else jnp.int64
+    slot = slot.astype(dtype)
     if variant == "scatter":
-        return jax.ops.segment_sum(
-            jnp.ones_like(slot, dtype=jnp.int64), slot,
-            num_segments=num_segments + 1,
-        )
+        with jax.named_scope("deequ.bincount.scatter"):
+            return jax.ops.segment_sum(
+                jnp.ones_like(slot), slot, num_segments=num_segments + 1,
+            )
     from deequ_tpu.ops.histogram_device import bincount_variant
 
     return bincount_variant(
-        variant, slot, num_segments + 1, jnp, dtype=jnp.int64
+        variant, slot, num_segments + 1, jnp, dtype=dtype
     )
 
 
@@ -381,7 +386,7 @@ def _bincount_fn(num_segments: int, mesh, variant: str = "scatter"):
 
     def count(k):
         slot = jnp.where(k < 0, num_segments, k)
-        counts = _count_slots(slot, num_segments, variant)
+        counts = _count_slots(slot, num_segments, variant).astype(jnp.int64)
         if mesh is not None:
             counts = jax.lax.psum(counts, ROW_AXIS)
         return counts
@@ -407,7 +412,7 @@ def _topk_fn(
 
     def kernel(c):
         slot = jnp.where(c < 0, num_segments, c)
-        counts = _count_slots(slot, num_segments, variant)
+        counts = _count_slots(slot, num_segments, variant).astype(jnp.int64)
         if mesh is not None:
             counts = jax.lax.psum(counts, ROW_AXIS)
         counts = counts[:num_segments]
@@ -415,7 +420,8 @@ def _topk_fn(
             counts = counts.at[merge_null_into].add(counts[0])
             counts = counts.at[0].set(0)
         num_groups = (counts > 0).sum()
-        top_counts, top_idx = jax.lax.top_k(counts, kk)
+        with jax.named_scope("deequ.topk"):
+            top_counts, top_idx = jax.lax.top_k(counts, kk)
         return num_groups, top_counts, top_idx
 
     if mesh is not None:
@@ -436,82 +442,205 @@ def _topk_fn(
 # counts-derived results (top-k bins, scalar stats) ever leave the device.
 
 
+def _resident_counts(args, row: int, num_segments: int, include_null: bool,
+                     variant: str, dtype):
+    """Traced: counts of row ``row`` of the resident code planes over
+    ``num_segments`` slots (slot 0 = null), chunk after chunk; ``args`` is
+    ``codes_0, rv_0, codes_1, rv_1, ...``. Rows that do not count land in
+    the trailing slot ``num_segments`` and are dropped."""
+    counts = jnp.zeros(num_segments + 1, dtype=dtype)
+    for codes, rv in zip(args[::2], args[1::2]):
+        c = codes[row]
+        on = rv if include_null else rv & (c >= 0)
+        slot = jnp.where(on, c + 1, num_segments)
+        counts = counts + _count_slots(slot, num_segments, variant).astype(
+            dtype
+        )
+    return counts[:num_segments]
+
+
+def _resident_jit(kernel, n_chunks: int, mesh, variants):
+    """``kernel(codes_0, rv_0, codes_1, rv_1, ...)`` jitted, under ``mesh``
+    over the row-sharded resident planes with a replicated result."""
+    if mesh is None:
+        return jax.jit(kernel)
+    in_specs = (P(None, ROW_AXIS), P(ROW_AXIS)) * n_chunks
+    return jax.jit(
+        shard_map(
+            kernel, mesh=mesh, in_specs=in_specs, out_specs=P(),
+            **_shard_map_kwargs(
+                "pallas" if "pallas" in variants else "scatter"
+            ),
+        )
+    )
+
+
 @lru_cache(maxsize=64)
 def _resident_bincount_fn(
     num_segments: int, n_chunks: int, row: int, include_null: bool, mesh,
     variant: str = "scatter",
 ):
     def kernel(*args):  # codes_0, rv_0, codes_1, rv_1, ...
-        counts = jnp.zeros(num_segments + 1, dtype=jnp.int64)
-        for i in range(n_chunks):
-            c = args[2 * i][row].astype(jnp.int64)
-            rv = args[2 * i + 1]
-            on = rv if include_null else rv & (c >= 0)
-            slot = jnp.where(on, c + 1, num_segments)
-            counts = counts + _count_slots(slot, num_segments, variant)
+        counts = _resident_counts(
+            args, row, num_segments, include_null, variant, jnp.int64
+        )
         if mesh is not None:
             counts = jax.lax.psum(counts, ROW_AXIS)
-        return counts[:num_segments]
+        return counts
 
-    if mesh is not None:
-        in_specs = (P(None, ROW_AXIS), P(ROW_AXIS)) * n_chunks
-        return jax.jit(
-            shard_map(
-                kernel, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                **_shard_map_kwargs(variant),
-            )
-        )
-    return jax.jit(kernel)
+    return _resident_jit(kernel, n_chunks, mesh, (variant,))
+
+
+def _resident_strings(table, columns, mesh):
+    """The table's device cache where every one of ``columns`` is a string
+    column of its resident code plane under ``mesh``, else None."""
+    cache = getattr(table, "_device_cache", None)
+    if cache is None or not cache.device_chunks:
+        return None
+    if not cache.matches(mesh, list(columns)):
+        return None
+    if any(c not in cache.packer.string_names for c in columns):
+        return None
+    return cache
+
+
+def _resident_args(cache) -> list:
+    args = []
+    for chunk in cache.device_chunks:
+        args.append(chunk[5])  # codes buffer
+        args.append(chunk[6])  # row_valid
+    return args
+
+
+def _resolve_resident_variant(table, cache, column: str) -> str:
+    """The bincount variant of one resident string column, recorded in the
+    kernel census: one pass per resident chunk, all inside one dispatch."""
+    from deequ_tpu.ops.device_policy import hist_is_wide, resolve_hist_variant
+
+    widths = (len(cache.packer.col_dict[column]) + 2,)
+    variant = resolve_hist_variant(widths, rows=table.num_rows)
+    SCAN_STATS.record_hist_dispatch(
+        variant, len(cache.device_chunks), wide=hist_is_wide(widths)
+    )
+    return variant
 
 
 def _resident_string_bincount(table, column: str, include_null: bool, mesh):
     """Counts per code slot (slot 0 = null when include_null) straight from
     the persisted chunks, or None when the table/column is not resident.
     Returns a DEVICE array of length cardinality+1."""
-    cache = getattr(table, "_device_cache", None)
-    if cache is None or not cache.device_chunks:
-        return None
-    if not cache.matches(mesh, [column]):
+    cache = _resident_strings(table, [column], mesh)
+    if cache is None:
         return None
     packer = cache.packer
-    if column not in packer.string_names:
-        return None
-    row = packer.string_names.index(column)
-    card = len(packer.col_dict[column])
-    from deequ_tpu.ops.device_policy import resolve_hist_variant
-
-    variant = resolve_hist_variant((card + 2,), rows=table.num_rows)
-    # one bincount pass per resident chunk, all inside one dispatch
-    SCAN_STATS.record_hist_dispatch(variant, len(cache.device_chunks))
     fn = _resident_bincount_fn(
-        card + 1, len(cache.device_chunks), row, include_null, mesh,
-        variant,
+        len(packer.col_dict[column]) + 1, len(cache.device_chunks),
+        packer.string_names.index(column), include_null, mesh,
+        _resolve_resident_variant(table, cache, column),
     )
-    args = []
-    for chunk in cache.device_chunks:
-        args.append(chunk[5])  # codes buffer
-        args.append(chunk[6])  # row_valid
-    return fn(*args)
+    with seam("dispatch", what="resident bincount"):
+        return fn(*_resident_args(cache))
 
 
 @lru_cache(maxsize=64)
-def _topk_from_counts_fn(kk: int, merge_null_into: int = -1):
-    """Top-k + group count from a dense counts vector. When
+def _resident_topk_fn(specs: tuple, n_chunks: int, mesh, wide_rows: bool):
+    """The top-k summaries of several resident string columns as ONE
+    program with ONE output vector: per ``(row, num_segments, kk,
+    merge_null_into, variant)`` of ``specs`` its ``[num_groups, kk top
+    counts, kk top slots]``, concatenated in order. When
     ``merge_null_into`` >= 0, slot 0 (the null group) folds into that slot
     BEFORE ranking: the Histogram metric stringifies groups (null ->
     "NullValue"), so a literal "NullValue" string and actual nulls are ONE
     bin — merging after truncation would undercount whenever one of the
-    pair straddles the k boundary."""
+    pair straddles the k boundary. Counts and ``top_k`` are int32 unless
+    the table holds 2^31 rows or more (``wide_rows``)."""
+    dtype = jnp.int64 if wide_rows else jnp.int32
 
-    def kernel(counts):
-        if merge_null_into >= 0:
-            counts = counts.at[merge_null_into].add(counts[0])
-            counts = counts.at[0].set(0)
-        num_groups = (counts > 0).sum()
-        top_counts, top_idx = jax.lax.top_k(counts, kk)
-        return num_groups, top_counts, top_idx
+    def kernel(*args):  # codes_0, rv_0, codes_1, rv_1, ...
+        parts = []
+        for row, num_segments, kk, merge_null_into, variant in specs:
+            counts = _resident_counts(
+                args, row, num_segments, True, variant, dtype
+            )
+            if mesh is not None:
+                counts = jax.lax.psum(counts, ROW_AXIS)
+            if merge_null_into >= 0:
+                counts = counts.at[merge_null_into].add(counts[0])
+                counts = counts.at[0].set(0)
+            num_groups = (counts > 0).sum(dtype=dtype)
+            with jax.named_scope("deequ.topk"):
+                top_counts, top_idx = jax.lax.top_k(counts, kk)
+            parts += [num_groups[None], top_counts, top_idx.astype(dtype)]
+        return jnp.concatenate(parts)
 
-    return jax.jit(kernel)
+    return _resident_jit(
+        kernel, n_chunks, mesh, tuple(spec[4] for spec in specs)
+    )
+
+
+def _null_value_slot(dictionary) -> int:
+    """The counts slot of a literal "NullValue" entry (-1: none): the
+    Histogram metric stringifies nulls to that label, so the two are ONE
+    bin. O(dictionary) on the host, memoized per dictionary."""
+    from deequ_tpu.ops.lut_cache import dictionary_lut
+
+    hits = dictionary_lut(
+        dictionary, "null_value_slot",
+        lambda d: np.nonzero(d == "NullValue")[0][:1] + 1,
+    )
+    return int(hits[0]) if len(hits) else -1
+
+
+def resident_top_k(
+    table: ColumnarTable, requests: Sequence[Tuple[str, int]], mesh=None
+) -> Optional[List["TopKCounts"]]:
+    """``group_top_k`` of several string columns of a persist()ed table at
+    once: every column's counts, null merge, group count and top-k in ONE
+    dispatch over the HBM-resident code planes and ONE fetch of ``1 + 2k``
+    integers a column. ``requests`` is ``[(column, k), ...]``; None when
+    the table, or one of the columns, is not resident under ``mesh`` (the
+    caller then goes column by column)."""
+    if mesh is None:
+        mesh = current_mesh()
+    columns = [column for column, _ in requests]
+    cache = _resident_strings(table, columns, mesh)
+    if cache is None:
+        return None
+    with seam("grouping", columns=",".join(columns)):
+        packer = cache.packer
+        specs = []
+        for column, k in requests:
+            dictionary = table[column].dictionary
+            specs.append((
+                packer.string_names.index(column), len(dictionary) + 1,
+                min(k, len(dictionary) + 1), _null_value_slot(dictionary),
+                _resolve_resident_variant(table, cache, column),
+            ))
+        fn = _resident_topk_fn(
+            tuple(specs), len(cache.device_chunks), mesh,
+            table.num_rows >= (1 << 31),
+        )
+        with seam("dispatch", what="resident top-k"):
+            out = fn(*_resident_args(cache))
+        with seam("fetch", what="resident top-k"):
+            flat = np.asarray(out)
+        _record_fetch(flat)
+        SCAN_STATS.grouping_passes += len(requests)
+        SCAN_STATS.rows_scanned += table.num_rows * len(requests)
+        results, at = [], 0
+        for (column, _), (_, _, kk, _, _) in zip(requests, specs):
+            num_groups = int(flat[at])
+            top_counts = flat[at + 1:at + 1 + kk]
+            top_idx = flat[at + 1 + kk:at + 1 + 2 * kk]
+            at += 1 + 2 * kk
+            dictionary = table[column].dictionary
+            keep = top_counts > 0
+            results.append(TopKCounts(table.num_rows, num_groups, tuple(
+                (None if idx == 0 else dictionary[idx - 1], cnt)
+                for idx, cnt in zip(top_idx[keep].tolist(),
+                                    top_counts[keep].tolist())
+            )))
+        return results
 
 
 @jax.jit
@@ -538,12 +667,17 @@ def _rle_stats_kernel(mat, va):
 
 @jax.jit
 def _stats_from_counts(counts):
+    """``[total, groups, singletons, entropy]`` as ONE f64 vector (the
+    three counts are exact there: under 2^53), so one fetch brings all."""
     total = counts.sum()
     groups = (counts > 0).sum()
     singles = (counts == 1).sum()
     p = counts / jnp.maximum(total, 1)
     ent = -jnp.where(counts > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0).sum()
-    return total, groups, singles, ent
+    return jnp.stack([
+        total.astype(jnp.float64), groups.astype(jnp.float64),
+        singles.astype(jnp.float64), ent,
+    ])
 
 
 def _device_bincount(keys: np.ndarray, num_segments: int, mesh) -> np.ndarray:
@@ -566,10 +700,12 @@ def _device_bincount(keys: np.ndarray, num_segments: int, mesh) -> np.ndarray:
 
     # histogram kernel tier (round 14): scatter vs one-hot matmul vs
     # pallas, resolved per dispatch from keyspace width / rows / platform
-    from deequ_tpu.ops.device_policy import resolve_hist_variant
+    from deequ_tpu.ops.device_policy import hist_is_wide, resolve_hist_variant
 
     variant = resolve_hist_variant((num_segments + 1,), rows=n)
-    SCAN_STATS.record_hist_dispatch(variant)
+    SCAN_STATS.record_hist_dispatch(
+        variant, wide=hist_is_wide((num_segments + 1,))
+    )
     counts = np.asarray(_bincount_fn(num_segments, mesh, variant)(keys))
     _record_fetch(counts)
     return counts[:num_segments]
@@ -833,34 +969,21 @@ def group_top_k(
     similarly tie-unstable)."""
     if mesh is None:
         mesh = current_mesh()
+    col = table[column]
+    if col.dtype == DType.STRING:
+        # persisted table: counts + top-k entirely from HBM-resident codes
+        resident = resident_top_k(table, [(column, k)], mesh)
+        if resident is not None:
+            return resident[0]
     SCAN_STATS.grouping_passes += 1
     SCAN_STATS.rows_scanned += table.num_rows
 
-    col = table[column]
     nv_code = -1
     if col.dtype == DType.STRING:
         # the Histogram metric stringifies nulls to "NullValue": if that
         # literal also appears in the data, the two slots are ONE bin and
         # must merge on device BEFORE top-k truncation
-        hits = np.nonzero(col.dictionary == "NullValue")[0]
-        if len(hits):
-            nv_code = int(hits[0]) + 1
-        # persisted table: counts + top-k entirely from HBM-resident codes
-        resident = _resident_string_bincount(table, column, True, mesh)
-        if resident is not None:
-            kk = min(k, len(col.dictionary) + 1)
-            num_groups, top_counts, top_idx = (
-                np.asarray(x)
-                for x in _topk_from_counts_fn(kk, nv_code)(resident)
-            )
-            top = []
-            for idx, cnt in zip(top_idx.tolist(), top_counts.tolist()):
-                if cnt <= 0:
-                    continue
-                top.append(
-                    (None if idx == 0 else col.dictionary[idx - 1], int(cnt))
-                )
-            return TopKCounts(table.num_rows, int(num_groups), tuple(top))
+        nv_code = _null_value_slot(col.dictionary)
         codes = col.codes.astype(np.int64) + 1
         decode = lambda idx: col.dictionary[idx - 1]  # noqa: E731
         card = len(col.dictionary)
@@ -899,10 +1022,15 @@ def group_top_k(
             codes = np.concatenate(
                 [codes, np.full(padded - n, -1, dtype=np.int64)]
             )
-        from deequ_tpu.ops.device_policy import resolve_hist_variant
+        from deequ_tpu.ops.device_policy import (
+            hist_is_wide,
+            resolve_hist_variant,
+        )
 
         variant = resolve_hist_variant((num_segments + 1,), rows=n)
-        SCAN_STATS.record_hist_dispatch(variant)
+        SCAN_STATS.record_hist_dispatch(
+            variant, wide=hist_is_wide((num_segments + 1,))
+        )
         num_groups, top_counts, top_idx = (
             np.asarray(x)
             for x in _topk_fn(num_segments, kk, mesh, nv_code, variant)(codes)
@@ -960,20 +1088,21 @@ def group_count_stats(
 
     # single resident string column: all four aggregates from HBM-resident
     # codes — only 4 scalars leave the device
-    if len(columns) == 1 and table[columns[0]].dtype == DType.STRING:
-        resident = _resident_string_bincount(
-            table, columns[0], not require_any_non_null, mesh
-        )
-        if resident is not None:
-            total, groups, singles, ent = (
-                np.asarray(x) for x in _stats_from_counts(resident)
+    if _resident_stats_eligible(table, columns, mesh):
+        with seam("grouping", columns=columns[0]):
+            resident = _resident_string_bincount(
+                table, columns[0], not require_any_non_null, mesh
             )
-            total = int(total)
+            with seam("dispatch", what="resident count stats"):
+                out = _stats_from_counts(resident)
+            with seam("fetch", what="resident count stats"):
+                stats = np.asarray(out)
+            _record_fetch(stats)
+            total, groups, singles = (int(x) for x in stats[:3])
             return CountStats(
-                total,
-                int(groups),
-                int(singles),
-                float(ent) if total > 0 and int(groups) > 0 else float("nan"),
+                total, groups, singles,
+                float(stats[3]) if total > 0 and groups > 0
+                else float("nan"),
             )
 
     prep = _prepare_grouping(
@@ -1029,20 +1158,17 @@ class GroupRequest:
 
 
 def _resident_stats_eligible(table, columns, mesh) -> bool:
-    """True when a stats-mode set would take ``group_count_stats``'s
+    """True when a stats-mode set takes ``group_count_stats``'s
     resident-string fast path (all four aggregates from HBM-resident
-    codes, four scalars fetched) — cheaper than any fusion, and its
+    codes, one small vector fetched) — cheaper than any fusion, and its
     device-side entropy reduction is not bit-guaranteed against the host
     finalize, so the optimizer must leave such sets on the per-set
     path."""
-    if len(columns) != 1 or table[columns[0]].dtype != DType.STRING:
-        return False
-    cache = getattr(table, "_device_cache", None)
-    if cache is None or not cache.device_chunks:
-        return False
-    if not cache.matches(mesh, [columns[0]]):
-        return False
-    return columns[0] in cache.packer.string_names
+    return (
+        len(columns) == 1
+        and table[columns[0]].dtype == DType.STRING
+        and _resident_strings(table, columns, mesh) is not None
+    )
 
 
 def _maybe_lint_fused(

@@ -527,6 +527,97 @@ def test_sharded_grouping_kernels_compile(as_tpu, topo):
     ).compile()
 
 
+STRING_ROWS = 12_500_000  # the cell strings12m.sscan: one resident chunk
+STRING_CLASSES = (50_000, 250_000, 1_000_000, 3_000_000)
+
+
+def _emitters(text, scope):
+    """The emitter XLA:TPU chose for each matmul fusion whose convolution
+    carries ``scope`` in its op name (the fusion call's
+    ``convolution_algorithm_config``)."""
+    found = set()
+    for computation in text.split("\n}\n"):
+        if not re.search(rf"convolution\(.*{re.escape(scope)}", computation):
+            continue
+        name = re.match(r"(%\S+) \(", computation.lstrip()).group(1)
+        for line in text.splitlines():
+            if f"calls={name}," in line:
+                found.add(re.search(r'"emitter":"(\w+)"', line).group(1))
+    return found
+
+
+def test_resident_top_k_of_the_four_string_classes_compiles(as_tpu, one_chip):
+    """The Histogram pass of ``strings12m.sscan`` (PR 32), one column of
+    each dictionary class in ONE program over the resident code plane: the
+    class under the one-hot cap counts by matmul, the three past it by an
+    int32 scatter-add. No 64-bit operand anywhere: the int64 scatter the
+    grouping kernels ran before is emulated on the v5e, 8x the int32 one
+    on the chip (PERF.md section 6, PR 32)."""
+    from deequ_tpu.ops.device_policy import resolve_hist_variant
+    from deequ_tpu.ops.segment import _resident_topk_fn
+
+    n = STRING_ROWS
+    specs = tuple(
+        (row, card + 1, 1000, -1, resolve_hist_variant((card + 2,), rows=n))
+        for row, card in enumerate(STRING_CLASSES)
+    )
+    assert [spec[4] for spec in specs] == [
+        "onehot", "scatter", "scatter", "scatter"]
+    compiled = _resident_topk_fn(specs, 1, None, False).lower(
+        _aval((20, n), np.int32, one_chip), _aval((n,), np.bool_, one_chip)
+    ).compile()
+    text = compiled.as_text()
+    scatters = re.findall(r"= (\S+) scatter\(", text)
+    assert sorted(s.split("{")[0] for s in scatters) == sorted(
+        f"s32[{card + 2}]" for card in STRING_CLASSES[1:])
+    assert not re.search(r"\b[su]64\[", text)
+    # the narrow class rides the MXU, built by the emitter the selection
+    # passes share since PR 31
+    assert _emitters(text, "deequ.bincount.onehot") == {
+        "EmitAllBatchInSublanes"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_string_hll_step_compiles_with_a_gather_and_an_mxu_fold(
+        as_tpu, one_chip):
+    """The scan pass of ``strings12m.sscan``: ApproxCountDistinct over one
+    string column of each dictionary class at the cell's 12.5M rows, the
+    (idx, rank) LUTs as run-time arguments at their padded widths: per
+    column one gather by code and the one-hot register fold, no scatter."""
+    from chipbench import suite_build
+    from chipbench.generators import string_table
+    from deequ_tpu.analyzers import ApproxCountDistinct
+    from deequ_tpu.analyzers.runner import AnalysisRunner
+    from deequ_tpu.ops.scan_engine import _build_step_fns, _ChunkPacker
+    from deequ_tpu.ops.scan_plan import plan_scan_ops
+
+    n = STRING_ROWS
+    params = {"n_string": 4, "dictionary_sizes": [5, 6, 7, 8],
+              "zipf_exponent": 1.0, "null_share": 0.01}
+    table = suite_build.table_of(string_table.generate(64, 3, params))
+    ops, scannable, failures = AnalysisRunner._build_scan_ops(
+        table, [ApproxCountDistinct(f"s{i}") for i in range(4)])
+    assert not failures and len(scannable) == 4
+    packer = _ChunkPacker({c: table[c] for c in table.column_names}, n)
+    plan = plan_scan_ops(ops, packer, resident=True, rows=n)
+    assert plan.hll_folds == 4
+    luts = {
+        col + "\x00" + kind: _aval(
+            (1 << (card - 1).bit_length(),), np.int32, one_chip)
+        for op, card in zip(plan.ops, STRING_CLASSES)
+        for col, kind, _ in op.luts
+    }
+    step_fn, _, _ = _build_step_fns(
+        plan.ops, packer.unpack_view(), None, n, tuple(sorted(luts)))
+    compiled = step_fn.lower(
+        *_chunk_avals(packer, n, one_chip, one_chip), luts).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"\bgather\(", text)) == 4
+    assert not re.search(r"\bscatter\(", text)
+    assert _emitters(text, "deequ.hll.fold") == {"EmitInputBatchInLanes"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 def test_pane_step_compiles_in_f64(one_chip):
     from deequ_tpu.analyzers import (
         Completeness, Maximum, Mean, Minimum, Size, Sum,

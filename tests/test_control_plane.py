@@ -170,14 +170,19 @@ def test_repeat_profile_is_pure_plan_cache_hit(single_device):
         linted = SCAN_STATS.plan_lint_traces
         fetches = SCAN_STATS.device_fetches
         batches = SCAN_STATS.coalesced_batches
+        grouped = SCAN_STATS.seam_grouping_count
         engine.profile_tenant(_window_table(seed=11), "t0", 2)
         assert SCAN_STATS.programs_built == built
         assert SCAN_STATS.plan_lint_traces == linted
         # one-fetch contract: the repeat profile's passes each drained
-        # exactly one fetch per coalesced batch
+        # exactly one fetch per coalesced batch; the histogram pass over
+        # the resident string column is one dispatch and one fetch of its
+        # own (counted since PR 32: it was three uncounted round trips)
         new_batches = SCAN_STATS.coalesced_batches - batches
         assert new_batches >= 2  # generic pass + per-schema passes
-        assert SCAN_STATS.device_fetches - fetches == new_batches
+        own_passes = SCAN_STATS.seam_grouping_count - grouped
+        assert own_passes == 1
+        assert SCAN_STATS.device_fetches - fetches == new_batches + own_passes
     finally:
         svc.stop(drain=False)
 
