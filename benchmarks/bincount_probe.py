@@ -19,7 +19,9 @@ every count bit-equal to ``np.bincount``:
 - ``topk64`` / ``topk32``: ``top_k(1000)`` over the dictionary's counts;
 - ``gather``: the HLL LUT gather by code; ``fold``: the one-hot register fold
   of 12.5M rows; ``presence_fold``: the registers from the entries PRESENT
-  (``counts > 0`` over the dictionary's slots, no per-row gather).
+  (``counts > 0`` over the dictionary's slots, no per-row gather), as
+  ``segment.resident_top_k`` folds them since PR 33
+  (``hll.registers_from_present``: on the MXU whatever the dictionary).
 
 Prints one JSON object. A time from a CPU run is not a device time: the
 object names the platform it ran on."""
@@ -117,7 +119,8 @@ def main(argv=None) -> int:
         row["fold"], regs = _median_ms(jax.jit(fold), packed, codes >= 0)
         present = jax.device_put(want[1:] > 0)
         row["presence_fold"], regs_present = _median_ms(
-            jax.jit(fold), jax.device_put(lut_np), present)
+            jax.jit(lambda t, on: hll.registers_from_present(t, on, p, jnp)),
+            jax.device_put(lut_np), present)
         assert np.array_equal(np.asarray(regs), np.asarray(regs_present))
         result["classes"][str(card)] = row
         print(f"bincount_probe: {card}: " + json.dumps(row), file=sys.stderr,

@@ -962,32 +962,49 @@ class Histogram(FrequencyBasedAnalyzer):
 
 
 def resident_histograms(
-    table, analyzers, aggregate_with=None, save_states_with=None
-) -> Dict[Histogram, HistogramMetric]:
+    table, analyzers, aggregate_with=None, save_states_with=None,
+    registers_of=(),
+) -> Tuple[Dict[Histogram, HistogramMetric], Dict[str, np.ndarray]]:
     """The metrics of the Histograms among ``analyzers`` that take the
     top-N fast path over string columns of a persist()ed table: all of
     them from ONE dispatch and ONE fetch (``segment.resident_top_k``; one
     by one, H Histograms were H dispatches and 3H round trips a run). An
     analyzer that gets no metric here goes through ``calculate``, which
-    also turns whatever failed here into its failure metric."""
-    from deequ_tpu.analyzers.base import find_first_failing
-    from deequ_tpu.ops.segment import resident_top_k
+    also turns whatever failed here into its failure metric.
 
-    batch = [
+    Beside them, the HLL registers of those columns of ``registers_of``
+    that are in the batch, folded in the same dispatch out of the entries
+    PRESENT in the counts: ``{column: registers}``, empty when the batch
+    did not run (the asker then scans, as ever)."""
+    from deequ_tpu.analyzers.base import find_first_failing
+    from deequ_tpu.ops.segment import resident_string_columns, resident_top_k
+
+    candidates = [
         a for a in analyzers
         if isinstance(a, Histogram)
         and a.takes_top_k_path(table, aggregate_with, save_states_with)
+    ]
+    if not candidates:
+        return {}, {}
+    resident = resident_string_columns(table)
+    batch = [
+        a for a in candidates
+        if a.column in resident
         and find_first_failing(table.schema, a.preconditions()) is None
     ]
     if not batch:
-        return {}
+        return {}, {}
     try:
-        stats = resident_top_k(
-            table, [(a.column, a.max_detail_bins) for a in batch]
+        served = resident_top_k(
+            table, [(a.column, a.max_detail_bins) for a in batch],
+            registers_of=registers_of,
         )
     # deequ-lint: ignore[bare-except] -- a failed batch falls back to calculate(), which re-raises into each analyzer's typed failure metric
     except Exception:  # noqa: BLE001
-        return {}
-    if stats is None:
-        return {}
-    return {a: a.metric_from_top_k(s) for a, s in zip(batch, stats)}
+        return {}, {}
+    if served is None:
+        return {}, {}
+    stats, registers = served
+    return (
+        {a: a.metric_from_top_k(s) for a, s in zip(batch, stats)}, registers
+    )

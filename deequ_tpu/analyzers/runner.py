@@ -36,7 +36,8 @@ from deequ_tpu.analyzers.grouping import (
     FrequencyBasedAnalyzer,
     Histogram,
 )
-from deequ_tpu.data.table import ColumnarTable, Schema
+from deequ_tpu.analyzers.sketches import ApproxCountDistinct
+from deequ_tpu.data.table import ColumnarTable, DType, Schema
 from deequ_tpu.exceptions import (
     GroupBudgetIgnoredWarning,
     MetricCalculationRuntimeException,
@@ -142,6 +143,36 @@ def _count_stats_capable(a) -> bool:
         and type(a).compute_from_count_stats
         is not _SSF.compute_from_count_stats
     )
+
+
+def _hll_presence_riders(
+    data, scanning, partners, aggregate_with, save_states_with
+) -> list:
+    """The ``ApproxCountDistinct`` analyzers of ``scanning`` that may leave
+    the fused scan and take their registers from the counts a Histogram of
+    the same run holds anyway (``segment.resident_top_k``): no ``where``,
+    a string column, and among ``partners`` a Histogram of that column on
+    the device top-N path (no state provider on either side: a state that
+    is merged or saved goes the scan's way; no binning, no stream).
+    Whether the table is resident is the batch's to find out: a rider that
+    comes back without registers returns to the scan. Without the partner
+    the counts are not free (a bincount of 22.6-84.8 ms a column against
+    the 113 ms it would save, PERF.md section 6, PR 32), so it stays."""
+    riders = [
+        a for a in scanning
+        if isinstance(a, ApproxCountDistinct) and a.where is None
+    ]
+    if not riders:
+        return riders
+    partnered = {
+        h.column for h in partners
+        if isinstance(h, Histogram)
+        and h.takes_top_k_path(data, aggregate_with, save_states_with)
+    }
+    return [
+        a for a in riders
+        if a.column in partnered and data[a.column].dtype == DType.STRING
+    ]
 
 
 def _release_spill(folder) -> None:
@@ -351,31 +382,16 @@ class AnalysisRunner:
             )
             return result
 
-        # (4) one fused scan for all shareable analyzers (reference L289-336)
-        scan_ctx = AnalysisRunner._run_scanning_analyzers(
-            data, scanning, aggregate_with, save_states_with,
-            on_device_error=on_device_error, device_deadline=device_deadline,
-            shard_deadline=shard_deadline,
-        )
-
-        # own-pass analyzers (KLL extra pass analogue, reference L155-160);
-        # on a stream they share ONE batch loop — N analyzers must not cost
-        # N full storage reads
-        own_ctx = AnalyzerContext.empty()
-        if own_pass and getattr(data, "is_streaming", False):
-            own_ctx += AnalysisRunner._run_own_pass_streaming(
-                data, own_pass, aggregate_with, save_states_with,
-                group_memory_budget=group_memory_budget,
-            )
-        elif own_pass:
-            # budgeted in-memory table: frequency-shaped own-pass states
-            # (Histogram) are O(#distinct) like the shared grouping path —
-            # slice the rows into budget-sized batches and take the
-            # spilling stream fold, same as _run_grouping_analyzers does
+        # budgeted in-memory table: frequency-shaped own-pass states
+        # (Histogram) are O(#distinct) like the shared grouping path —
+        # slice the rows into budget-sized batches and take the
+        # spilling stream fold, same as _run_grouping_analyzers does
+        streaming = getattr(data, "is_streaming", False)
+        spillable: list = []
+        if own_pass and not streaming:
             from deequ_tpu.spill import budget_batch_rows, resolve_group_budget
 
             budget = resolve_group_budget(data, group_memory_budget)
-            spillable: list = []
             if budget is not None:
                 batch_rows = budget_batch_rows(budget)
                 if data.num_rows > batch_rows:
@@ -383,6 +399,60 @@ class AnalysisRunner:
                         a for a in own_pass
                         if isinstance(a, FrequencyBasedAnalyzer)
                     ]
+        one_by_one = [a for a in own_pass if a not in spillable]
+
+        # a persist()ed table's Histograms: one dispatch, one fetch, and
+        # BEFORE the fused scan: the where-free ApproxCountDistinct of a
+        # string column whose Histogram is in the batch takes its
+        # registers from the batch's counts and leaves the scan (it
+        # returns to it when the batch brought nothing for it)
+        own_ctx = AnalyzerContext.empty()
+        served: Dict[Analyzer, Metric] = {}
+        if one_by_one and not streaming:
+            from deequ_tpu.analyzers.grouping import resident_histograms
+
+            with seam("plan", what="presence riders"):
+                riders = _hll_presence_riders(
+                    data, scanning, one_by_one, aggregate_with,
+                    save_states_with,
+                )
+            histograms, registers = resident_histograms(
+                data, one_by_one, aggregate_with, save_states_with,
+                registers_of=[a.column for a in riders],
+            )
+            own_ctx.metric_map.update(histograms)
+            pairs = [
+                (a, a.state_from_present_registers(registers[a.column]))
+                for a in riders if a.column in registers
+            ]
+            if pairs:
+                served = _put_metrics(
+                    AnalyzerContext.empty(), pairs, None, None
+                ).metric_map
+
+        # (4) one fused scan for all shareable analyzers (reference L289-336)
+        scan_ctx = AnalysisRunner._run_scanning_analyzers(
+            data,
+            [a for a in scanning if a not in served] if served else scanning,
+            aggregate_with, save_states_with,
+            on_device_error=on_device_error, device_deadline=device_deadline,
+            shard_deadline=shard_deadline,
+        )
+        if served:  # in the order the scan would have answered
+            answered = {**scan_ctx.metric_map, **served}
+            scan_ctx.metric_map = {
+                a: answered[a] for a in scanning if a in answered
+            }
+
+        # own-pass analyzers (KLL extra pass analogue, reference L155-160);
+        # on a stream they share ONE batch loop — N analyzers must not cost
+        # N full storage reads
+        if own_pass and streaming:
+            own_ctx += AnalysisRunner._run_own_pass_streaming(
+                data, own_pass, aggregate_with, save_states_with,
+                group_memory_budget=group_memory_budget,
+            )
+        elif own_pass:
             if spillable:
                 from deequ_tpu.data.streaming import stream_table
 
@@ -391,13 +461,6 @@ class AnalysisRunner:
                     aggregate_with, save_states_with,
                     group_memory_budget=budget,
                 )
-            from deequ_tpu.analyzers.grouping import resident_histograms
-
-            one_by_one = [a for a in own_pass if a not in spillable]
-            # a persist()ed table's Histograms: one dispatch, one fetch
-            own_ctx.metric_map.update(resident_histograms(
-                data, one_by_one, aggregate_with, save_states_with
-            ))
             for analyzer in one_by_one:
                 if analyzer not in own_ctx.metric_map:
                     own_ctx.metric_map[analyzer] = analyzer.calculate(

@@ -94,6 +94,10 @@ class ApproxCountDistinctState(DoubleValuedState):
         return hll_ops.estimate_cardinality(np.array(self.registers))
 
 
+# the hash suite of a string column's registers (host xxhash64, v1 content)
+STRING_HASH_VERSION = 1
+
+
 @dataclass(frozen=True)
 class ApproxCountDistinct(ScanShareableAnalyzer):
     """Approximate distinct count via HLL++
@@ -124,7 +128,10 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
         # idx/rank derivation, just gathered as a packed i32 LUT), so
         # they stay suite 1 and MERGE with pre-v4 persisted states;
         # numeric/boolean registers come from the u32 suite (2)
-        hash_version = 1 if dtype == DType.STRING else hll_ops.HASH_VERSION
+        hash_version = (
+            STRING_HASH_VERSION if dtype == DType.STRING
+            else hll_ops.HASH_VERSION
+        )
 
         def update(vals, row_valid, xp, n):
             rows = _rows(vals, row_valid, xp, n, pred)
@@ -186,6 +193,14 @@ class ApproxCountDistinct(ScanShareableAnalyzer):
         return ApproxCountDistinctState(
             tuple(int(r) for r in regs),
             int(np.asarray(result["hash_version"])),
+        )
+
+    def state_from_present_registers(self, registers) -> ApproxCountDistinctState:
+        """The state of a string column whose registers were folded out of
+        the dictionary entries present (``segment.resident_top_k``): the
+        same LUT as ``scan_op`` gathers from, so the same suite's stamp."""
+        return ApproxCountDistinctState(
+            tuple(np.asarray(registers).tolist()), STRING_HASH_VERSION
         )
 
     def compute_metric_from(self, state) -> DoubleMetric:

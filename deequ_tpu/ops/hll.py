@@ -358,33 +358,65 @@ def string_idx_rank_lut(values, p: int, seed: int = XXHASH_SEED) -> np.ndarray:
     return packed if len(packed) else np.zeros(1, dtype=np.int32)
 
 
-def registers_from_idx_rank(idx, rank, valid, p: int, xp):
-    """Fold (idx, rank) rows into an HLL register file on device.
+def _on_accelerator(xp) -> bool:
+    """Traced code on a device that has an MXU: on a CPU backend the
+    one-hot matmul is a large memory/FLOP regression over the scatter."""
+    import jax
 
-    Registers take the max rank per idx; invalid rows contribute rank 0.
-    Lowering paths: one-hot bf16 matmul on the MXU (default for large
-    device chunks) or XLA segment_max (small chunks / host numpy).
+    return xp is not np and jax.devices()[0].platform != "cpu"
+
+
+def _fold_registers(idx, rank, valid, p: int, xp, mxu: bool):
+    """Registers take the max rank per idx; invalid rows contribute rank 0.
+    Lowering paths: one-hot bf16 matmul on the MXU or XLA segment_max.
     The fold's one-hot width is fixed at 64: it covers every rank cap."""
     import jax
 
     m = 1 << p
     rank = xp.where(valid, rank, 0)
     idx = xp.where(valid, idx, 0)
+    if mxu:
+        return _registers_mxu_fold(idx, rank, m, xp)
+    regs = jax.ops.segment_max(
+        rank, idx, num_segments=m, indices_are_sorted=False
+    ).astype(xp.int32)
+    return xp.maximum(regs, 0)  # untouched segments fill with INT_MIN
+
+
+def registers_from_idx_rank(idx, rank, valid, p: int, xp):
+    """Fold (idx, rank) rows into an HLL register file on device: on the
+    MXU for large device chunks, by segment_max for small chunks and host
+    numpy."""
+    import jax
 
     with jax.named_scope("deequ.hll.fold"):
-        if xp is not np:
-            # TPU only: on CPU backends the one-hot matmul is a large
-            # memory/FLOP regression over scatter (no MXU to ride).
-            if (
-                idx.shape[0] >= _MXU_FOLD_MIN_ROWS
-                and jax.devices()[0].platform != "cpu"
-            ):
-                return _registers_mxu_fold(idx, rank, m, xp)
+        return _fold_registers(
+            idx, rank, valid, p, xp,
+            mxu=idx.shape[0] >= _MXU_FOLD_MIN_ROWS and _on_accelerator(xp),
+        )
 
-        regs = jax.ops.segment_max(
-            rank, idx, num_segments=m, indices_are_sorted=False
-        ).astype(xp.int32)
-        return xp.maximum(regs, 0)  # untouched segments fill with INT_MIN
+
+def registers_from_present(packed, present, p: int, xp):
+    """The register file of a dictionary column from the entries PRESENT:
+    ``packed`` is the dictionary's packed (idx, rank) LUT
+    (:func:`string_idx_rank_lut`), ``present[k]`` whether some counted row
+    holds entry ``k``. A register is the largest rank among the VALUES
+    hashed to it, so the fold of the K entries present equals the fold of
+    the rows (:func:`registers_from_idx_rank` after the per-row gather),
+    register for register, at K elements of work in place of n. On an
+    accelerator it rides the MXU whatever K: a scatter walks its elements
+    one by one there, and the grouping program that calls it is held free
+    of scatters but the wide counts (equal to numpy from 1 to 3M entries
+    on the v5e, 30.4 ms a suite for the twenty columns of
+    ``strings12m.sscan``: PERF.md section 6, PR 33)."""
+    import jax
+
+    with jax.named_scope("deequ.hll.present"):
+        idx = (packed >> xp.int32(6)).astype(xp.int32)
+        rank = (packed & xp.int32(0x3F)).astype(xp.int32)
+        return _fold_registers(
+            idx, rank, present, p, xp, mxu=_on_accelerator(xp)
+        )
 
 
 def registers_from_hashes(hashes, valid, p: int, xp):

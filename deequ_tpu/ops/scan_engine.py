@@ -314,6 +314,11 @@ class ScanStats:
         # dispatched plan, times its chunks (counted on the host, where
         # _record_kernel_passes counts a dispatch)
         self.hll_folds = 0
+        # columns whose HLL registers were folded out of the dictionary
+        # entries PRESENT in the counts a Histogram of the same run
+        # already had (segment.resident_top_k): K entries of work a
+        # column, no per-row gather, no per-row fold, no scan
+        self.hll_presence_folds = 0
         # dictionary LUTs BUILT (a memo miss of lut_cache.dictionary_lut:
         # O(dictionary) host work, hashing included); a warm suite
         # builds none

@@ -25,6 +25,8 @@ from jax.sharding import PartitionSpec as P
 
 from deequ_tpu.data.table import Column, ColumnarTable, DType
 from deequ_tpu.obs.recorder import seam
+from deequ_tpu.ops import hll
+from deequ_tpu.ops.lut_cache import dictionary_lut_device
 from deequ_tpu.ops.scan_engine import SCAN_STATS
 from deequ_tpu.parallel.mesh import ROW_AXIS, current_mesh, shard_map
 
@@ -459,12 +461,13 @@ def _resident_counts(args, row: int, num_segments: int, include_null: bool,
     return counts[:num_segments]
 
 
-def _resident_jit(kernel, n_chunks: int, mesh, variants):
-    """``kernel(codes_0, rv_0, codes_1, rv_1, ...)`` jitted, under ``mesh``
-    over the row-sharded resident planes with a replicated result."""
+def _resident_jit(kernel, n_chunks: int, mesh, variants, n_luts: int = 0):
+    """``kernel(codes_0, rv_0, codes_1, rv_1, ..., lut_0, ...)`` jitted,
+    under ``mesh`` over the row-sharded resident planes (and ``n_luts``
+    replicated dictionary LUTs behind them) with a replicated result."""
     if mesh is None:
         return jax.jit(kernel)
-    in_specs = (P(None, ROW_AXIS), P(ROW_AXIS)) * n_chunks
+    in_specs = (P(None, ROW_AXIS), P(ROW_AXIS)) * n_chunks + (P(),) * n_luts
     return jax.jit(
         shard_map(
             kernel, mesh=mesh, in_specs=in_specs, out_specs=P(),
@@ -543,7 +546,8 @@ def _resident_string_bincount(table, column: str, include_null: bool, mesh):
 
 
 @lru_cache(maxsize=64)
-def _resident_topk_fn(specs: tuple, n_chunks: int, mesh, wide_rows: bool):
+def _resident_topk_fn(specs: tuple, n_chunks: int, mesh, wide_rows: bool,
+                      registers: tuple = ()):
     """The top-k summaries of several resident string columns as ONE
     program with ONE output vector: per ``(row, num_segments, kk,
     merge_null_into, variant)`` of ``specs`` its ``[num_groups, kk top
@@ -553,17 +557,36 @@ def _resident_topk_fn(specs: tuple, n_chunks: int, mesh, wide_rows: bool):
     "NullValue"), so a literal "NullValue" string and actual nulls are ONE
     bin — merging after truncation would undercount whenever one of the
     pair straddles the k boundary. Counts and ``top_k`` are int32 unless
-    the table holds 2^31 rows or more (``wide_rows``)."""
-    dtype = jnp.int64 if wide_rows else jnp.int32
+    the table holds 2^31 rows or more (``wide_rows``).
 
-    def kernel(*args):  # codes_0, rv_0, codes_1, rv_1, ...
+    ``registers`` holds per spec the HLL precision asked of that column (0:
+    none; ``()``: nobody asks). An asking column's packed (idx, rank) LUT
+    follows the chunks as a run-time argument, in spec order, so the
+    program serves every dictionary of one size; its ``2^p`` registers
+    follow its top-k in the output vector. They are the fold of the
+    entries PRESENT in the counts (``hll.registers_from_present``), read
+    BEFORE the null merge: a null must not mark a literal "NullValue"
+    entry present. One column after another: the one-hot fold is not safe
+    to batch."""
+    dtype = jnp.int64 if wide_rows else jnp.int32
+    registers = registers or (0,) * len(specs)
+
+    def kernel(*args):  # codes_0, rv_0, codes_1, rv_1, ..., lut_0, ...
+        planes, luts = args[:2 * n_chunks], iter(args[2 * n_chunks:])
         parts = []
-        for row, num_segments, kk, merge_null_into, variant in specs:
+        for (row, num_segments, kk, merge_null_into, variant), p in zip(
+                specs, registers):
             counts = _resident_counts(
-                args, row, num_segments, True, variant, dtype
+                planes, row, num_segments, True, variant, dtype
             )
             if mesh is not None:
                 counts = jax.lax.psum(counts, ROW_AXIS)
+            if p:
+                # slot 0 is the null group and never counts; the LUT is
+                # padded to a power of two past its num_segments - 1 entries
+                regs = hll.registers_from_present(
+                    next(luts)[:num_segments - 1], counts[1:] > 0, p, jnp
+                )
             if merge_null_into >= 0:
                 counts = counts.at[merge_null_into].add(counts[0])
                 counts = counts.at[0].set(0)
@@ -571,10 +594,13 @@ def _resident_topk_fn(specs: tuple, n_chunks: int, mesh, wide_rows: bool):
             with jax.named_scope("deequ.topk"):
                 top_counts, top_idx = jax.lax.top_k(counts, kk)
             parts += [num_groups[None], top_counts, top_idx.astype(dtype)]
+            if p:
+                parts.append(regs.astype(dtype))
         return jnp.concatenate(parts)
 
     return _resident_jit(
-        kernel, n_chunks, mesh, tuple(spec[4] for spec in specs)
+        kernel, n_chunks, mesh, tuple(spec[4] for spec in specs),
+        n_luts=sum(map(bool, registers)),
     )
 
 
@@ -592,14 +618,24 @@ def _null_value_slot(dictionary) -> int:
 
 
 def resident_top_k(
-    table: ColumnarTable, requests: Sequence[Tuple[str, int]], mesh=None
-) -> Optional[List["TopKCounts"]]:
+    table: ColumnarTable, requests: Sequence[Tuple[str, int]], mesh=None,
+    registers_of: Sequence[str] = (),
+) -> Optional[Tuple[List["TopKCounts"], Dict[str, np.ndarray]]]:
     """``group_top_k`` of several string columns of a persist()ed table at
     once: every column's counts, null merge, group count and top-k in ONE
     dispatch over the HBM-resident code planes and ONE fetch of ``1 + 2k``
     integers a column. ``requests`` is ``[(column, k), ...]``; None when
     the table, or one of the columns, is not resident under ``mesh`` (the
-    caller then goes column by column)."""
+    caller then goes column by column).
+
+    For each column of ``registers_of`` among the requests, the same
+    dispatch folds the HLL registers of the column out of the dictionary
+    entries PRESENT in its counts (where-free ApproxCountDistinct of a
+    string column: the registers are a function of the set of values, and
+    the counts already say which entries some row holds), and the same
+    fetch brings them: the second element of the result, ``{column:
+    registers}``. The LUT is the memoized device array the fused scan
+    gathers from."""
     if mesh is None:
         mesh = current_mesh()
     columns = [column for column, _ in requests]
@@ -608,7 +644,9 @@ def resident_top_k(
         return None
     with seam("grouping", columns=",".join(columns)):
         packer = cache.packer
-        specs = []
+        p = hll.precision_from_relative_sd()
+        asking = set(registers_of)
+        specs, precisions = [], []
         for column, k in requests:
             dictionary = table[column].dictionary
             specs.append((
@@ -616,23 +654,38 @@ def resident_top_k(
                 min(k, len(dictionary) + 1), _null_value_slot(dictionary),
                 _resolve_resident_variant(table, cache, column),
             ))
+            precisions.append(p if column in asking else 0)
+            asking.discard(column)  # once, whatever the requests repeat
+        with seam("plan", what="hll luts"):
+            luts = [
+                dictionary_lut_device(
+                    table[column].dictionary, f"hll_ir_p{p}",
+                    lambda d: hll.string_idx_rank_lut(d, p), mesh,
+                )
+                for (column, _), p_col in zip(requests, precisions) if p_col
+            ]
         fn = _resident_topk_fn(
             tuple(specs), len(cache.device_chunks), mesh,
-            table.num_rows >= (1 << 31),
+            table.num_rows >= (1 << 31), tuple(precisions) if luts else (),
         )
         with seam("dispatch", what="resident top-k"):
-            out = fn(*_resident_args(cache))
+            out = fn(*_resident_args(cache), *luts)
         with seam("fetch", what="resident top-k"):
             flat = np.asarray(out)
         _record_fetch(flat)
         SCAN_STATS.grouping_passes += len(requests)
+        SCAN_STATS.hll_presence_folds += len(luts)
         SCAN_STATS.rows_scanned += table.num_rows * len(requests)
-        results, at = [], 0
-        for (column, _), (_, _, kk, _, _) in zip(requests, specs):
+        results, registers, at = [], {}, 0
+        for (column, _), (_, _, kk, _, _), p_col in zip(
+                requests, specs, precisions):
             num_groups = int(flat[at])
             top_counts = flat[at + 1:at + 1 + kk]
             top_idx = flat[at + 1 + kk:at + 1 + 2 * kk]
             at += 1 + 2 * kk
+            if p_col:
+                registers[column] = flat[at:at + (1 << p_col)]
+                at += 1 << p_col
             dictionary = table[column].dictionary
             keep = top_counts > 0
             results.append(TopKCounts(table.num_rows, num_groups, tuple(
@@ -640,7 +693,15 @@ def resident_top_k(
                 for idx, cnt in zip(top_idx[keep].tolist(),
                                     top_counts[keep].tolist())
             )))
-        return results
+        return results, registers
+
+
+def resident_string_columns(table) -> Tuple[str, ...]:
+    """The string columns in the code planes of ``table`` as it is resident
+    under the current mesh: what ``resident_top_k`` can serve. Empty when
+    the table is not persist()ed, or under another mesh."""
+    cache = _resident_strings(table, (), current_mesh())
+    return () if cache is None else tuple(cache.packer.string_names)
 
 
 @jax.jit
@@ -974,7 +1035,7 @@ def group_top_k(
         # persisted table: counts + top-k entirely from HBM-resident codes
         resident = resident_top_k(table, [(column, k)], mesh)
         if resident is not None:
-            return resident[0]
+            return resident[0][0]
     SCAN_STATS.grouping_passes += 1
     SCAN_STATS.rows_scanned += table.num_rows
 

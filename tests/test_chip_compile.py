@@ -578,6 +578,44 @@ def test_resident_top_k_of_the_four_string_classes_compiles(as_tpu, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_resident_top_k_with_registers_folds_the_entries_present(
+        as_tpu, one_chip):
+    """The ONE pass of ``strings12m.sscan`` since PR 33: the same four
+    classes with the HLL registers asked of every column, the packed
+    (idx, rank) LUTs as run-time arguments at their padded widths. The
+    registers come from the K entries present in the counts: no gather
+    over the rows, no scatter but the three wide counts, the K-entry fold
+    on the MXU at every class (the 50k one too, under the per-row fold's
+    row floor), no 64-bit operand."""
+    from deequ_tpu.ops import hll
+    from deequ_tpu.ops.device_policy import resolve_hist_variant
+    from deequ_tpu.ops.segment import _resident_topk_fn
+
+    n = STRING_ROWS
+    p = hll.precision_from_relative_sd()
+    specs = tuple(
+        (row, card + 1, 1000, -1, resolve_hist_variant((card + 2,), rows=n))
+        for row, card in enumerate(STRING_CLASSES)
+    )
+    compiled = _resident_topk_fn(specs, 1, None, False, (p,) * 4).lower(
+        _aval((20, n), np.int32, one_chip), _aval((n,), np.bool_, one_chip),
+        *[_aval((1 << (card - 1).bit_length(),), np.int32, one_chip)
+          for card in STRING_CLASSES],
+    ).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bgather\(", text)
+    scatters = re.findall(r"= (\S+) scatter\(", text)
+    assert sorted(s.split("{")[0] for s in scatters) == sorted(
+        f"s32[{card + 2}]" for card in STRING_CLASSES[1:])
+    assert not re.search(r"\b[su]64\[", text)
+    assert _emitters(text, "deequ.bincount.onehot") == {
+        "EmitAllBatchInSublanes"}
+    assert _emitters(text, "deequ.hll.present") == {"EmitInputBatchInLanes"}
+    assert not _emitters(text, "deequ.hll.fold")
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert f"s32[{4 * (2001 + (1 << p))}]" in text  # ONE output vector
+
+
 def test_string_hll_step_compiles_with_a_gather_and_an_mxu_fold(
         as_tpu, one_chip):
     """The scan pass of ``strings12m.sscan``: ApproxCountDistinct over one

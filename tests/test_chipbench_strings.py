@@ -87,7 +87,8 @@ def test_load_cell_finds_every_file_and_the_files_state_the_cut():
         "evaluate_ms_per_suite", "unspanned_ms_per_suite",
         "hist_onehot_per_suite", "hist_scatter_per_suite",
         "grouping_passes_per_suite", "grouping_ms_per_suite",
-        "hll_folds_per_suite", "hist_wide_per_suite", "lut_builds_in_window"}
+        "hll_folds_per_suite", "hist_wide_per_suite", "lut_builds_in_window",
+        "hll_presence_folds_per_suite"}
     assert len(names) == len(set(names))
     # a suite reads every column's int32 codes once: no validity byte
     assert work.suite_bytes(config, suite, config["rows"]) == 12_500_000 * 80
@@ -101,12 +102,14 @@ def test_the_cell_runs_correct_with_the_counters_read(tiny_cell, seed):
     for name, check in result["checks"].items():
         assert check["value"] == 0, name
     c = result["layer_counters"]
-    # one fetch of the scan's result vector, one of the twenty top-k's
-    assert c["fetches_per_suite"] == 2
+    # ONE fetch since PR 33: the twenty top-k's and, beside them, the
+    # registers of the twenty columns; no scan is dispatched
+    assert c["fetches_per_suite"] == 1
     assert c["programs_built_in_window"] == 0
     assert c["lut_builds_in_window"] == 0
     assert c["grouping_passes_per_suite"] == 20
-    assert c["hll_folds_per_suite"] == 20
+    assert c["hll_folds_per_suite"] == 0
+    assert c["hll_presence_folds_per_suite"] == 20
     assert c["hist_onehot_per_suite"] + c["hist_scatter_per_suite"] == 20
     assert c["hist_wide_per_suite"] == 20  # the CPU's cap is 32 slots
     assert c["grouping_ms_per_suite"] > 0
@@ -123,8 +126,9 @@ def test_a_traced_run_reports_the_counters_and_no_device_number(tiny_cell):
     assert result["correct"] is True, result["notes"]
     metrics = result["metrics"]
     assert metrics["grouping_passes_per_suite"]["value"] == 20
-    assert metrics["hll_folds_per_suite"]["value"] == 20
-    assert metrics["fetches_per_suite"]["value"] == 2
+    assert metrics["hll_folds_per_suite"]["value"] == 0
+    assert metrics["hll_presence_folds_per_suite"]["value"] == 20
+    assert metrics["fetches_per_suite"]["value"] == 1
     assert metrics["lut_builds_in_window"]["value"] == 0
     assert metrics["grouping_ms_per_suite"]["value"] > 0
     assert "scan_hbm_roofline" not in metrics  # a CPU trace
@@ -132,9 +136,10 @@ def test_a_traced_run_reports_the_counters_and_no_device_number(tiny_cell):
 
 
 def test_every_fetch_of_a_suite_is_counted_and_waits_inside_a_seam(tiny_cell):
-    """One suite of the persisted table: the scan's fetch and ONE fetch for
-    all twenty Histograms, both under the ``fetch`` seam; the grouping seam
-    holds host time only."""
+    """One suite of the persisted table: ONE fetch for all twenty
+    Histograms and the registers of the twenty ApproxCountDistincts that
+    ride with them (PR 33: no scan is dispatched), under the ``fetch``
+    seam; the grouping seam holds host time only."""
     config, suite = tiny_cell["config"], tiny_cell["suite"]
     data = string_table.generate(ROWS, 17, config["generator_params"])
     driver = resident_topn_loop.Driver(config, tiny_cell["traffic"], suite, data)
@@ -144,15 +149,18 @@ def test_every_fetch_of_a_suite_is_counted_and_waits_inside_a_seam(tiny_cell):
     delta = {k: v - before.get(k, 0) for k, v in counters().items()}
     driver.release()
     assert not SCAN_STATS.degradation_events and answers["failed"] == []
-    assert delta["device_fetches"] == delta["seam_fetch_count"] == 2
+    assert delta["device_fetches"] == delta["seam_fetch_count"] == 1
     assert delta["seam_grouping_count"] == 1  # twenty Histograms, one pass
     assert delta["grouping_passes"] == 20
+    assert delta["hll_presence_folds"] == 20 and delta["hll_folds"] == 0
+    assert delta["scan_passes"] == 0
     assert delta["hist_scatter_dispatches"] + delta["hist_onehot_dispatches"] == 20
     assert delta["programs_built"] == 0 and delta["lut_builds"] == 0
-    # 20 x (1 + 2k) int32 of the top-k's, k = min(1000, dictionary + 1)
-    topk_bytes = 4 * sum(1 + 2 * min(DETAIL_BINS, SIZES[i % 4] + 1)
-                         for i in range(20))
-    assert delta["bytes_fetched"] > topk_bytes
+    # 20 x (1 + 2k + 512) int32: the top-k's, k = min(1000, dictionary +
+    # 1), and the registers
+    assert delta["bytes_fetched"] == 4 * sum(
+        1 + 2 * min(DETAIL_BINS, SIZES[i % 4] + 1)
+        + (1 << hll.precision_from_relative_sd()) for i in range(20))
     histograms = answers["values"][20:]
     assert all(isinstance(h, TopBins) for h in histograms)
     assert [len(h.bins) for h in histograms[:4]] == [301, 1000, 1000, 1000]
@@ -288,21 +296,24 @@ def test_the_new_counter_files_evaluate_on_a_counter_delta():
     specs = {m["name"]: m for m in cell["layer_metrics"]}
     totals = {"suites": 4, "one": 1, "grouping_passes": 80,
               "seam_grouping_seconds": 0.6, "hll_folds": 80,
+              "hll_presence_folds": 80,
               "hist_wide_dispatches": 60, "lut_builds": 0}
     ctx = {"counters": totals}
     read = lambda name: layer_metrics.evaluate(specs[name], ctx)  # noqa: E731
     assert read("grouping_passes_per_suite") == 20.0
     assert read("grouping_ms_per_suite") == pytest.approx(150.0)
     assert read("hll_folds_per_suite") == 20.0
+    assert read("hll_presence_folds_per_suite") == 20.0
     assert read("hist_wide_per_suite") == 15.0
     assert read("lut_builds_in_window") == 0.0
     # a program without the seam or the counters (the parent): nothing to
     # read, no raise
     for gone in ("seam_grouping_seconds", "hll_folds", "hist_wide_dispatches",
-                 "lut_builds"):
+                 "lut_builds", "hll_presence_folds"):
         del totals[gone]
     assert read("grouping_ms_per_suite") is None
     assert read("hll_folds_per_suite") is None
+    assert read("hll_presence_folds_per_suite") is None
     assert read("hist_wide_per_suite") is None
     assert read("lut_builds_in_window") is None
     assert read("grouping_passes_per_suite") == 20.0
