@@ -9,11 +9,23 @@ JSON object:
 
 - ``seam_ms_per_op`` / ``seam_count_per_op``: the exclusive milliseconds and
   the openings of every seam per operation of the window
-  (``SCAN_STATS.seam_*`` deltas), ``unspanned_ms_per_op`` beside them;
+  (``SCAN_STATS.seam_*`` deltas; ``fetch.copy`` is a part of ``fetch``), and
+  beside them ``run_own_ms_per_op`` (the two enclosing seams' own time),
+  ``harness_ms_per_op`` (the benchmark's span less the root seams' wall) and
+  ``unfed_ms_per_op`` (``SCAN_STATS.unfed_seconds``: seam time with nothing
+  dispatched to the device);
 - ``persist``: ``persist.pack`` / ``persist.stage`` seconds of the set-up;
 - ``idle_gap_s_by_seam``: the device's idle stretches inside the traced
   window by the innermost ``deequ.*`` host span open there (the seams are
-  ``TraceAnnotation``s on the device's clock);
+  ``TraceAnnotation``s on the device's clock; ``deequ.fetch.copy`` is a span
+  of its own inside ``deequ.fetch``);
+- ``unfed``: the check that the host's belief and the device's clock agree.
+  The feed gauge rebuilt from the trace (up at the end of each
+  ``deequ.dispatch``, down at the start of each ``deequ.fetch.copy``: every
+  fetch of a chipbench cell is its scan's last) gives the unfed stretches of
+  the ``deequ.run`` spans; ``trace_idle_ms_per_op`` is the device's idle time
+  inside them per traced operation, ``counter_ms_per_op`` the window's
+  ``unfed_ms_per_op``, ``difference_ms_per_op`` the first less the second;
 - ``host_span_s_by_seam``: the seams' own durations in the trace;
 - ``device_s_by_scope`` / ``device_s_by_family``: exclusive device seconds by
   the ``jax.named_scope`` of each XLA op (``deequ.<Analyzer>.<column>``),
@@ -160,6 +172,43 @@ def device_events_by_scope(trace_dir: str):
     return events, census, sample
 
 
+def overlap(a, b) -> list:
+    """The time two sorted lists of disjoint ``(start, end)`` share."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            out.append((lo, hi))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def unfed_stretches(host, lo: float, hi: float) -> list:
+    """The stretches of the root seams between ``lo`` and ``hi`` in which
+    the program's feed gauge stood at zero, rebuilt from the seams' spans
+    on the profiler's clock."""
+    from chipbench import trace_reduce
+
+    marks = sorted(
+        [(e, True) for n, _, e in host if n == "deequ.dispatch"]
+        + [(s, False) for n, s, _ in host if n == "deequ.fetch.copy"])
+    out, fed, at = [], False, lo
+    for t, up in marks:
+        if up and not fed:
+            out.append((at, t))
+        elif not up and fed:
+            at = t
+        fed = up
+    if not fed:
+        out.append((at, hi))
+    roots = trace_reduce.union(
+        (s, e) for n, s, e in host if n == "deequ.run")
+    return overlap(trace_reduce.clip(out, lo, hi), roots)
+
+
 def family(scope: str) -> str:
     """``deequ.Mean.c3`` -> ``Mean``; ``deequ.unpack`` -> ``unpack``."""
     parts = scope.split(".")
@@ -205,21 +254,25 @@ def main(argv=None) -> int:
                   for name in SEAM_NAMES}
     span_ms = 1000.0 * totals["run_span_seconds"] / ops
 
-    names = ["deequ." + n for n in SEAM_NAMES + ("run", "scan_attempt")]
+    names = ["deequ." + n for n in SEAM_NAMES]
     names += [trace_reduce.TRACED, window.op_name]
     trace = trace_reduce.read_xplane(tracer.dir, names)
     lo, hi = next((s, e) for n, s, e in trace["host"]
                   if n == trace_reduce.TRACED)
     device_ops = [ev for _, p in sorted(trace["devices"].items())[:1]
                   for ev in p["ops"]]
-    gaps = trace_reduce.attribute(
-        trace_reduce.idle_gaps(device_ops, lo, hi), trace["host"])
+    idle = trace_reduce.idle_gaps(device_ops, lo, hi)
+    gaps = trace_reduce.attribute(idle, trace["host"])
+    unfed = unfed_stretches(trace["host"], lo, hi)
+    seconds = lambda spans: sum(e - s for s, e in spans)  # noqa: E731
     host_spans = {}
     for n, s, e in trace["host"]:
         if s >= lo and e <= hi:
             host_spans[n] = host_spans.get(n, 0.0) + (e - s)
     traced_ops = sum(1 for n, s, e in trace["host"]
                      if n == window.op_name and s >= lo and e <= hi)
+    unfed_ms = 1000.0 * totals["unfed_seconds"] / ops
+    trace_idle_ms = 1000.0 * seconds(overlap(idle, unfed)) / max(traced_ops, 1)
     events, census, sample = device_events_by_scope(tracer.dir)
     by_scope = trace_reduce.self_seconds(events, lo, hi)
     by_family = {}
@@ -244,13 +297,20 @@ def main(argv=None) -> int:
         "host_ms_per_op": span_ms - 1000.0 * (
             totals["dispatch_seconds"] + totals["drain_wait_seconds"]) / ops,
         "seam_ms_per_op": seam_ms, "seam_count_per_op": seam_count,
-        "unspanned_ms_per_op": span_ms - sum(
-            seam_ms[n] for n in SEAM_NAMES
-            if not n.startswith("persist") and n != "grouping.host"),
+        "run_own_ms_per_op": seam_ms["run"] + seam_ms["scan_attempt"],
+        "harness_ms_per_op": span_ms - 1000.0 * totals["run_seconds"] / ops,
+        "unfed_ms_per_op": unfed_ms,
         "setup": dict(phases), "persist": persist,
         "traced": {"window_s": hi - lo, "busy_s": busy,
                    "operations": traced_ops},
         "idle_gap_s_by_seam": ordered(gaps),
+        "unfed": {
+            "counter_ms_per_op": unfed_ms,
+            "trace_unfed_span_ms_per_op": (
+                1000.0 * seconds(unfed) / max(traced_ops, 1)),
+            "trace_idle_ms_per_op": trace_idle_ms,
+            "difference_ms_per_op": trace_idle_ms - unfed_ms,
+        },
         "host_span_s_by_seam": ordered(host_spans),
         "device_s_by_family": ordered(by_family),
         "device_s_by_scope": dict(list(ordered(by_scope).items())[:40]),

@@ -10,12 +10,25 @@ seam("pack", bytes=n):`` at a layer boundary does three things:
    name is an underscore in its field. The four device-wait seams also
    feed the two older fields with what those always held:
    ``dispatch_seconds`` = ``stage`` + ``dispatch``,
-   ``drain_wait_seconds`` = ``drain`` + ``fetch``. The enclosing seams
-   (``run``, ``scan_attempt``) have no exclusive counter;
-   ``scan_attempt`` adds its whole duration to ``scan_seconds``. Only
-   caller threads count: the engine's worker threads (prefetch reader,
-   watchdog pool) mark themselves with :func:`worker_seams` and emit
-   spans only — the caller's wait for them is a seam of its own.
+   ``drain_wait_seconds`` = ``drain`` + ``fetch``; ``fetch.copy`` (the
+   copy out, nested in ``fetch`` behind the wait) feeds ``fetch``'s
+   seconds and ``drain_wait_seconds`` as well as its own, so both keep
+   what they held. The enclosing seams (``run``, ``scan_attempt``)
+   count their own exclusive seconds like any other (time under no
+   named seam) and add their whole duration to ``run_seconds`` /
+   ``scan_seconds``: over one thread, the exclusive seconds of all
+   seams under a root sum to ``run_seconds``. Only caller threads
+   count: the engine's worker threads (prefetch reader, watchdog pool)
+   mark themselves with :func:`worker_seams` and emit spans only — the
+   caller's wait for them is a seam of its own.
+
+   **The feed gauge.** Per caller thread, the device computations it
+   has dispatched (:func:`device_fed`) and does not yet know to be
+   ready (:func:`device_ready`). Every stretch of a seam's exclusive
+   time that begins with the gauge at zero — the host had given the
+   device nothing — is *unfed* time: it adds to
+   ``SCAN_STATS.unfed_seconds`` beside the seam's own seconds and rides
+   the recorder span as ``unfed_s``.
 2. **Span on the profiler's clock.** It enters
    ``jax.profiler.TraceAnnotation("deequ.<name>", **args)``: inside any
    profiler session the seam lands in the ``.xplane.pb`` beside the
@@ -302,7 +315,10 @@ class FlightRecorder:
         discipline ``result.scan_stats`` / ``retry_stats`` follow).
         ``dropped_baseline`` (the recorder's ``dropped`` captured at
         run start) makes the drop count a delta too; ``open`` counts
-        only spans opened in the window."""
+        only spans opened in the window. A phase whose seams counted
+        unfed time (the ``unfed_s`` a counting seam leaves among its
+        span's args: exclusive seconds with nothing dispatched to the
+        device) carries the sum as ``unfed_seconds``."""
         phases: Dict[str, dict] = {}
         events: Dict[str, int] = {}
         for r in self.records():
@@ -315,10 +331,17 @@ class FlightRecorder:
                 row["count"] += 1
                 if r.duration is not None:
                     row["wall_seconds"] += r.duration
+                unfed = r.args.get("unfed_s")
+                if unfed:
+                    row["unfed_seconds"] = (
+                        row.get("unfed_seconds", 0.0) + unfed
+                    )
             else:
                 events[r.name] = events.get(r.name, 0) + 1
         for row in phases.values():
-            row["wall_seconds"] = round(row["wall_seconds"], 6)
+            for key in ("wall_seconds", "unfed_seconds"):
+                if key in row:
+                    row[key] = round(row[key], 6)
         open_spans = [
             s for s in self.open_spans()
             if since is None or s.t_start >= since
@@ -476,22 +499,25 @@ def resolve_recorder(trace=None) -> Optional[FlightRecorder]:
 #: every counted seam, in pipeline order (docs/observability.md has the
 #: table: layer, where it is emitted, the metric that reads it)
 SEAM_NAMES = (
+    "run", "scan_attempt",
     "plan", "build", "pack", "stage", "dispatch", "drain", "fetch",
+    "fetch.copy",
     "states", "evaluate", "sketch_fold", "grouping", "repository",
     "persist.pack", "persist.stage", "grouping.host",
 )
-#: seams whose exclusive seconds ALSO feed an older ScanStats field,
-#: with exactly what that field always held
-_LEGACY_FIELD = {
-    "stage": "dispatch_seconds",
-    "dispatch": "dispatch_seconds",
-    "drain": "drain_wait_seconds",
-    "fetch": "drain_wait_seconds",
+#: seams whose exclusive seconds ALSO feed older ScanStats fields, with
+#: exactly what those fields always held (``fetch.copy`` is the part of
+#: a fetch behind its wait: ``seam_fetch_seconds`` keeps the whole)
+_LEGACY_FIELDS = {
+    "stage": ("dispatch_seconds",),
+    "dispatch": ("dispatch_seconds",),
+    "drain": ("drain_wait_seconds",),
+    "fetch": ("drain_wait_seconds",),
+    "fetch.copy": ("seam_fetch_seconds", "drain_wait_seconds"),
 }
-#: enclosing seams: no exclusive counter (their own time is what no
-#: named seam accounts for); the value is the field their WHOLE
-#: duration adds to, if any
-_ENCLOSING = {"run": None, "scan_attempt": "scan_seconds"}
+#: enclosing seams: their exclusive seconds are the time under no named
+#: seam; the value is the field their WHOLE duration adds to
+_ENCLOSING = {"run": "run_seconds", "scan_attempt": "scan_seconds"}
 #: the ids an enclosing seam hands down to the seams under it
 _ID_ARGS = ("run_id", "scan_id")
 _UNSET = object()
@@ -505,9 +531,11 @@ def seam_fields(name: str):
     return stem + "_seconds", stem + "_count"
 
 
-#: name -> (seconds field, count field, legacy field or None)
+#: name -> (seconds field, count field, older fields fed, wall field)
 _COUNTED = {
-    name: seam_fields(name) + (_LEGACY_FIELD.get(name),)
+    name: seam_fields(name) + (
+        _LEGACY_FIELDS.get(name, ()), _ENCLOSING.get(name),
+    )
     for name in SEAM_NAMES
 }
 
@@ -519,15 +547,31 @@ _SEAM_TLS = threading.local()
 
 class _ThreadSeams:
     """One thread's seam state: the innermost open counted seam, whether
-    the thread is an engine worker (spans only), and the ids handed
-    down by the enclosing seams."""
+    the thread is an engine worker (spans only), the ids handed down by
+    the enclosing seams, and the feed gauge: how many device
+    computations the thread has dispatched (``fed``) and how many of
+    them it knows to be ready (``ready``)."""
 
-    __slots__ = ("open", "worker", "ids")
+    __slots__ = ("open", "worker", "ids", "fed", "ready")
 
     def __init__(self):
         self.open = None
         self.worker = False
         self.ids = None
+        self.fed = 0
+        self.ready = 0
+
+    def cut(self, unfed: bool) -> None:
+        """The gauge leaves or reaches zero: close the open seam's
+        stretch here, as the unfed or fed time it was."""
+        seam_ = self.open
+        if seam_ is not None:
+            now = time.perf_counter()
+            stretch = now - seam_._resumed
+            seam_._own += stretch
+            if unfed:
+                seam_._unfed += stretch
+            seam_._resumed = now
 
 
 def _thread_seams() -> _ThreadSeams:
@@ -548,6 +592,55 @@ def seam_ids() -> Dict[str, Any]:
     """The ``run_id`` / ``scan_id`` in force on this thread (what a
     worker-thread scope should be seeded with)."""
     return dict(_thread_seams().ids or {})
+
+
+# -- the feed gauge ----------------------------------------------------------
+
+
+def device_fed() -> None:
+    """This thread enqueued one device computation (a step, a fold
+    merge, an own-pass kernel; a transfer is none: the device computes
+    nothing during a put)."""
+    state = _thread_seams()
+    if not state.worker:
+        if state.fed == state.ready:
+            state.cut(unfed=True)
+        state.fed += 1
+
+
+def fed_mark():
+    """The newest dispatch of this thread, for the :func:`device_ready`
+    of a result fetched later (a deferred scan keeps it)."""
+    state = _thread_seams()
+    return state, state.fed
+
+
+def device_ready(mark=True) -> None:
+    """A wait on this thread proved the dispatch ``mark`` stands for (a
+    :func:`fed_mark`; True: the thread's newest) ready, and every one
+    before it: the device runs them in order. Where that is the newest,
+    the gauge is at zero from here. A mark taken on another thread says
+    nothing here."""
+    state = _thread_seams()
+    if state.worker:
+        return
+    if mark is True:
+        upto = state.fed
+    elif mark[0] is state:
+        upto = mark[1]
+    else:
+        return
+    if upto > state.ready:
+        if upto == state.fed:
+            state.cut(unfed=False)
+        state.ready = upto
+
+
+def feed_gauge() -> int:
+    """Device computations this thread has dispatched and does not yet
+    know to be ready."""
+    state = _thread_seams()
+    return state.fed - state.ready
 
 
 @contextmanager
@@ -574,7 +667,7 @@ class seam:
     seam inside traced code is a host callback (``span-in-jit``)."""
 
     __slots__ = ("name", "args", "_state", "_t0", "_resumed", "_own",
-                 "_outer", "_annotation", "_span", "_ids_before")
+                 "_unfed", "_outer", "_annotation", "_span", "_ids_before")
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -589,9 +682,12 @@ class seam:
             now = time.perf_counter()
             self._outer = outer = state.open
             if outer is not None:
-                outer._own += now - outer._resumed
+                stretch = now - outer._resumed
+                outer._own += stretch
+                if state.fed == state.ready:
+                    outer._unfed += stretch
             state.open = self
-            self._own = 0.0
+            self._own = self._unfed = 0.0
             self._t0 = self._resumed = now
         return self
 
@@ -615,30 +711,36 @@ class seam:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         state = self._state
+        unfed = 0.0
         if not state.worker:
             now = time.perf_counter()
             outer = self._outer
             state.open = outer
             if outer is not None:
                 outer._resumed = now
+            stretch = now - self._resumed
+            own = self._own + stretch
+            unfed = self._unfed
+            if state.fed == state.ready:
+                unfed += stretch
             stats = _STATS
             if stats is not None:
-                counted = _COUNTED.get(self.name)
-                if counted is not None:
-                    own = self._own + (now - self._resumed)
-                    seconds, count, legacy = counted
-                    setattr(stats, seconds, getattr(stats, seconds) + own)
-                    setattr(stats, count, getattr(stats, count) + 1)
-                    if legacy is not None:
-                        setattr(stats, legacy, getattr(stats, legacy) + own)
-                else:
-                    total = _ENCLOSING[self.name]  # KeyError: no such seam
-                    if total is not None:
-                        setattr(stats, total,
-                                getattr(stats, total) + (now - self._t0))
+                # KeyError: no such seam
+                seconds, count, older, wall = _COUNTED[self.name]
+                setattr(stats, seconds, getattr(stats, seconds) + own)
+                setattr(stats, count, getattr(stats, count) + 1)
+                for name in older:
+                    setattr(stats, name, getattr(stats, name) + own)
+                if wall is not None:
+                    setattr(stats, wall,
+                            getattr(stats, wall) + (now - self._t0))
+                if unfed:
+                    stats.unfed_seconds += unfed
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
             if self._span is not None:
+                if unfed:
+                    self._span.record.args["unfed_s"] = unfed
                 self._span.__exit__(exc_type, exc, tb)
             if self._ids_before is not _UNSET:
                 state.ids = self._ids_before

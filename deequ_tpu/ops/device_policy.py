@@ -47,7 +47,12 @@ from deequ_tpu.exceptions import (
     DeviceHangException,
     classify_device_error,
 )
-from deequ_tpu.obs.recorder import seam, worker_seams
+from deequ_tpu.obs.recorder import (
+    device_fed,
+    device_ready,
+    seam,
+    worker_seams,
+)
 
 # -- fault-injection seam ----------------------------------------------------
 
@@ -328,10 +333,18 @@ def device_call(
     deadline: Optional[float] = None,
     hook_ctx: Optional[Dict[str, Any]] = None,
     seam_name: Optional[str] = None,
+    newest=False,
     **span_args,
 ):
     """Run one device-boundary call under classification (+ optional
     watchdog + optional fault injection), inside the boundary's seam.
+
+    The feed gauge (obs/recorder.py) moves here, on the caller's thread:
+    an ``execute`` call that enqueued something (``dispatch`` / ``build``;
+    a ``drain`` only waits, on an older result) feeds the device once it
+    returns; a ``fetch`` whose result is the scan's last names the
+    dispatch it proves ready in ``newest`` (a :func:`fed_mark`, or True
+    for the thread's newest). A ``transfer`` feeds nothing.
 
     Raw jaxlib/XLA failures re-raise as their typed DeviceException (with
     ``__cause__`` preserved); non-device errors propagate untouched.
@@ -351,14 +364,21 @@ def device_call(
             hook(boundary, hook_ctx)
         return fn()
 
-    with seam(
-        seam_name or _BOUNDARY_SEAM[boundary],
-        boundary=boundary, what=what, **span_args,
-    ):
+    name = seam_name or _BOUNDARY_SEAM[boundary]
+    with seam(name, boundary=boundary, what=what, **span_args):
         try:
             if deadline is not None:
-                return _call_with_deadline(body, deadline, what, boundary)
-            return body()
+                value = _call_with_deadline(body, deadline, what, boundary)
+            else:
+                value = body()
+            if boundary == "execute" and name != "drain":
+                device_fed()
+            elif newest:
+                # without a watchdog wait_then_copy lowered the gauge
+                # where the wait ended; under one the body ran on a
+                # pooled thread and the whole fetch counted as fed
+                device_ready(newest)
+            return value
         except DeviceException:
             raise
         except Exception as e:  # noqa: BLE001 — classified below;
@@ -368,6 +388,48 @@ def device_call(
             if typed is not None:
                 raise typed from e
             raise
+
+
+def wait_then_copy(result, newest=False):
+    """The body of every fetch, inside its ``fetch`` seam: wait for the
+    device, then copy the result (an array or a pytree of them) to the
+    host under the child seam ``fetch.copy`` (what is left of the copy
+    once the result is ready). The device works through the first and
+    stands through the second; where the result is its
+    scan's last (``newest``, as :func:`device_call` takes it) the gauge
+    drops between the two, so the copy is unfed time."""
+    import jax
+    import numpy as np
+
+    leaves = jax.tree.leaves(result)
+    # ask for the copy BEFORE waiting, as a bare np.asarray does: it then
+    # starts on the device the moment the result is ready. Asked for
+    # behind the wait it costs one more host round trip (0.5 ms a scan
+    # suite on the chip: PERF.md section 6, PR 34)
+    for leaf in leaves:
+        leaf.copy_to_host_async()
+    jax.block_until_ready(result)
+    if newest:
+        device_ready(newest)
+    with seam("fetch.copy", bytes=sum(int(a.nbytes) for a in leaves)):
+        return jax.tree.map(np.asarray, result)
+
+
+def device_fetch(
+    result,
+    what: str,
+    deadline: Optional[float] = None,
+    newest=False,
+):
+    """One device->host fetch at the ``fetch`` boundary: classification
+    and the watchdog as :func:`device_call` gives them, the wait and the
+    copy told apart as :func:`wait_then_copy` does. Under an armed
+    ``deadline`` the body runs on a pooled thread, whose seams are spans
+    only: the caller's ``fetch`` then holds all of it, unsplit."""
+    return device_call(
+        lambda: wait_then_copy(result, newest), "fetch", what=what,
+        deadline=deadline, newest=newest,
+    )
 
 
 # -- backend health ----------------------------------------------------------

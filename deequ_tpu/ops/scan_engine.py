@@ -54,6 +54,9 @@ from deequ_tpu.obs.recorder import (
     SEAM_NAMES,
     bind_seam_counters,
     current_recorder,
+    device_fed,
+    device_ready,
+    fed_mark,
     maybe_arm_from_env,
     recording_scope,
     resolve_recorder,
@@ -70,6 +73,8 @@ from deequ_tpu.ops.device_policy import (
     _call_with_deadline,
     default_shard_deadline,
     device_call,
+    device_fetch,
+    wait_then_copy,
     install_scan_fault_hook,  # noqa: F401 — re-exported: the seam lives here
 )
 from deequ_tpu.parallel.mesh import (
@@ -336,9 +341,15 @@ class ScanStats:
         # device compute + any in-flight transfer not hidden by the
         # pipeline window. Both are written by the seams below:
         # dispatch_seconds = stage + dispatch, drain_wait_seconds =
-        # drain + fetch; scan_seconds is the scan_attempt seams' wall.
+        # drain + fetch; scan_seconds is the scan_attempt seams' wall
+        # and run_seconds the root seams' (a verification run's).
         self.dispatch_seconds = 0.0
         self.drain_wait_seconds = 0.0
+        self.run_seconds = 0.0
+        # exclusive seam seconds that began with the caller thread's
+        # feed gauge at zero: the host had dispatched nothing the device
+        # could still be computing (obs/recorder.py:device_fed)
+        self.unfed_seconds = 0.0
         # every duration the engine takes (obs/recorder.py:seam): the
         # EXCLUSIVE seconds of each seam and how often it opened, as
         # flat numbers so a counter snapshot carries them
@@ -544,17 +555,23 @@ class ScanStats:
         with self._fetch_lock:
             self.subplan_cache_hits += int(n)
 
-    def record_staged(self, nbytes: int, overlapped: bool) -> None:
+    def record_staged(
+        self, nbytes: int, overlapped: bool, count_chunk: bool = True
+    ) -> None:
         """Account one HOST->DEVICE chunk staging (the double-buffered
         transfers of the packing loops). Staging is the opposite
         direction from a fetch — it never counts against the one-fetch
         contract; ``overlapped`` marks transfers issued while the
         previous chunk was still staged-undispatched (see
-        ``ingest_overlap_frac``)."""
+        ``ingest_overlap_frac``). A put that is no chunk's (the device
+        fold's fresh accumulator) adds its bytes only
+        (``count_chunk=False``): the overlap ratio is one of CHUNK
+        transfers."""
         self.bytes_staged += int(nbytes)
-        self.chunks_staged += 1
-        if overlapped:
-            self.chunks_staged_overlapped += 1
+        if count_chunk:
+            self.chunks_staged += 1
+            if overlapped:
+                self.chunks_staged_overlapped += 1
 
     def record_degradation(self, kind: str, **detail) -> dict:
         """Append one degradation decision (kind: 'oom_bisect' |
@@ -1760,8 +1777,17 @@ class _DeviceFoldPlan:
 
     def fresh_init(self):
         """A NEW device accumulator (never reuse one across scans: the
-        first merge donates it)."""
-        return jnp.asarray(self._init_np)
+        first merge donates it): a host->device put like a chunk's, so
+        under the ``stage`` seam and in ``bytes_staged`` (on the caller's
+        thread whatever the watchdog: the default device is the
+        thread's)."""
+        nbytes = self._init_np.nbytes
+        acc = device_call(
+            lambda: jnp.asarray(self._init_np), "transfer",
+            what="fold accumulator", bytes=nbytes,
+        )
+        SCAN_STATS.record_staged(nbytes, overlapped=False, count_chunk=False)
+        return acc
 
     def merge_body(self, acc, new):
         """Pure traced merge: fold one chunk's flat vector into the
@@ -1879,18 +1905,20 @@ class _PartialFolder:
         # device round trip, the watchdog's prime target
         self.deadline = deadline
 
-    def drain(self, device_result) -> None:
+    def drain(self, device_result, newest=False) -> None:
         # host-side slices (fetch_deferred hands those out) are already
         # materialized: only a true device array is a fetch. Async device
         # failures (OOM, device loss) surface HERE: device_call classifies
         # them once, so every drain path (inline, deferred, grouped)
-        # raises typed, and a hung device becomes DeviceHangException
+        # raises typed, and a hung device becomes DeviceHangException.
+        # ``newest`` names the dispatch this result proves ready when it
+        # is its scan's last (device_policy.device_call): the feed gauge
+        # drops where its wait ends
         if isinstance(device_result, np.ndarray):
             flat = device_result
         else:
-            flat = device_call(
-                lambda: np.asarray(device_result), "fetch",
-                what="scan drain", deadline=self.deadline,
+            flat = device_fetch(
+                device_result, "scan drain", self.deadline, newest,
             )
             SCAN_STATS.record_fetch(flat.nbytes)
         with seam("evaluate", what="fold"):
@@ -1956,6 +1984,9 @@ class DeferredScan:
         # scans in flight it would double-count in scan_seconds
         self._inline = inline
         self._scan_id = scan_id
+        # the thread's newest dispatch is this scan's last: what the
+        # last of the pending results proves ready when it is fetched
+        self._fed_mark = fed_mark()
         self._done = False
         self._error: Optional[BaseException] = None
 
@@ -1971,8 +2002,11 @@ class DeferredScan:
                           deferred=True)
             ):
                 try:
-                    for device_result in pending:
-                        self._folder.drain(device_result)
+                    for i, device_result in enumerate(pending, 1):
+                        self._folder.drain(
+                            device_result,
+                            i == len(pending) and self._fed_mark,
+                        )
                     for lease in leases:
                         lease.release()
                 except BaseException as e:  # noqa: BLE001 — a retry must
@@ -2029,12 +2063,14 @@ def _fetch_deferred(pending: Sequence["DeferredScan"]) -> None:
         default_device_deadline(),
     )
 
+    # the newest dispatch among the scans': what this fetch proves ready
+    newest = max((s._fed_mark for s in pending), key=lambda mark: mark[1])
+
     def materialize():
-        if len(arrays) == 1:
-            return [np.asarray(arrays[0])]
-        if not same_device:
-            return [np.asarray(a) for a in arrays]
-        host = np.asarray(jnp.concatenate(arrays))  # the one round trip
+        if len(arrays) == 1 or not same_device:
+            return wait_then_copy(arrays, newest)
+        # the one round trip
+        host = wait_then_copy(jnp.concatenate(arrays), newest)
         parts = []
         off = 0
         for a in arrays:
@@ -2048,6 +2084,7 @@ def _fetch_deferred(pending: Sequence["DeferredScan"]) -> None:
     # this blocking fetch must become DeviceHangException, not a freeze)
     parts = device_call(
         materialize, "fetch", what="deferred scan fetch", deadline=deadline,
+        newest=newest,
     )
     for s in pending:  # every pending result is on the host: all ran
         leases, s._leases = s._leases, ()
@@ -2265,12 +2302,18 @@ def _governed_attempt(budget, fn: Callable, what: str):
             return fn()
 
     # the attempt's own seams run on the watchdog worker as spans only;
-    # what this thread spends is the wait for it
+    # what this thread spends is the wait for it. The worker feeds the
+    # device where this thread cannot see: the wait counts as fed (no
+    # unfed time is claimed for a governed attempt)
     with seam("drain", what=what, governed=True):
-        return _call_with_deadline(
-            governed_fn, max(wall_left, MIN_BUDGET_WATCHDOG_SECONDS), what,
-            "execute",
-        )
+        device_fed()
+        try:
+            return _call_with_deadline(
+                governed_fn, max(wall_left, MIN_BUDGET_WATCHDOG_SECONDS),
+                what, "execute",
+            )
+        finally:
+            device_ready()
 
 
 def run_scan(
@@ -2735,7 +2778,10 @@ def _run_scan_once(
                 )
                 held.pop(0).release()
         elif len(in_flight) >= window:
-            folder.drain(in_flight.pop(0))
+            # an OLDER result while newer chunks are in flight: the gauge
+            # stays up (at a window of one it is the newest)
+            oldest = in_flight.pop(0)
+            folder.drain(oldest, not in_flight)
             held.pop(0).release()
 
     if cache is not None:
@@ -2862,6 +2908,7 @@ class DeferredGroupScan:
     def __init__(self, device_out, folders):
         self._device_out = device_out
         self._folders = folders
+        self._fed_mark = fed_mark()  # the group's one dispatch
         self._results: Optional[list] = None
         self._done = False
         self._error: Optional[BaseException] = None
@@ -2875,9 +2922,11 @@ class DeferredGroupScan:
             with seam("scan_attempt", deferred=True,
                       scans=len(self._folders)):
                 try:
-                    with seam("fetch"):
-                        # the one round trip
-                        host = np.asarray(self._device_out)
+                    # the one round trip
+                    host = device_fetch(
+                        self._device_out, "group scan fetch",
+                        newest=self._fed_mark,
+                    )
                     SCAN_STATS.record_fetch(host.nbytes)
                     out = []
                     for k, folder in enumerate(self._folders):
@@ -3096,6 +3145,7 @@ def run_scan_group(
     # one enqueue here (a program's first call also traces and compiles)
     with seam("build" if cached is None else "dispatch", tables=K):
         device_out = vstep(*bufs, lut_stacked)
+        device_fed()
     # the kernel census, once per table in the stack
     _record_kernel_passes(plan_ir, K)
 
@@ -3419,7 +3469,8 @@ def _run_scan_stream(
             in_flight.append(flat)
             held.append(lease)
             if len(in_flight) >= window:
-                folder.drain(in_flight.pop(0))
+                oldest = in_flight.pop(0)
+                folder.drain(oldest, not in_flight)
                 held.pop(0).release()
 
     def drain_fold() -> None:
@@ -3427,7 +3478,8 @@ def _run_scan_stream(
             return
         folder.fold_plan = fold_state["plan"]
         folder.fold_filled = fold_state["filled"]
-        folder.drain(fold_state["acc"])
+        # the accumulator is the newest dispatch's (the last merge's)
+        folder.drain(fold_state["acc"], True)
         # the fetched accumulator had merged every chunk dispatched so far
         # (release is idempotent: their throttle pops find nothing left)
         for lease in held:
@@ -3593,8 +3645,8 @@ def _run_scan_stream(
     if use_fold:
         drain_fold()  # the (usually only) fetch of the whole stream scan
     else:
-        for device_result in in_flight:
-            folder.drain(device_result)
+        for i, device_result in enumerate(in_flight, 1):
+            folder.drain(device_result, i == len(in_flight))
         for lease in held:
             lease.release()
     return folder.merged

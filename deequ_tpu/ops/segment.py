@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from deequ_tpu.data.table import Column, ColumnarTable, DType
 from deequ_tpu.obs.recorder import seam
 from deequ_tpu.ops import hll
+from deequ_tpu.ops.device_policy import device_call, device_fetch
 from deequ_tpu.ops.lut_cache import dictionary_lut_device
 from deequ_tpu.ops.scan_engine import SCAN_STATS
 from deequ_tpu.parallel.mesh import ROW_AXIS, current_mesh, shard_map
@@ -541,8 +542,10 @@ def _resident_string_bincount(table, column: str, include_null: bool, mesh):
         packer.string_names.index(column), include_null, mesh,
         _resolve_resident_variant(table, cache, column),
     )
-    with seam("dispatch", what="resident bincount"):
-        return fn(*_resident_args(cache))
+    return device_call(
+        lambda: fn(*_resident_args(cache)), "execute",
+        what="resident bincount",
+    )
 
 
 @lru_cache(maxsize=64)
@@ -668,10 +671,12 @@ def resident_top_k(
             tuple(specs), len(cache.device_chunks), mesh,
             table.num_rows >= (1 << 31), tuple(precisions) if luts else (),
         )
-        with seam("dispatch", what="resident top-k"):
-            out = fn(*_resident_args(cache), *luts)
-        with seam("fetch", what="resident top-k"):
-            flat = np.asarray(out)
+        out = device_call(
+            lambda: fn(*_resident_args(cache), *luts), "execute",
+            what="resident top-k",
+        )
+        # the own pass's one fetch: its dispatch is the thread's newest
+        flat = device_fetch(out, "resident top-k", newest=True)
         _record_fetch(flat)
         SCAN_STATS.grouping_passes += len(requests)
         SCAN_STATS.hll_presence_folds += len(luts)
@@ -1154,10 +1159,11 @@ def group_count_stats(
             resident = _resident_string_bincount(
                 table, columns[0], not require_any_non_null, mesh
             )
-            with seam("dispatch", what="resident count stats"):
-                out = _stats_from_counts(resident)
-            with seam("fetch", what="resident count stats"):
-                stats = np.asarray(out)
+            out = device_call(
+                lambda: _stats_from_counts(resident), "execute",
+                what="resident count stats",
+            )
+            stats = device_fetch(out, "resident count stats", newest=True)
             _record_fetch(stats)
             total, groups, singles = (int(x) for x in stats[:3])
             return CountStats(

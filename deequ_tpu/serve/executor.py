@@ -47,7 +47,7 @@ from deequ_tpu.ops.scan_engine import (
     _scoped_update,
     _split_lut_key,
 )
-from deequ_tpu.ops.device_policy import device_call
+from deequ_tpu.ops.device_policy import device_call, device_fetch
 
 
 def _member_packer(plan, table) -> _ChunkPacker:
@@ -501,14 +501,11 @@ def _run_coalesced(
         deadline=device_deadline, hook_ctx=hook_ctx,
     )
 
-    def fetch() -> np.ndarray:
-        host = np.asarray(device_out)  # the batch's ONE round trip
-        SCAN_STATS.record_fetch(host.nbytes)
-        return host
-
-    host = device_call(
-        fetch, "fetch", what="coalesced drain", deadline=device_deadline,
+    # the batch's ONE round trip: its dispatch is this thread's newest
+    host = device_fetch(
+        device_out, "coalesced drain", device_deadline, newest=True,
     )
+    SCAN_STATS.record_fetch(host.nbytes)
     out: List[List[Any]] = []
     for k in range(K):  # padding slices [K:] are discarded
         canonical = _unflatten_member(host[k], recipes)

@@ -77,7 +77,10 @@ def test_load_cell_finds_every_file_of_the_cell():
         "evaluate_ms_per_suite", "unspanned_ms_per_suite",
         "select_passes_per_suite", "sort_passes_per_suite",
         "sketch_fold_ms_per_suite", "summaries_folded_per_suite",
-        "hist_onehot_per_suite", "hist_scatter_per_suite"}
+        "hist_onehot_per_suite", "hist_scatter_per_suite",
+        "unfed_ms_per_suite", "fetch_copy_ms_per_suite",
+        "run_own_ms_per_suite", "harness_ms_per_suite",
+        "staged_mb_per_suite", "idle_while_fed_ms_per_suite"}
     assert len(names) == len(set(names))
     # the selection step reads the suite's bytes once: 50 nullable f64 columns
     from chipbench import work

@@ -49,7 +49,7 @@ def test_load_cell_finds_every_file_of_the_cell():
     assert {"sharded_scan_hbm_roofline", "chunks_per_suite",
             "collectives_per_suite", "plane_ops_per_suite"} <= set(names)
     assert "scan_hbm_roofline" not in names and "pack_ms_per_suite" not in names
-    assert len(names) == len(set(names)) == 13
+    assert len(names) == len(set(names)) == 19
     # what the contract holds a cell to: one four-chip cell always may
     four = [w for w in bench["workloads"] if w["chips"] == 4]
     assert [w["name"] for w in four] == [CELL]

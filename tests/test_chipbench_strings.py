@@ -88,7 +88,10 @@ def test_load_cell_finds_every_file_and_the_files_state_the_cut():
         "hist_onehot_per_suite", "hist_scatter_per_suite",
         "grouping_passes_per_suite", "grouping_ms_per_suite",
         "hll_folds_per_suite", "hist_wide_per_suite", "lut_builds_in_window",
-        "hll_presence_folds_per_suite"}
+        "hll_presence_folds_per_suite",
+        "unfed_ms_per_suite", "fetch_copy_ms_per_suite",
+        "run_own_ms_per_suite", "harness_ms_per_suite",
+        "idle_while_fed_ms_per_suite"}
     assert len(names) == len(set(names))
     # a suite reads every column's int32 codes once: no validity byte
     assert work.suite_bytes(config, suite, config["rows"]) == 12_500_000 * 80

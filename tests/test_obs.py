@@ -272,7 +272,8 @@ def _seam_counters():
     from deequ_tpu.obs import seam_fields
 
     fields = [f for name in SEAM_NAMES for f in seam_fields(name)]
-    fields += ["dispatch_seconds", "drain_wait_seconds", "scan_seconds"]
+    fields += ["dispatch_seconds", "drain_wait_seconds", "scan_seconds",
+               "run_seconds", "unfed_seconds"]
     return {f: getattr(SCAN_STATS, f) for f in fields}
 
 
@@ -297,38 +298,55 @@ def test_seam_fields_are_numbers_straight_after_reset(name):
     assert type(snap[count]) is int and snap[count] == 0
 
 
+def _exclusive_seconds(d):
+    """Every seam's exclusive seconds, each second once: ``fetch.copy``
+    feeds ``seam_fetch_seconds`` too and is a part of it."""
+    return sum(
+        v for k, v in d.items()
+        if k.startswith("seam_") and k.endswith("_seconds")
+    ) - d["seam_fetch_copy_seconds"]
+
+
 def test_seam_exclusive_accounting_sums_to_the_root():
     """While a child seam is open the parent's clock stands still: the
-    exclusive seconds of nested seams on one thread sum to the outermost
-    seam's duration, and an enclosing seam bills its whole wall."""
+    exclusive seconds of nested seams on one thread, the two enclosing
+    ones included, sum to the root seam's wall exactly, and an enclosing
+    seam bills its whole wall beside its own time."""
     import time
 
     from deequ_tpu.obs import seam
 
     before = _seam_counters()
     t0 = time.perf_counter()
-    with seam("scan_attempt", scan_id=-1):
-        with seam("plan"):
-            time.sleep(0.010)
-            with seam("pack", chunk=0):
+    with seam("run", run_id=-1):
+        time.sleep(0.002)
+        with seam("scan_attempt", scan_id=-1):
+            with seam("plan"):
                 time.sleep(0.010)
-                with seam("stage"):
-                    time.sleep(0.005)
+                with seam("pack", chunk=0):
+                    time.sleep(0.010)
+                    with seam("stage"):
+                        time.sleep(0.005)
+            time.sleep(0.002)
+            with seam("fetch"):
+                with seam("fetch.copy", bytes=8):
+                    time.sleep(0.002)
             with seam("evaluate"):
                 time.sleep(0.005)
     wall = time.perf_counter() - t0
     d = _seam_deltas(before)
-    counted = sum(
-        v for k, v in d.items()
-        if k.startswith("seam_") and k.endswith("_seconds")
-    )
-    # the seams share their timestamps: counting a second twice would put
-    # the sum over the root's wall, whatever the machine's load. What the
-    # sum lacks is the enclosing seam's own few microseconds
+    # the seams share their timestamps: the sum IS the root's wall (a
+    # second counted twice, or the root's own time dropped, would show
+    # whatever the machine's load)
+    assert _exclusive_seconds(d) == pytest.approx(d["run_seconds"], abs=1e-9)
+    assert d["seam_run_seconds"] >= 0.002 and d["seam_run_count"] == 1
+    assert d["seam_scan_attempt_seconds"] >= 0.002
+    assert d["seam_scan_attempt_count"] == 1
+    assert d["seam_fetch_copy_seconds"] >= 0.002
+    assert d["seam_fetch_seconds"] >= d["seam_fetch_copy_seconds"]
+    assert d["seam_fetch_count"] == d["seam_fetch_copy_count"] == 1
     root = d["scan_seconds"]
-    assert counted <= root + 1e-9, (counted, root)
-    assert root - counted < 2.5e-3, (counted, root)
-    assert 0.030 <= root <= wall
+    assert 0.034 <= root <= d["run_seconds"] - 0.002 <= wall
     # each seam kept its OWN time only: at least its sleep, and no more
     # than the root's wall less the sleeps that ran under the other seams
     # (no ceiling on the sleeps themselves: a loaded machine oversleeps)
@@ -339,7 +357,9 @@ def test_seam_exclusive_accounting_sums_to_the_root():
     assert d["seam_plan_count"] == d["seam_pack_count"] == 1
     # the older fields are the sums they are defined as
     assert d["dispatch_seconds"] == pytest.approx(d["seam_stage_seconds"])
-    assert d["drain_wait_seconds"] == 0.0
+    assert d["drain_wait_seconds"] == pytest.approx(d["seam_fetch_seconds"])
+    # nothing was dispatched: every second of it was unfed
+    assert d["unfed_seconds"] == pytest.approx(d["run_seconds"], abs=1e-9)
 
 
 def test_unknown_seam_name_is_an_error():
@@ -392,6 +412,271 @@ def test_watchdog_call_counts_once_on_the_caller():
     d = _seam_deltas(before)
     assert d["seam_fetch_count"] == 1
     assert d["drain_wait_seconds"] == pytest.approx(d["seam_fetch_seconds"])
+
+
+# -- the feed gauge, fetch.copy, the accumulator's put ------------------------
+
+
+@pytest.fixture
+def gauge_at_zero():
+    """The gauge is the thread's: a test starts and must end with every
+    dispatch known ready."""
+    from deequ_tpu.obs.recorder import device_ready, feed_gauge
+
+    device_ready()
+    yield
+    assert feed_gauge() == 0
+    device_ready()
+
+
+def test_fed_and_unfed_stretches_add_up_to_the_seams_own_seconds(
+        gauge_at_zero):
+    """A seam that straddles a dispatch counts only the stretch before
+    it as unfed; one that straddles the proof of readiness only the
+    stretch behind it; a child's time is the child's."""
+    import time
+
+    from deequ_tpu.obs import seam
+    from deequ_tpu.obs.recorder import device_fed, device_ready, feed_gauge
+
+    rec = FlightRecorder()
+    before = _seam_counters()
+    with recording_scope(rec):
+        with seam("dispatch"):
+            time.sleep(0.004)          # unfed: nothing enqueued yet
+            device_fed()
+            assert feed_gauge() == 1
+            time.sleep(0.006)          # fed
+        with seam("evaluate"):
+            time.sleep(0.003)          # fed: the host overlaps the device
+            with seam("sketch_fold"):
+                time.sleep(0.003)      # the child's, fed
+        with seam("fetch"):
+            time.sleep(0.004)          # fed: the wait
+            device_ready()
+            assert feed_gauge() == 0
+            with seam("fetch.copy", bytes=8):
+                time.sleep(0.005)      # unfed: the device stands
+    d = _seam_deltas(before)
+    (dispatch,) = _spans(rec, "dispatch")
+    (fetch,) = _spans(rec, "fetch")
+    (copy,) = _spans(rec, "fetch.copy")
+    assert 0.004 <= dispatch.args["unfed_s"] <= d["seam_dispatch_seconds"] - 0.006
+    assert copy.args["unfed_s"] == pytest.approx(
+        d["seam_fetch_copy_seconds"], abs=1e-9)
+    assert copy.args["unfed_s"] >= 0.005
+    # the wait itself was fed: what fetch holds unfed is its few
+    # microseconds around the copy
+    assert fetch.args.get("unfed_s", 0.0) < 0.004
+    for name in ("evaluate", "sketch_fold"):
+        (span,) = _spans(rec, name)
+        assert "unfed_s" not in span.args
+    unfed = sum(r.args.get("unfed_s", 0.0) for r in _spans(rec))
+    assert d["unfed_seconds"] == pytest.approx(unfed, abs=1e-9)
+    fed = _exclusive_seconds(d) - d["unfed_seconds"]
+    assert fed >= 0.006 + 0.003 + 0.003 + 0.004
+    phases = rec.summary()["phases"]
+    assert phases["fetch.copy"]["unfed_seconds"] == pytest.approx(
+        copy.args["unfed_s"], abs=1e-6)
+    assert "unfed_seconds" not in phases["evaluate"]
+
+
+def test_a_throttle_on_an_older_result_leaves_the_gauge_up(gauge_at_zero):
+    """``device_call`` moves the gauge: a dispatch feeds, a put and a
+    wait on an older result move nothing, the fetch that names the
+    newest dispatch zeroes it."""
+    import jax.numpy as jnp
+
+    from deequ_tpu.obs.recorder import feed_gauge
+    from deequ_tpu.ops.device_policy import device_call, device_fetch
+    from deequ_tpu.ops.scan_engine import _block_throttle
+
+    put = device_call(lambda: jnp.arange(4.0), "transfer", what="a put")
+    assert feed_gauge() == 0
+    first = device_call(lambda: put + 1.0, "execute", what="chunk 0")
+    second = device_call(lambda: first * 2.0, "execute", what="chunk 1")
+    assert feed_gauge() == 2
+    _block_throttle(first, "throttle", None)
+    assert feed_gauge() == 2
+    older = device_fetch(first, "an older result")
+    assert feed_gauge() == 2 and older.tolist() == [1.0, 2.0, 3.0, 4.0]
+    newest = device_fetch(second, "the last result", newest=True)
+    assert feed_gauge() == 0 and newest.tolist() == [2.0, 4.0, 6.0, 8.0]
+
+
+def test_fetch_copy_is_a_part_of_fetch_and_counts_no_second_fetch(
+        gauge_at_zero):
+    import jax.numpy as jnp
+
+    from deequ_tpu.ops.device_policy import device_call, device_fetch
+
+    rec = FlightRecorder()
+    before = _seam_counters()
+    with recording_scope(rec):
+        out = device_call(lambda: jnp.ones(1024) * 3.0, "execute", what="op")
+        host = device_fetch(out, "probe", newest=True)
+    d = _seam_deltas(before)
+    assert host.shape == (1024,) and isinstance(host, np.ndarray)
+    assert d["seam_fetch_count"] == 1 and d["seam_fetch_copy_count"] == 1
+    assert 0 < d["seam_fetch_copy_seconds"] < d["seam_fetch_seconds"]
+    assert d["drain_wait_seconds"] == pytest.approx(
+        d["seam_drain_seconds"] + d["seam_fetch_seconds"], abs=1e-12)
+    (copy,) = _spans(rec, "fetch.copy")
+    (fetch,) = _spans(rec, "fetch")
+    assert copy.parent_id == fetch.span_id
+    assert copy.args["bytes"] == host.nbytes
+
+
+def test_under_a_watchdog_the_fetch_is_not_split_and_the_gauge_drops(
+        gauge_at_zero):
+    """An armed deadline runs the body on a pooled thread, whose seams
+    are spans only: the caller's ``fetch`` holds all of it."""
+    import jax.numpy as jnp
+
+    from deequ_tpu.obs.recorder import feed_gauge
+    from deequ_tpu.ops.device_policy import device_call, device_fetch
+
+    before = _seam_counters()
+    out = device_call(lambda: jnp.ones(8) + 1.0, "execute", what="op",
+                      deadline=30.0)
+    assert feed_gauge() == 1
+    host = device_fetch(out, "probe", deadline=30.0, newest=True)
+    d = _seam_deltas(before)
+    assert host.tolist() == [2.0] * 8 and feed_gauge() == 0
+    assert d["seam_fetch_count"] == 1 and d["seam_fetch_copy_count"] == 0
+    assert d["seam_fetch_copy_seconds"] == 0.0
+
+
+def test_a_worker_thread_counts_no_unfed_time_and_moves_no_gauge():
+    import threading
+    import time
+
+    from deequ_tpu.obs import seam
+    from deequ_tpu.obs.recorder import (
+        device_fed, device_ready, feed_gauge, worker_seams,
+    )
+
+    before = _seam_counters()
+    seen = []
+
+    def work():
+        with worker_seams():
+            with seam("pack", chunk=0):
+                time.sleep(0.003)
+                device_fed()
+                seen.append(feed_gauge())
+                device_ready()
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive() and seen == [0]
+    assert all(v == 0 for v in _seam_deltas(before).values())
+
+
+def test_the_older_of_two_deferred_scans_leaves_the_gauge_up(gauge_at_zero):
+    """A deferred scan keeps the mark of its last dispatch: fetching the
+    older of two in flight proves nothing of the newer."""
+    from deequ_tpu.obs.recorder import feed_gauge
+    from deequ_tpu.ops.scan_engine import run_scan
+
+    ops, _, _ = AnalysisRunner._build_scan_ops(_table(), _analyzers())
+    run_scan(_table(), ops)  # builds the program
+    assert feed_gauge() == 0
+    older = run_scan(_table(seed=1), ops, defer=True)
+    newer = run_scan(_table(seed=2), ops, defer=True)
+    assert feed_gauge() == 2
+    older.result()
+    assert feed_gauge() == 1
+    newer.result()
+    assert feed_gauge() == 0
+
+
+def _three_chunk_table(monkeypatch, n=3000):
+    from deequ_tpu.ops import scan_engine
+
+    monkeypatch.setattr(scan_engine, "MAX_RESIDENT_CHUNK_ROWS", 1024)
+    table = _table(n=n)
+    table.persist()
+    assert len(table._device_cache.device_chunks) == 3
+    return table
+
+
+def test_a_three_chunk_resident_scan_ends_fed_to_zero_with_its_put_staged(
+        monkeypatch, gauge_at_zero):
+    """Three dispatches, two merges into an accumulator whose put is a
+    ``stage`` like a chunk's (its bytes in ``bytes_staged``, no chunk
+    counted), one fetch, and the gauge back at zero."""
+    from deequ_tpu import Check, CheckLevel, VerificationSuite
+    from deequ_tpu.obs.recorder import feed_gauge
+
+    table = _three_chunk_table(monkeypatch)
+
+    def run(recorder=None):
+        builder = (
+            VerificationSuite.on_data(table)
+            .add_check(Check(CheckLevel.ERROR, "t").has_size(lambda n: n == 3000))
+            .add_required_analyzers(_analyzers())
+        )
+        if recorder is not None:
+            builder = builder.with_tracing(recorder)
+        return builder.run()
+
+    try:
+        run()  # builds the programs
+        before = _seam_counters()
+        staged = (SCAN_STATS.bytes_staged, SCAN_STATS.chunks_staged)
+        overlap = SCAN_STATS.ingest_overlap_frac
+        rec = FlightRecorder()
+        result = run(rec)
+    finally:
+        table.unpersist()
+    assert str(result.status).endswith("SUCCESS") and feed_gauge() == 0
+    d = _seam_deltas(before)
+    assert d["seam_dispatch_count"] == 6      # three steps, three merges
+    assert d["seam_fetch_count"] == d["seam_fetch_copy_count"] == 1
+    (put,) = _spans(rec, "stage")
+    assert put.args["what"] == "fold accumulator"
+    assert SCAN_STATS.bytes_staged - staged[0] == put.args["bytes"] > 0
+    assert SCAN_STATS.chunks_staged == staged[1]
+    assert SCAN_STATS.ingest_overlap_frac == overlap
+    assert 0 < d["unfed_seconds"] <= d["run_seconds"]
+    assert _exclusive_seconds(d) == pytest.approx(d["run_seconds"], abs=1e-9)
+    # the chunks between the first dispatch and the last wait were fed
+    (fetch,) = _spans(rec, "fetch")
+    assert d["unfed_seconds"] < d["run_seconds"] - (
+        d["seam_fetch_seconds"] - d["seam_fetch_copy_seconds"]
+        - fetch.args.get("unfed_s", 0.0)) + 1e-9
+    phases = result.run_trace["phases"]
+    assert phases["plan"]["unfed_seconds"] > 0
+    assert phases["fetch.copy"]["unfed_seconds"] > 0
+
+
+def test_an_own_pass_top_k_feeds_once_and_ends_at_zero(gauge_at_zero):
+    """``resident_top_k`` goes through the boundary like a scan: its one
+    dispatch feeds, its one fetch waits, copies and zeroes the gauge."""
+    from deequ_tpu.obs.recorder import feed_gauge
+    from deequ_tpu.ops.segment import resident_top_k
+
+    rng = np.random.default_rng(3)
+    words = np.array([f"w{i}" for i in range(40)], dtype=object)
+    table = ColumnarTable([
+        Column("s", DType.STRING,
+               codes=rng.integers(0, 40, 5000).astype(np.int32),
+               dictionary=words),
+    ])
+    table.persist()
+    try:
+        resident_top_k(table, [("s", 5)])  # builds the program
+        before = _seam_counters()
+        stats, _ = resident_top_k(table, [("s", 5)])
+    finally:
+        table.unpersist()
+    d = _seam_deltas(before)
+    assert feed_gauge() == 0 and len(stats[0].top) == 5
+    assert d["seam_dispatch_count"] == 1 and d["seam_grouping_count"] == 1
+    assert d["seam_fetch_count"] == d["seam_fetch_copy_count"] == 1
+    assert 0 < d["unfed_seconds"] < _exclusive_seconds(d)
 
 
 def _append(table, states, repository, key):
