@@ -26,7 +26,16 @@ equivalent built here is a *batched multi-rank radix selection*:
   3. Reconstruct the f64 item per target: the selected f32 hi value
      plus a deterministically-chosen lo-plane rider (tie rule below),
      and extract the < w exact-remainder elements by threshold +
-     stable tie-split + compaction.
+     stable tie-split + compaction, all three by COUNTING over groups
+     of 32 elements (:func:`_remainder_source`): one pass packs the
+     0/1 planes "above / at the bottom key, below / at the top key"
+     into a u32 word a group (a matmul against powers of two), the
+     tie split is an INDEX threshold (the index of the tie numbered
+     ``tie_rank``: a running total over the groups and one word), and
+     slot s reads the (s + 1)-th set element of the remainder's words,
+     found by inverting the groups' running totals with a bincount and
+     a cumulative count, one gather a slot. No n-long running count,
+     no search.
 
 Two formulations compute step 2 and the rider, bit for bit alike; the
 plan's histogram variant (ops/histogram_device.py) picks one:
@@ -51,7 +60,8 @@ gathers, 7.2% the scatter-min, 3.8% the three histogram passes; the sort
 summary 81.7 ms (x6 faster than the selection it was to replace); the
 matmul formulation with the compaction as a GATHER (W binary searches
 over the running count of remainder elements) 41.4 ms (27.3 since PR
-31, below). On the TPU a gather or scatter walks its n elements one
+31, below; 16.6 since PR 35, which took the searches out: further
+below). On the TPU a gather or scatter walks its n elements one
 after another (32-43 ms a 4.77M-element pass, 364 ms in f64); a matmul
 pass costs 5-12 ms.
 
@@ -70,6 +80,21 @@ pass 1 as it builds pass 2, to the cycle of its own estimate, and on the
 chip it costs 4.67 ms. The histogram is the same, bit for bit (0/1
 products, f32 accumulation per block of at most 2^16 rows, integer
 fold), and so is every summary.
+
+The extraction (PERF.md section 6, PR 35): inside that step the
+remainder's compaction took 8.79 ms a column summary, 31% of the
+device's time: W = 32,768 binary searches over the n-long running count
+(23 dependent gathers each, 7.1 ns a gather: 5.38 ms), a ``(2, n)``
+running count that numbered every tie at the two bounding keys (1.92)
+and the running count itself (0.96). A gather costs its 7 ns whatever
+the table, so a shorter search saves little; the gain is in not
+searching: :func:`_remainder_source` finds positions in a 0/1 plane by
+COUNTING over groups of 32 elements (a matmul packs a plane into words,
+a bincount inverts the groups' running totals), with one word gather a
+slot. 0.95 ms a column summary on the chip, of it 0.70 the three
+W-element gathers that are left (the word, ``x[source]``,
+``lo[source]``); the alone-figure above fell from 25.9 to 16.6 ms.
+``source`` is the same array, slot for slot, and so is every summary.
 
 Passes touch each element O(1) times in native u32/i32 ops — no f64
 emulation, no u64: XLA:TPU rejects f64->u64 bitcasts, ops/hll.py. The
@@ -92,9 +117,10 @@ kernel determinism"):
   ambiguity the sort path already documents for itself;
 - the remainder multiset reproduces the stable-argsort tie split
   exactly: ties at the threshold key enter the remainder in original
-  index order, so remainder contents match the sort path element for
-  element (the summary is order-insensitive; ``fold_summaries`` sorts
-  per level).
+  index order (an index threshold: the ties at or after the one
+  numbered ``tie_rank``), so remainder contents match the sort path
+  element for element (the summary is order-insensitive;
+  ``fold_summaries`` sorts per level).
 
 jnp-only: no numpy mirror here — the host reference for tests is the sort
 path itself.
@@ -162,16 +188,18 @@ def inverse_monotone_u32(u, xp):
 
 
 def _segment_count(seg, num_segments: int, xp):
-    """Histogram of i32 segment ids for the LUT formulation, under the
+    """Histogram of i32 segment ids for the LUT formulation's passes and
+    for the extraction's inverting count (:func:`_first_set`), under the
     routed kernel tier (ops/histogram_device.py): the scatter variant
     traces ``at[].add`` exactly as before round 14 (``at[].add`` rather
     than segment_sum — same scatter, but without materializing the
     all-ones operand, measured ~2x faster on CPU); the pallas variant
-    replaces the scatter with a Mosaic grid kernel (the one-hot variant
-    never gets here: ``_select_u32_multirank``). The ambient variant is
-    bound by the planner around the whole selection update
-    (ops/scan_plan._bind_hist_variant), so all three passes of one
-    summary trace the SAME kernel shape — the plan-hist-scatter lint
+    replaces the scatter with a Mosaic grid kernel; under the one-hot
+    variant the passes are matmuls of their own
+    (``_select_u32_multirank``) and only the extraction gets here. The
+    ambient variant is bound by the planner around the whole selection
+    update (ops/scan_plan._bind_hist_variant), so every count of one
+    summary traces the SAME kernel shape — the plan-hist-scatter lint
     contract."""
     from deequ_tpu.ops.histogram_device import bincount
 
@@ -440,6 +468,181 @@ def _multirank_lut(u, ranks, xp):
     return keys, rank_rem, min_tie_index
 
 
+#: elements in a group of the remainder's extraction: a group's 0/1 plane
+#: packs into ONE u32 word, lane l in bit l; and the elements in a row of
+#: the matmul that packs them (the TPU's lane count: an n-long plane is
+#: rows of 128 lanes where it lies), ``_QUARTERS`` groups a row
+_GROUP = 32
+_PACK_ROW = 128
+_QUARTERS = _PACK_ROW // _GROUP
+
+
+def _pack_weights():
+    """``(_PACK_ROW, 2 * _QUARTERS)``: lane l of a row adds ``2^(l % 16)``
+    to the low (columns 0-3) or the high (4-7) half of its group's word.
+    Sixteen distinct powers of two a column: exact in bf16 and in the f32
+    that accumulates them."""
+    lanes = np.arange(_PACK_ROW)
+    weights = np.zeros((_PACK_ROW, 2 * _QUARTERS), np.float32)
+    weights[lanes, lanes // _GROUP + _QUARTERS * (lanes % _GROUP // 16)] = (
+        2.0 ** (lanes % 16)
+    )
+    return weights
+
+
+def _group_words(plane):
+    """An n-long 0/1 plane as the u32 words of its groups of ``_GROUP``
+    elements, lane l of a group in bit l: ``(_QUARTERS, rows)``, group
+    ``_QUARTERS * r + j`` (elements ``128 r + 32 j`` and up) at ``[j, r]``.
+    A matmul over the rows of 128 lanes the plane lies in, and its words
+    the way the MXU hands them out, rows along the lanes: a reduction over
+    32-element groups re-lays the whole plane out on the TPU, and so does
+    a flat list of the words (0.37-0.6 ms a 4.77M-element plane by the
+    compiler's own estimate, where this is 8 us)."""
+    dtype = _plane_dtype(jnp)
+    halves = jnp.matmul(
+        _row_blocks(plane, _PACK_ROW).astype(dtype),
+        jnp.asarray(_pack_weights(), dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.uint32).T
+    return halves[:_QUARTERS] | (halves[_QUARTERS:] << jnp.uint32(16))
+
+
+def _bounds_words_body(u, bounds):
+    """ONE pass over the keys: the four 0/1 planes the remainder is made
+    of (above / at the bottom key, below / at the top key), in words."""
+    v_b, v_t = bounds[0], bounds[1]
+    return jnp.stack([
+        _group_words(plane)
+        for plane in (u > v_b, u == v_b, u < v_t, u == v_t)
+    ])
+
+
+# as the passes: the batched matmul is the one XLA:TPU miscompiles
+_bounds_words = map_under_vmap(_bounds_words_body)
+
+
+def _first_index(words):
+    """The index of the first element of each group of ``words``."""
+    quarter = jnp.arange(_QUARTERS, dtype=jnp.int32)[:, None] * _GROUP
+    row = jnp.arange(words.shape[-1], dtype=jnp.int32)[None, :] * _PACK_ROW
+    return quarter + row
+
+
+def _running_totals(words):
+    """Per group the number of set elements up to and including it, in
+    index order: the running total of the ROWS' totals (n / 128 long) plus
+    the quarters before it in its row."""
+    totals = jax.lax.population_count(words).astype(jnp.int32)
+    within = jnp.cumsum(totals, axis=-2)
+    rows = within[..., -1, :]
+    return within + (jnp.cumsum(rows, axis=-1) - rows)[..., None, :]
+
+
+def _word_of(words, group):
+    """``words`` at the groups numbered ``group`` in index order (one
+    plane; clipped to the last group)."""
+    group = jnp.minimum(group, words.size - 1)
+    return words.reshape(-1)[
+        group % _QUARTERS * words.shape[-1] + group // _QUARTERS
+    ]
+
+
+def _low_lanes(count):
+    """The word whose lanes ``[0, count)`` are set (none at ``count <= 0``,
+    all from ``_GROUP`` on)."""
+    low = (
+        jnp.uint32(1) << jnp.clip(count, 0, _GROUP - 1).astype(jnp.uint32)
+    ) - jnp.uint32(1)
+    return jnp.where(count >= _GROUP, jnp.uint32(0xFFFFFFFF), low)
+
+
+def _nth_lane(word, q):
+    """The lane of the ``q``-th (1-based) set bit of each word: how many
+    lanes have fewer than ``q`` set bits up to and including themselves
+    (``_GROUP`` where the word holds fewer than ``q``). Lane arithmetic on
+    a ``(..., _GROUP)`` compare, no gather."""
+    upto = _low_lanes(jnp.arange(1, _GROUP + 1, dtype=jnp.int32))
+    counts = jax.lax.population_count(word[..., None] & upto)
+    return jnp.sum(
+        counts.astype(jnp.int32) < q[..., None], axis=-1, dtype=jnp.int32
+    )
+
+
+def _nth_set(words, q, n: int):
+    """The index of the ``q``-th (1-based, ``q >= 1``) set element of one
+    0/1 plane in words (:func:`_group_words`; ``q`` a scalar); ``n`` where
+    the plane holds fewer than ``q``. By counting: the groups whose running
+    total is below ``q`` are the groups wholly before it, and the largest
+    such total is the number of elements before its group (two
+    compare-reduces over the groups, 1/32 of the plane's length); the lane
+    is arithmetic on the group's word."""
+    running = _running_totals(words)
+    wholly_before = running < q
+    group = jnp.sum(wholly_before, dtype=jnp.int32)
+    before = jnp.max(jnp.where(wholly_before, running, 0))
+    index = group * _GROUP + _nth_lane(_word_of(words, group), q - before)
+    return jnp.where(group < words.size, index, n)
+
+
+def _first_set(words, count: int, n: int, xp):
+    """The indices of the first ``count`` set elements of one 0/1 plane in
+    words, ascending; ``n`` in the slots past the plane's total.
+    :func:`_nth_set` at ``q = 1..count`` without a search: the groups'
+    running totals are INVERTED by counting. Slot s lies in the group
+    that follows every group whose running total is ``<= s``: their number
+    is the cumulative count of a bincount of the running totals (under
+    the ambient histogram variant, as the passes'), and the elements
+    before that group are the largest running total ``<= s``, a cumulative
+    max over the slots of the totals that occur. One gather a slot (the
+    group's word); the lane is arithmetic."""
+    running = _running_totals(words).reshape(-1)
+    occurs = _segment_count(jnp.minimum(running, count), count + 1, xp)
+    group = jnp.cumsum(occurs)[:count]
+    slots = jnp.arange(count + 1, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(occurs > 0, slots, 0))[:count]
+    index = group * _GROUP + _nth_lane(
+        _word_of(words, group), slots[1:] - before
+    )
+    return jnp.where(group < words.size, index, n)
+
+
+def _remainder_source(u, bounds, tie_ranks, has_rem, W: int, xp):
+    """``source[s]``, s in 0..W-1: the index of the (s + 1)-th element of
+    the exact remainder in index order, ``n - 1`` in the slots past it
+    (their weight is 0). The remainder is what lies between the two
+    bounding keys ``bounds = (v_b, v_t)``, ties on either split by index as
+    a stable argsort splits them: at ``v_b`` the ties numbered
+    ``tie_ranks[0]`` and up join, at ``v_t`` those up to ``tie_ranks[1]``.
+
+    By COUNTING over groups of ``_GROUP`` elements, never by an n-long
+    running count and a search a slot (on the TPU a gather walks its
+    elements one after another, and so did the 23 steps of each of W
+    binary searches): ONE pass over the keys packs the four 0/1 planes
+    the remainder is made of into a word a group; the tie split is an
+    INDEX threshold (:func:`_nth_set`, once a bound), applied to the words;
+    the slots come from the remainder's words by :func:`_first_set`."""
+    n = u.shape[0]
+    above, tie_b, below, tie_t = _bounds_words(u, bounds)
+    # everything from here on is a group long: the index of each group's
+    # first element, and the lanes that hold an element (the padding of
+    # the last row is in no plane)
+    first = _first_index(above)
+    live = _low_lanes(n - first)
+    # a tie numbered past the last one reads n: at the bottom no tie
+    # joins, at the top every tie does
+    i_b = _nth_set(tie_b & live, tie_ranks[0] + 1, n)
+    i_t = _nth_set(tie_t & live, tie_ranks[1] + 1, n)
+    rem = (
+        (above | (tie_b & ~_low_lanes(i_b - first)))
+        & (below | (tie_t & _low_lanes(i_t - first + 1)))
+        & live
+    )
+    return xp.minimum(
+        _first_set(xp.where(has_rem, rem, xp.uint32(0)), W, n, xp), n - 1
+    )
+
+
 def chunk_summary_select(x, valid, sketch_size: int, local_n: int, xp, lo):
     """Inside-jit: one chunk/shard -> the SAME fixed-shape weighted
     summary as ``kll_device.chunk_summary``, computed by multi-rank
@@ -505,28 +708,9 @@ def chunk_summary_select(x, valid, sketch_size: int, local_n: int, xp, lo):
     # at ranks >= m inside the same +inf tie group the remainder's top
     # ranks occupy, so "everything above the threshold" would overrun.
     with jax.named_scope("deequ.select.extract"):
-        v_b, v_t = keys[k], keys[k + 1]
-        j0, j1 = tie_rank[k], tie_rank[k + 1]
         has_rem = r0 < m.astype(xp.int32)
-        tie_b = u == v_b
-        tie_t = u == v_t
-        pos_b, pos_t = xp.cumsum(
-            xp.stack([tie_b, tie_t]).astype(xp.int32), axis=1
-        ) - 1
-        above = (u > v_b) | (tie_b & (pos_b >= j0))
-        below = (u < v_t) | (tie_t & (pos_t <= j1))
-        rem = has_rem & above & below
-        # compaction by GATHER: slot s holds the element at which the
-        # running count of remainder elements first reaches s + 1 — W
-        # binary searches over the running count, where a scatter walks
-        # all n elements to drop all but < w of them
-        source = xp.minimum(
-            xp.searchsorted(
-                xp.cumsum(rem.astype(xp.int32)),
-                xp.arange(1, W + 1, dtype=xp.int32),
-                side="left", method="scan",
-            ),
-            x.shape[0] - 1,
+        source = _remainder_source(
+            u, keys[k:k + 2], tie_rank[k:k + 2], has_rem, W, xp
         )
         # item values come from the PADDED plane (invalid rows read as
         # +inf, lo zeroed) — the exact array the sort path gathers from
@@ -555,8 +739,8 @@ def chunk_summary_select_batched(X, M, sketch_size: int, local_n: int, xp, lo):
     """K columns at once: (K, n) values + (K, n) validity + (K, n) lo
     planes -> summaries with a leading K axis. The members run one after
     another (``lax.map``): a member's passes are whole-device programs
-    already, and its temporaries (the key plane, the running counts, the
-    blocks' one-hot planes) are n-sized — batched over K = 50 members
+    already, and its temporaries (the key plane, the 0/1 planes of the
+    extraction, the blocks' one-hot planes) are n-sized — batched over K = 50 members
     they stood beside a resident table as tens of GB."""
     return jax.lax.map(
         lambda member: chunk_summary_select(

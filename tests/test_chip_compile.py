@@ -387,6 +387,22 @@ def test_selection_kernel_compiles_at_config3_width(as_tpu, one_chip):
     # gathers W slots
     assert "convolution" in text and not re.search(r"\bscatter\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    # the remainder's slots by COUNTING (PERF.md section 6, PR 35): no
+    # running count over the n elements anywhere in the step (the longest
+    # is over the 4 x 37,283 words of a plane's 32-element groups) and no
+    # loop of gathers in the extraction (a binary search a slot was 23
+    # dependent gathers, 5.4 ms a column on the chip)
+    windows = [
+        line for line in text.splitlines() if re.search(r"\breduce-window\(", line)
+    ]
+    assert windows
+    for line in windows:
+        shape = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+        assert np.prod([int(d) for d in shape.split(",") if d]) <= n // 16, line
+    assert not [
+        line for line in text.splitlines()
+        if re.search(r"\bwhile\(", line) and "deequ.select.extract" in line
+    ]
     # pass 1 is the program passes 2-3 are: its matmul fusion reads the
     # leading digits as an operand (the barrier held through the TPU
     # pipeline) and the compiler builds all three with one emitter. At 256

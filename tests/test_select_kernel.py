@@ -391,6 +391,154 @@ def test_pass1_leading_digits_stay_opaque_in_the_lowered_program():
     assert "deequ.select.pass1/optimization_barrier" in text
 
 
+# -- the remainder's extraction by counting (PR 35) ----------------------
+
+
+def _mask_of(n, set_at):
+    mask = np.zeros(n, bool)
+    mask[np.asarray(set_at, dtype=np.int64)] = True
+    return mask
+
+
+_MASKS = {
+    "empty": _mask_of(1000, []),
+    "full": np.ones(1000, bool),
+    "full_whole_rows": np.ones(1024, bool),
+    # every set element in one group of 32, and in its last lanes
+    "one_group": _mask_of(1000, range(530, 544)),
+    "last_element_only": _mask_of(1000, [999]),
+    "first_element_only": _mask_of(1000, [0]),
+    # n no multiple of a group, and smaller than one
+    "ragged": _RNG.random(777) < 0.3,
+    "under_one_group": _mask_of(7, [1, 2, 6]),
+    "single_row": _mask_of(1, [0]),
+    # clustered in the last groups / the first, sparse between
+    "clustered": _mask_of(
+        5001, [*range(0, 70), 2500, *range(4900, 5001)]
+    ),
+    "several_rows_sparse": _RNG.random(20_001) < 0.002,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MASKS))
+def test_nth_set_element_matches_numpy(case):
+    """The primitive of the extraction, the index of the q-th set element
+    of a 0/1 plane by counting over its packed groups, against
+    ``np.flatnonzero(mask)[q - 1]``: at q = 1, q = total, q past the total
+    (the not-found value is n) and in between; and all the first slots at
+    once (``_first_set``), under both histogram variants of its
+    inverting bincount."""
+    from deequ_tpu.ops.histogram_device import active_hist_variant
+    from deequ_tpu.ops.select_device import (
+        _first_set,
+        _group_words,
+        _nth_set,
+    )
+
+    mask = _MASKS[case]
+    n = len(mask)
+    where = np.flatnonzero(mask)
+    total = len(where)
+    words = _group_words(jnp.asarray(mask))
+    assert words.dtype == jnp.uint32 and words.shape == (4, -(-n // 128))
+    nth = jax.jit(lambda w, q: _nth_set(w, q, n))
+    for q in sorted({1, 2, max(total // 2, 1), max(total, 1), total + 1,
+                     total + 40, n + 1}):
+        want = where[q - 1] if q <= total else n
+        assert int(nth(words, jnp.int32(q))) == want, q
+    for count in sorted({1, max(total, 1), total + 3, 64}):
+        want = np.full(count, n)
+        want[:min(count, total)] = where[:count]
+        for variant in ("scatter", "onehot"):
+            with active_hist_variant(variant):
+                got = _first_set(words, count, n, jnp)
+            assert np.array_equal(np.asarray(got), want), (count, variant)
+
+
+def _searched_source(u, bounds, tie_ranks, has_rem, W):
+    """The extraction as it stood until PR 35, the reference of the one
+    that counts: the ties at the two bounding keys numbered by an n-long
+    running count each, slot s the element at which the running count of
+    remainder elements first reaches s + 1, by W binary searches."""
+    v_b, v_t = bounds[0], bounds[1]
+    j0, j1 = tie_ranks[0], tie_ranks[1]
+    tie_b = u == v_b
+    tie_t = u == v_t
+    pos_b, pos_t = jnp.cumsum(
+        jnp.stack([tie_b, tie_t]).astype(jnp.int32), axis=1
+    ) - 1
+    above = (u > v_b) | (tie_b & (pos_b >= j0))
+    below = (u < v_t) | (tie_t & (pos_t <= j1))
+    rem = has_rem & above & below
+    return jnp.minimum(
+        jnp.searchsorted(
+            jnp.cumsum(rem.astype(jnp.int32)),
+            jnp.arange(1, W + 1, dtype=jnp.int32),
+            side="left", method="scan",
+        ),
+        u.shape[0] - 1,
+    )
+
+
+# the remainder all in the last groups / the first / one tie group
+# spanning the plane, with invalid rows and +inf / NaN present
+_ORDERED_BASE = np.where(
+    (_ord_r := _RNG.random(5000)) < 0.03,
+    np.where(_ord_r < 0.015, np.inf, np.nan),
+    _grid(np.round(_RNG.normal(0, 3, 5000), 2)),
+)
+_ORDERED = {
+    "sorted_ascending": (np.sort(_ORDERED_BASE), _RNG.random(5000) > 0.1),
+    "sorted_descending": (
+        np.sort(_ORDERED_BASE)[::-1].copy(), _RNG.random(5000) > 0.1,
+    ),
+    "constant": (np.full(5000, 3.25), _RNG.random(5000) > 0.1),
+    "constant_inf": (np.full(3000, np.inf), _RNG.random(3000) > 0.4),
+}
+_EXTRACTION_CASES = {**_ADVERSARIAL, **_ORDERED}
+
+
+@pytest.mark.parametrize("case", sorted(_EXTRACTION_CASES))
+@pytest.mark.parametrize("k", [64, 256])
+def test_extraction_source_matches_searched_reference(case, k, monkeypatch):
+    """``source``, the index each remainder slot reads, equal to the
+    searched formulation's slot for slot (so ``items`` and ``weights``
+    are): the arguments are the ones ``chunk_summary_select`` itself hands
+    its extraction on that column."""
+    from deequ_tpu.ops import select_device
+
+    values, mask = _EXTRACTION_CASES[case]
+    if mask is None:
+        mask = np.ones(len(values), bool)
+    n = len(values)
+    hi, lo = split_pair_np(np.asarray(values, dtype=np.float64))
+    W = select_device.strata_capacity(n, k)
+    counted = select_device._remainder_source
+    past = jnp.asarray([n + 5, n + 5], dtype=jnp.int32)
+
+    def spy(u, bounds, tie_ranks, has_rem, slots, xp):
+        assert slots == W
+        pairs.extend(
+            (counted(u, bounds, ranks, has_rem, W, xp),
+             _searched_source(u, bounds, ranks, has_rem, W))
+            # the column's own tie numbers; and those a clipped padding
+            # target can ask for, past the last tie: none joins at the
+            # bottom, all join at the top
+            for ranks in (tie_ranks, past)
+        )
+        return pairs[0][0]
+
+    def both(x, valid, low):
+        chunk_summary_select(x, valid, k, n, jnp, lo=low)
+        return tuple(pairs)
+
+    pairs = []
+    monkeypatch.setattr(select_device, "_remainder_source", spy)
+    for got, want in jax.jit(both)(hi, mask, lo):
+        assert got.shape == (W,)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 # -- KLL merge algebra --------------------------------------------------
 
 
