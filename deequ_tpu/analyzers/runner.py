@@ -918,8 +918,8 @@ class AnalysisRunner:
 
         Trade-off vs the non-resilient paths: per-batch monoid folds
         instead of the device-resident pipelined partials — checkpointable
-        state costs some scan-engine pipelining (measured by bench.py's
-        checkpoint-overhead probe)."""
+        state costs some scan-engine pipelining (not measured on the
+        chip)."""
         from deequ_tpu.analyzers.base import StreamStateFolder
         from deequ_tpu.ops.segment import group_counts_state
         from deequ_tpu.resilience.checkpoint import (

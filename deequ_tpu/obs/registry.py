@@ -435,8 +435,7 @@ def _kernels_section() -> dict:
     """Read-through over the histogram kernel tier (round 14,
     ops/histogram_device.py): per-variant bincount/segment-fold dispatch
     counts off ScanStats plus the resolved force knob — the observable
-    pair the kernel A/B probe (bench.measure_kernel_ab) reads to prove
-    the routed variant actually dispatched."""
+    pair that proves the routed variant actually dispatched."""
     from deequ_tpu.envcfg import EnvConfigError, env_value
     from deequ_tpu.ops.scan_engine import SCAN_STATS
 
@@ -459,9 +458,8 @@ def _planner_section() -> dict:
     """Read-through over the whole-run plan optimizer (round 19,
     ops/segment.fused_group_counts + serve/plan_cache.SUBPLAN_CACHE):
     fused grouping-pass count, sub-plan cache hit count, and the fusion
-    knob as resolved — the observable triple the plan-fusion A/B probe
-    (bench.measure_plan_fusion) reads to prove fusion actually grouped
-    and sharing actually hit."""
+    knob as resolved — the observable triple that proves fusion
+    actually grouped and sharing actually hit."""
     from deequ_tpu.envcfg import EnvConfigError, env_value
     from deequ_tpu.ops.scan_engine import SCAN_STATS
 

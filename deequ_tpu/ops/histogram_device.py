@@ -26,7 +26,7 @@ histogram-shaped reduction shares:
   one-hot planes to fit: grid over (segment blocks x row blocks), each
   step accumulates a compare-against-iota tile into its output block (a
   VPU formulation — no scatter, no sorted structure). The v5e compiler
-  accepts it (tests/test_chip_compile.py) and chip_smoke.py runs it on
+  accepts it (tests/test_chip_compile_counts.py) and chip_smoke.py runs it on
   the chip, but no policy resolves to it: it costs O(n * num_segments)
   compares and has no measured width range yet (ROADMAP C2), so it is
   reachable only through the DEEQU_TPU_HIST_VARIANT force knob and

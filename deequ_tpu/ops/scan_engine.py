@@ -306,9 +306,7 @@ class ScanStats:
         # selection kernel's three passes count under the plan's
         # resolved hist_variant, the grouping kernels
         # (ops/segment.py) under their per-dispatch resolution. The
-        # obs registry's "kernels" section reads these through; the
-        # kernel A/B probe (bench.measure_kernel_ab) asserts the
-        # routed variant actually dispatched
+        # obs registry's "kernels" section reads these through
         self.hist_scatter_dispatches = 0
         self.hist_onehot_dispatches = 0
         self.hist_pallas_dispatches = 0
@@ -439,14 +437,14 @@ class ScanStats:
         # attempts charged against an armed RunBudget (I/O retries, OOM
         # bisections, encoded demotions, mesh reshards, CPU fallbacks —
         # one ledger for the composed ladder) and how many runs
-        # exhausted one. Healthy runs charge ZERO — the observable pair
-        # behind bench.py's measure_governance_overhead <1% contract
+        # exhausted one. Healthy runs charge ZERO (what governance
+        # costs a healthy run is not measured on the chip)
         self.budget_charges = 0
         self.budget_exhaustions = 0
         # serving layer (deequ_tpu/serve, round 10): compiled-plan cache
         # traffic — a HIT means the suite ran with zero new traces, zero
-        # compiles, and zero plan-lint traces (the hard repeat-tenant
-        # contract measure_serving_load asserts); a MISS pays the
+        # compiles, and zero plan-lint traces (the repeat-tenant
+        # contract the tier-1 serve suite asserts); a MISS pays the
         # one-time build. Coalescing telemetry: packed multi-tenant
         # dispatches, real tenant suites they carried, and padding slots
         # burned to reach the tenant-axis bucket (occupancy =
@@ -2413,10 +2411,10 @@ def _evict_device_cache(table) -> int:
 def _governed_attempt(budget, fn: Callable, what: str):
     """Run one WHOLE scan attempt under the run budget's wall watchdog.
 
-    One worker thread per governed attempt — never per device call: the
-    healthy-path cost of governance must stay <1% of wall (bench.py's
-    ``measure_governance_overhead`` contract), and a per-call watchdog
-    measured ~30% on the config-1 profile. A hang anywhere inside the
+    One worker thread per governed attempt — never per device call: a
+    per-call watchdog measured ~30% of wall on the config-1 profile (what
+    one per attempt costs is not measured on the chip; a healthy run
+    charges ``budget_charges`` nothing). A hang anywhere inside the
     attempt becomes a typed ``DeviceHangException`` at the remaining
     budget, which the ladder then charges — so termination within
     ``run_deadline`` holds for hangs, not just exceptions. Ungoverned

@@ -245,7 +245,7 @@ _ONEHOT_PLANE_ELEMENTS = 1 << 26
 #: fusion's emitter by this count (at a multiple of 128 a transposing one,
 #: twice as slow here) and its windows by whether it sees an iota behind
 #: the operand (small ones, twice as slow again): module docstring,
-#: tests/test_chip_compile.py
+#: tests/test_chip_compile_select.py
 _PASS1_ROWS = _B + 8
 
 

@@ -20,8 +20,8 @@ This module is the one global ledger:
   Every ladder rung charges it: ``resilience/retry.py`` charges failed
   I/O tries, ``ops/scan_engine.py:run_scan`` charges bisections,
   demotions, reshards, and fallback transitions. A clean first try never
-  charges — healthy runs spend nothing (the <1% bench contract,
-  ``measure_governance_overhead``);
+  charges — healthy runs spend nothing (``budget_charges`` stays zero;
+  what governance costs a healthy run is not measured on the chip);
 - :func:`run_budget_scope` — the ambient slot the charge sites resolve,
   so a streaming run's hundred per-batch scans all draw on ONE budget
   instead of paying per batch;

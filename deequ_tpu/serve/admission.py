@@ -51,7 +51,7 @@ Three mechanisms, composed at the service's submit/queue seam:
   the ladder doesn't flap at a boundary. The invariant the whole tier
   keeps: COMPUTATION IS NEVER DEGRADED — brownout changes which
   requests run, never how, so every completed result stays bit-identical
-  to an unloaded serial run (``measure_overload_shedding`` gates on it).
+  to an unloaded serial run (the tier-1 ``slo`` suite asserts it).
 
 Observables: per-class ``serve_admitted_* / serve_admission_rejected_* /
 serve_shed_*`` counters and the ``serve_brownout_level`` gauge

@@ -26,9 +26,9 @@ plan — the batch found the traced program for its (tenant bucket, LUT
 signature) and ran with zero op builds, zero traces, zero compiles,
 zero plan-lint traces; ``plan_cache_misses`` counts suites whose batch
 had to build/trace any of it (the executor accounts both,
-suite-weighted). The repeat-tenant contract (bench
-``measure_serving_load`` + tier-1 ``serve`` suite): a second identical
-suite is a hit and adds zero ``plan_lint_traces`` / ``programs_built``.
+suite-weighted). The repeat-tenant contract (the tier-1 ``serve``
+suite): a second identical suite is a hit and adds zero
+``plan_lint_traces`` / ``programs_built``.
 """
 
 from __future__ import annotations

@@ -546,26 +546,97 @@ def test_stream_csv_bool_mixed_literal_parity(tmp_path):
     )
 
 
-def test_billion_row_proof_harness_scaled():
-    """The committed 1B-row proof harness (benchmarks/BILLION_ROW_PROOF.md)
-    must keep passing at a scaled size: segmented incremental == one-pass
-    streaming, RSS bound asserted internally."""
-    import os
-    import sys
+def _synthetic_source(total_rows, step, row_offset, seed):
+    """One float column in batches of ``step`` rows, batch k regenerated
+    from ``seed`` plus its global index: the segments' streams and the
+    one-pass stream yield identical bytes, and nothing is ever
+    materialized."""
+    from deequ_tpu.data.source import BatchSource
+    from deequ_tpu.data.table import Column, DType, Field, Schema
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, os.path.join(root, "benchmarks"))
-    import billion_row_proof
+    class Synthetic(BatchSource):
+        preferred_batch_rows = step
 
-    argv = sys.argv
-    try:
-        sys.argv = [
-            "p", "--rows", "8000000", "--segments", "4",
-            "--batch-rows", "1000000",
-        ]
-        billion_row_proof.main()
-    finally:
-        sys.argv = argv
+        @property
+        def schema(self):
+            return Schema([Field("v", DType.FRACTIONAL)])
+
+        @property
+        def num_rows(self):
+            return total_rows
+
+        def batches(self, columns=None, batch_rows=None):
+            for start in range(0, total_rows, step):
+                index = (row_offset + start) // step
+                vals = np.random.default_rng(seed + index).normal(
+                    100.0, 5.0, min(step, total_rows - start))
+                yield ColumnarTable([Column("v", DType.FRACTIONAL, values=vals)])
+
+    return Synthetic()
+
+
+def _rss_mb():
+    with open("/proc/self/status") as f:
+        for line in f:
+            if line.startswith("VmRSS:"):
+                return int(line.split()[1]) / 1024.0
+    return float("nan")
+
+
+def test_segmented_run_equals_one_pass_in_bounded_rss():
+    """The out-of-core 1B-row loop at a scaled size (8M rows in 4 segments
+    of 1M-row batches): each segment a StreamingTable analysed with
+    ``aggregate_with`` / ``save_states_with``, its metrics saved to a
+    repository whose Mean series feeds an anomaly detector (no alert on
+    one stationary stream), host RSS under 4 GB after every segment; the
+    chained metrics equal one streaming pass over the whole dataset."""
+    from deequ_tpu.anomaly import AnomalyDetector, OnlineNormalStrategy
+    from deequ_tpu.anomaly.history import DataPoint
+    from deequ_tpu.repository import AnalysisResult, ResultKey
+    from deequ_tpu.repository.memory import InMemoryMetricsRepository
+    from deequ_tpu.states import InMemoryStateProvider
+
+    total, segments, batch_rows, seed = 8_000_000, 4, 1_000_000, 1000
+    seg_rows = total // segments
+    analyzers = [
+        Size(), Completeness("v"), Mean("v"), StandardDeviation("v"),
+        Minimum("v"), Maximum("v"),
+    ]
+    repo = InMemoryMetricsRepository()
+    states = InMemoryStateProvider()
+    for seg in range(segments):
+        source = _synthetic_source(seg_rows, batch_rows, seg * seg_rows, seed)
+        ctx = AnalysisRunner.do_analysis_run(
+            StreamingTable(source), analyzers,
+            aggregate_with=states, save_states_with=states,
+        )
+        repo.save(AnalysisResult(ResultKey(seg, {"proof": "1b"}), ctx))
+        assert _rss_mb() < 4096.0, seg
+
+    final = repo.load_by_key(
+        ResultKey(segments - 1, {"proof": "1b"})
+    ).analyzer_context
+    chained = {a: final.metric_map[a].value.get() for a in analyzers}
+    assert chained[Size()] == total
+
+    series = repo.load().with_tag_values({"proof": "1b"}).get()
+    means = [
+        DataPoint(r.result_key.data_set_date, r.analyzer_context.metric_map[
+            Mean("v")].value.get())
+        for r in series
+    ]
+    assert len(means) == segments
+    detection = AnomalyDetector(
+        OnlineNormalStrategy()).detect_anomalies_in_history(means)
+    assert detection.anomalies == []  # one stationary stream
+
+    one_pass = AnalysisRunner.do_analysis_run(
+        StreamingTable(_synthetic_source(total, batch_rows, 0, seed)),
+        analyzers,
+    )
+    for a in analyzers:
+        whole = one_pass.metric_map[a].value.get()
+        assert abs(chained[a] - whole) <= 1e-9 * max(1.0, abs(whole)), a
 
 
 def test_stream_state_folding_is_tree_shaped():
